@@ -21,8 +21,8 @@ worse, as silent performance/accuracy loss on a pod):
 A second, AST-based pass lints the source itself: raw ``shard_map``
 imports or raw ``lax`` collective calls outside ``parallel/comm.py`` (the
 audited wrappers exist for a reason), and keywords passed to JAX APIs that
-the *installed* JAX signature does not accept — the ``check_vma`` vs
-``check_rep`` class of API-drift bug, caught before any kernel runs.
+the *installed* JAX signature does not accept — API drift such as the
+retired ``check_rep`` keyword, caught before any kernel runs.
 
 Run ``python -m slate_tpu.analysis.lint``; intentional exceptions go in
 ``slate_tpu/analysis/waivers.cfg``.  The drivers are traced abstractly via

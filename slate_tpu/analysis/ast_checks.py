@@ -4,16 +4,16 @@ Three rules, none of which need to import the modules under inspection:
 
 - ``ast-shard-map-import``: ``shard_map`` imported straight from jax
   anywhere but ``parallel/comm.py`` — every kernel must come through
-  ``shard_map_compat`` so version drift is absorbed in one place.
+  ``shard_map_compat`` so the installed signature is met in one place.
 - ``ast-raw-collective``: a raw ``lax.psum``/``all_gather``/
   ``psum_scatter``/``ppermute``/``all_to_all`` call outside
   ``parallel/comm.py`` — the audited wrappers (``psum_a`` etc.) exist so
   the comm-volume audit sees every byte.
 - ``ast-kwargs``: a keyword passed to a known JAX API that the *installed*
-  signature does not accept.  This is the static form of the
-  ``shard_map(check_vma=...)`` TypeError on JAX 0.4.37: the lint compares
-  call sites against ``inspect.signature`` of the running JAX, so CI fails
-  at lint time instead of at the 30th kernel launch.
+  signature does not accept (e.g. the retired ``check_rep`` spelling of
+  ``shard_map``'s ``check_vma``): the lint compares call sites against
+  ``inspect.signature`` of the running JAX, so CI fails at lint time
+  instead of at the 30th kernel launch.
 - ``ast-masked-psum-bcast``: ``psum(where(...), axis)`` /
   ``psum_a(where(...), axis)`` outside ``parallel/comm.py`` — the
   masked-psum broadcast idiom pays ~2x the bytes of a rooted broadcast
@@ -46,19 +46,12 @@ COMM_MODULE = os.path.join("parallel", "comm.py")
 # operate on sources rather than registry drivers (the masked-psum seed)
 SEEDED_SOURCES: list = []
 
-# kwargs shard_map_compat absorbs on purpose (the rename pair); valid at
-# any call site that routes through the compat wrapper
-_COMPAT_EXTRA = {"check_vma", "check_rep"}
-
 
 def _installed_signatures() -> Dict[str, frozenset]:
     """Parameter-name sets of the JAX APIs whose call sites we validate."""
     import jax
 
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as _sm
+    from jax import shard_map as _sm
 
     sigs = {}
     for name, fn in (("shard_map", _sm), ("jit", jax.jit)):
@@ -185,16 +178,12 @@ def check_source(
                 )
             )
 
-        # kwarg drift: direct calls (shard_map_compat validates against the
-        # same signature + the rename aliases it absorbs)...
+        # kwarg drift: direct calls (shard_map_compat forwards to the
+        # same signature)...
         base = sigs.get("shard_map" if name == "shard_map_compat" else name)
         if base is not None:
-            # only the compat wrapper absorbs the rename aliases; a RAW
-            # shard_map call with check_vma on JAX 0.4.37 is exactly the
-            # TypeError this rule exists to catch
-            allowed = base | (_COMPAT_EXTRA if name == "shard_map_compat" else set())
             for kw in node.keywords:
-                if kw.arg is not None and kw.arg not in allowed:
+                if kw.arg is not None and kw.arg not in base:
                     out.append(
                         Finding(
                             "ast-kwargs",

@@ -11,7 +11,7 @@ from __future__ import annotations
 from typing import Iterator, List, Sequence, Tuple
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from .findings import Finding
 

@@ -40,7 +40,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from .findings import Finding
 from .jaxpr_checks import DATA_COLLECTIVES, _axes_of, _sub_jaxprs, iter_eqns

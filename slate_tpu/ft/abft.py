@@ -337,7 +337,8 @@ def _ft_potrf_jit(at, mesh, p, q, nt, la, bi, pi, fi, fv):
         bad = (~jnp.isfinite(dvals) | (dvals <= 0)) & diag_tiles
         gidx = i_log[:, None, None] * nb + jnp.arange(nb)[None, None, :] + 1
         big = nt * nb + 1
-        local_info = jnp.min(jnp.where(bad, gidx, big))
+        # int32 before the reduction: TPU lowers 64-bit all-reduces for sum only
+        local_info = jnp.min(jnp.where(bad, gidx, big)).astype(jnp.int32)
         info = lax.pmin(lax.pmin(local_info, ROW_AXIS), COL_AXIS)
         info = jnp.where(info >= big, 0, info).astype(jnp.int32)
         return t_loc, info[None, None]
@@ -424,7 +425,8 @@ def _ft_lu_jit(at, mesh, p, q, nt, la, bi, pi, fi, fv):
         bad = (~jnp.isfinite(jnp.abs(dvals)) | (dvals == 0)) & diag_tiles
         gidx = i_log[:, None, None] * nb + jnp.arange(nb)[None, None, :] + 1
         big = nt * nb + 1
-        local_info = jnp.min(jnp.where(bad, gidx, big))
+        # int32 before the reduction: TPU lowers 64-bit all-reduces for sum only
+        local_info = jnp.min(jnp.where(bad, gidx, big)).astype(jnp.int32)
         info = lax.pmin(lax.pmin(local_info, ROW_AXIS), COL_AXIS)
         info = jnp.where(info >= big, 0, info).astype(jnp.int32)
         return t_loc, info[None, None]

@@ -370,22 +370,27 @@ OZAKI_F64_BUFFERS = 4
 
 def hbm_budget(default: int = V5E_HBM_BYTES) -> int:
     """Per-device HBM budget for routing decisions: the SLATE_TPU_HBM_BYTES
-    env override, else the default backend device's reported bytes_limit,
-    else the v5e default.  Never raises (CPU devices report no stats)."""
+    env override, else the default backend device's reported bytes_limit.
+    Devices that report no memory statistics (CPU) get ``default``; a TPU
+    that reports no ``bytes_limit`` raises — guessing its size would
+    route real work against a made-up budget."""
     env = os.environ.get(HBM_ENV)
     if env:
         try:
             return int(float(env))
         except ValueError:
             pass
-    try:
-        import jax
+    import jax
 
-        stats = jax.devices()[0].memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
+    dev = jax.devices()[0]
+    stats = dev.memory_stats()
+    if stats and stats.get("bytes_limit"):
+        return int(stats["bytes_limit"])
+    if dev.platform == "tpu":
+        raise RuntimeError(
+            f"{dev.device_kind} reports no bytes_limit in memory_stats(); "
+            f"set {HBM_ENV} to its HBM size"
+        )
     return default
 
 

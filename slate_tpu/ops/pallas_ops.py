@@ -11,8 +11,7 @@ round-trips between the elementwise ops they fuse.
 
 The FUSED PANEL KERNELS below are this module's hot half (SURVEY "Hard
 parts": the panel factorization is the latency bottleneck — nb tiny XLA
-dispatches per k-step; BENCH_r05: potrf f32 ~2.4 TF/s vs gemm f32
-~101 TF/s on the same chip).  MAGMA-style batched one-sided panels
+dispatches per k-step).  MAGMA-style batched one-sided panels
 (Abdelfattah et al.) factor the whole panel in ONE on-chip kernel; the
 Pallas forms here do the same:
 
@@ -44,6 +43,13 @@ semantics for every dtype; dispatch is gated by ``Option.PanelImpl``
 (:func:`resolve_panel_impl`, the ``Option.BcastImpl`` pattern) and on
 CPU/tier-1 every kernel runs under the Pallas interpreter and is
 parity-tested against its XLA reference (tests/test_pallas_panels.py).
+``auto`` resolves panels to ``xla`` on every backend (no chip evidence
+for the fused panels yet).  The panel column loops slice values with
+``lax.dynamic_slice``, which Mosaic does not lower, so every panel
+kernel raises on a TPU (:func:`_require_mosaic_lowering`) until a
+benchmark shows a Mosaic form pays off; the elementwise, gemm and
+trailing-update kernels compile.  tests/test_chip_compile.py pins both
+for a described v5e.
 
 Use :func:`use_pallas_tiles` to gate the elementwise twins exactly like
 ``ops.matmul._use_pallas`` does for the gemm kernel.
@@ -59,14 +65,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas TPU backend is unavailable on pure-CPU builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from ..types import SlateError
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -74,7 +75,7 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 def use_pallas_tiles(a: jax.Array) -> bool:
     """Pallas path: TPU backend, supported dtype, (k, nb, nb) tile stack
     big enough that a grid launch beats XLA's fused form."""
-    if not _HAS_PLTPU or jax.default_backend() != "tpu":
+    if jax.default_backend() != "tpu":
         return False
     if a.dtype not in (jnp.float32, jnp.bfloat16):
         return False
@@ -90,7 +91,7 @@ def transpose_pallas(a: jax.Array) -> jax.Array:
     """Batched tile transpose over a (k, nb, nb) stack
     (device_transpose.cu): one grid step per tile."""
     k, mb, nb = a.shape
-    return pl.pallas_call(
+    return _pallas_call(
         _transpose_kernel,
         out_shape=jax.ShapeDtypeStruct((k, nb, mb), a.dtype),
         grid=(k,),
@@ -109,17 +110,13 @@ def geadd_pallas(alpha, a: jax.Array, beta, b: jax.Array) -> jax.Array:
     k, mb, nb = a.shape
     al = jnp.asarray([alpha], a.dtype)
     be = jnp.asarray([beta], a.dtype)
-    return pl.pallas_call(
+    return _pallas_call(
         _geadd_kernel,
         out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
         grid=(k,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM)
-            if _HAS_PLTPU
-            else pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec(memory_space=pltpu.SMEM)
-            if _HAS_PLTPU
-            else pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, mb, nb), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, mb, nb), lambda i: (i, 0, 0)),
         ],
@@ -140,7 +137,7 @@ def genorm_max_pallas(a: jax.Array) -> jax.Array:
     """Per-tile max-abs over a (k, nb, nb) stack (device_genorm.cu,
     NormScope::Matrix reduced tile-wise)."""
     k, mb, nb = a.shape
-    colmax = pl.pallas_call(
+    colmax = _pallas_call(
         _norm_max_kernel,
         out_shape=jax.ShapeDtypeStruct((k, 8, nb), a.dtype),
         grid=(k,),
@@ -169,9 +166,8 @@ PANEL_IMPL_ENV = "SLATE_TPU_PANEL_IMPL"
 _PANEL_DEFAULT = [None]  # session default (use_panel_impl), outside jit
 _PANEL_ACTIVE = ["__chain__"]  # trace-time impl (panel_impl_scope)
 
-# auto only engages a panel whose working set fits comfortably in VMEM
-# next to the solve tiles (~16 MB/core on v5e; headroom for double
-# buffering)
+# auto only engages an update whose broadcast panels fit comfortably in
+# VMEM (~16 MB/core on v5e; headroom for double buffering)
 _PANEL_VMEM_CAP = 4 * 1024 * 1024
 
 
@@ -223,23 +219,40 @@ def panel_impl_scope(impl: str):
 def _interpret() -> bool:
     """Pallas interpreter mode: anywhere the real TPU backend is absent
     (CPU tier-1/CI), kernels run interpreted — same lax semantics, pure
-    JAX — so every kernel is testable off-chip."""
+    JAX — so every kernel is testable off-chip.  On a TPU backend every
+    kernel goes to Mosaic; nothing falls back to the interpreter."""
     from .matmul import _tpu_is_default
 
-    return not (_HAS_PLTPU and _tpu_is_default())
+    return not _tpu_is_default()
+
+
+def _require_mosaic_lowering(kernel: str) -> None:
+    """Refuse, on a TPU backend, a panel kernel Mosaic cannot lower: the
+    Cholesky / LU / QR column loops slice values with
+    ``lax.dynamic_slice``, which the TPU Pallas lowering does not
+    implement (tests/test_chip_compile.py pins which kernels compile).
+    Raising here keeps an explicit ``Option.PanelImpl=pallas`` from
+    failing deep in the compiler, and from ever dropping silently to XLA
+    or to the interpreter."""
+    if not _interpret():
+        raise SlateError(
+            f"{kernel} does not lower for the TPU (Mosaic has no "
+            "in-kernel dynamic_slice); use Option.PanelImpl='xla' or "
+            "'auto' on a TPU backend"
+        )
 
 
 def panel_active_impl() -> str:
     """Concrete trace-time impl: the innermost ``panel_impl_scope`` when
     a kernel pinned one (static jit arg), else the resolve chain; with
-    ``auto`` mapped to its concrete meaning — ``pallas`` on a real TPU
-    backend, ``xla`` elsewhere (so CPU tier-1 stays bitwise today's
-    results unless pallas is requested explicitly)."""
+    ``auto`` mapped to ``xla`` on every backend.  The fused panel kernels
+    run only when asked for (auto has no chip evidence for them yet); on
+    a TPU they raise (:func:`_require_mosaic_lowering`)."""
     impl = _PANEL_ACTIVE[-1]
     if impl == "__chain__":
         impl = resolve_panel_impl()
     if impl == "auto":
-        impl = "xla" if _interpret() else "pallas"
+        impl = "xla"
     return impl
 
 
@@ -459,8 +472,21 @@ def _lu_inv_body(a: jax.Array):
     return lu, jnp.triu(uinv)
 
 
-def _pallas_call(*args, **kw):
-    return pl.pallas_call(*args, interpret=_interpret(), **kw)
+def _pallas_call(kernel, **kw):
+    """``pl.pallas_call`` for this backend: interpreted off-TPU; on a TPU
+    the kernel and its index maps trace with x64 off, since Mosaic lowers
+    32-bit grid indices only (the library runs with ``jax_enable_x64`` on
+    for its f64 paths; on-chip kernels take f32/bf16/int32 operands)."""
+    interpret = _interpret()
+    call = pl.pallas_call(kernel, interpret=interpret, **kw)
+    if interpret:
+        return call
+
+    def run(*args):
+        with jax.enable_x64(False):
+            return call(*args)
+
+    return run
 
 
 # ---------------------------------------------------------------------------
@@ -472,6 +498,7 @@ def chol_diag_inv_pallas(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """(L, L^-1) of one nb x nb Hermitian block in ONE kernel dispatch:
     the on-chip replacement for the ``cholesky`` + ``triangular_solve``
     pair (each of which unrolls into per-column micro-ops on TPU)."""
+    _require_mosaic_lowering("chol_diag_inv_pallas")
     n = a.shape[0]
 
     def kern(a_ref, l_ref, x_ref):
@@ -495,6 +522,7 @@ def chol_panel_tiles_pallas(
     factors the diagonal tile (column loop, inverse kept in VMEM
     scratch), steps 1..L solve the panel tiles ``A_i L^-H`` on the MXU.
     Returns (tril L_kk, solved tile stack)."""
+    _require_mosaic_lowering("chol_panel_tiles_pallas")
     nb = dtile.shape[0]
     L = tiles.shape[0]
 
@@ -528,9 +556,7 @@ def chol_panel_tiles_pallas(
             jax.ShapeDtypeStruct((nb, nb), dtile.dtype),
             jax.ShapeDtypeStruct((L, nb, nb), tiles.dtype),
         ),
-        scratch_shapes=[
-            (pltpu.VMEM if _HAS_PLTPU else pltpu_vmem_stub)((nb, nb), dtile.dtype)
-        ],
+        scratch_shapes=[pltpu.VMEM((nb, nb), dtile.dtype)],
     )(dtile, tiles)
     return l, solved
 
@@ -547,6 +573,7 @@ def lu_panel_tiles_pallas(
     the packed L\\U of the diagonal tile (+ U^-1 in scratch), steps 1..L
     solve the column tiles ``A_i U^-1`` on the MXU.  Returns
     (packed L\\U, solved tile stack)."""
+    _require_mosaic_lowering("lu_panel_tiles_pallas")
     nb = dtile.shape[0]
     L = tiles.shape[0]
 
@@ -580,9 +607,7 @@ def lu_panel_tiles_pallas(
             jax.ShapeDtypeStruct((nb, nb), dtile.dtype),
             jax.ShapeDtypeStruct((L, nb, nb), tiles.dtype),
         ),
-        scratch_shapes=[
-            (pltpu.VMEM if _HAS_PLTPU else pltpu_vmem_stub)((nb, nb), dtile.dtype)
-        ],
+        scratch_shapes=[pltpu.VMEM((nb, nb), dtile.dtype)],
     )(dtile, tiles)
     return lu, solved
 
@@ -591,6 +616,7 @@ def lu_rowsolve_tiles_pallas(luk: jax.Array, tiles: jax.Array) -> jax.Array:
     """The getrf-nopiv panel-row phase: step 0 computes unit-L^-1 from
     the packed diagonal L\\U (scratch), steps 1..L solve the row tiles
     ``L^-1 A_j`` on the MXU."""
+    _require_mosaic_lowering("lu_rowsolve_tiles_pallas")
     nb = luk.shape[0]
     L = tiles.shape[0]
 
@@ -618,9 +644,7 @@ def lu_rowsolve_tiles_pallas(luk: jax.Array, tiles: jax.Array) -> jax.Array:
             (1, nb, nb), lambda i: (jnp.maximum(i - 1, 0), 0, 0)
         ),
         out_shape=jax.ShapeDtypeStruct((L, nb, nb), tiles.dtype),
-        scratch_shapes=[
-            (pltpu.VMEM if _HAS_PLTPU else pltpu_vmem_stub)((nb, nb), luk.dtype)
-        ],
+        scratch_shapes=[pltpu.VMEM((nb, nb), luk.dtype)],
     )(luk, tiles)
 
 
@@ -635,6 +659,7 @@ def qr_panel_pallas(a: jax.Array):
     the reference's internal_geqrf panel + larft pair as a single
     dispatch.  Returns (packed VR, tau, T); runs the SAME op sequence as
     ``linalg.qr._panel_qr`` + ``_larft`` (bitwise under interpret)."""
+    _require_mosaic_lowering("qr_panel_pallas")
     m, w = a.shape
 
     def kern(a_ref, vr_ref, tau_ref, t_ref):
@@ -661,6 +686,7 @@ def qr_panel_offset_pallas(a: jax.Array, row0):
     building block ``_panel_qr_offset`` + ``_larft_v`` as one dispatch.
     ``row0`` may be traced (a loop residue); it rides along as a scalar
     operand.  Returns (r, v, tau, T)."""
+    _require_mosaic_lowering("qr_panel_offset_pallas")
     m, w = a.shape
     r0 = jnp.asarray(row0, jnp.int32).reshape(1, 1)
 
@@ -673,15 +699,12 @@ def qr_panel_offset_pallas(a: jax.Array, row0):
         tau_ref[:] = tau[None, :]
         t_ref[:] = _larft_v(v, tau)
 
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM)
-        if _HAS_PLTPU and not _interpret()
-        else pl.BlockSpec((1, 1), lambda: (0, 0)),
-        pl.BlockSpec((m, w), lambda: (0, 0)),
-    ]
     r, v, tau, t = _pallas_call(
         kern,
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((1, 1), lambda: (0, 0)),
+            pl.BlockSpec((m, w), lambda: (0, 0)),
+        ],
         out_specs=(
             pl.BlockSpec((m, w), lambda: (0, 0)),
             pl.BlockSpec((m, w), lambda: (0, 0)),
@@ -735,6 +758,28 @@ def summa_update_pallas(
     )(pan, urow, acc)
 
 
+# The trailing kernels' per-tile (I, J) keep mask.  Interpreted, each
+# grid step (j, i) gets its own (1, 1) block.  Mosaic refuses (1, 1)
+# blocks, so on the chip the mask rides SMEM whole, flattened row-major,
+# and each step reads its own entry by program id — the element interpret
+# mode reads (a whole-array SMEM block read at [0, 0] would hand every
+# step tile (0, 0)'s mask).
+
+
+def _mask_input(mask: jax.Array):
+    """(BlockSpec, operand) for the keep mask on this backend."""
+    m32 = mask.astype(jnp.int32)
+    if _interpret():
+        return pl.BlockSpec((1, 1), lambda j, i: (i, j)), m32
+    return pl.BlockSpec(memory_space=pltpu.SMEM), m32.reshape(-1)
+
+
+def _tile_mask(m_ref, J: int):
+    if _interpret():
+        return m_ref[0, 0]
+    return m_ref[pl.program_id(1) * J + pl.program_id(0)]
+
+
 def chol_trailing_update_pallas(
     view: jax.Array, pan: jax.Array, pan_t: jax.Array, mask: jax.Array
 ) -> jax.Array:
@@ -745,21 +790,16 @@ def chol_trailing_update_pallas(
     ``excl_kc`` column) computed in XLA outside and riding SMEM."""
     I, nb, _ = pan.shape
     J = pan_t.shape[0]
-    m32 = mask.astype(jnp.int32)
+    mask_spec, m = _mask_input(mask)
 
     def kern(m_ref, p_ref, t_ref, a_ref, o_ref):
         upd = lax.dot_general(
             p_ref[0], t_ref[0], (((1,), (1,)), ((), ())),
             precision=_HIGHEST,
         ).astype(a_ref.dtype)
-        sel = jnp.where(m_ref[0, 0] != 0, upd, jnp.zeros_like(upd))
+        sel = jnp.where(_tile_mask(m_ref, J) != 0, upd, jnp.zeros_like(upd))
         o_ref[:] = a_ref[:] - sel[None, None]
 
-    mask_spec = (
-        pl.BlockSpec(memory_space=pltpu.SMEM)
-        if _HAS_PLTPU and not _interpret()
-        else pl.BlockSpec((1, 1), lambda j, i: (i, j))
-    )
     return _pallas_call(
         kern,
         grid=(J, I),
@@ -771,7 +811,7 @@ def chol_trailing_update_pallas(
         ],
         out_specs=pl.BlockSpec((1, 1, nb, nb), lambda j, i: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
-    )(m32, pan, pan_t, view)
+    )(m, pan, pan_t, view)
 
 
 def lu_trailing_update_pallas(
@@ -783,20 +823,15 @@ def lu_trailing_update_pallas(
     exclusions; all-ones on the plain sweep) computed in XLA outside."""
     I, nb, _ = pan.shape
     J = urow.shape[0]
-    m32 = mask.astype(jnp.int32)
+    mask_spec, m = _mask_input(mask)
 
     def kern(m_ref, p_ref, u_ref, a_ref, o_ref):
         upd = jnp.matmul(
             p_ref[0], u_ref[0], precision=_HIGHEST
         ).astype(a_ref.dtype)
-        sel = jnp.where(m_ref[0, 0] != 0, upd, jnp.zeros_like(upd))
+        sel = jnp.where(_tile_mask(m_ref, J) != 0, upd, jnp.zeros_like(upd))
         o_ref[:] = a_ref[:] - sel[None, None]
 
-    mask_spec = (
-        pl.BlockSpec(memory_space=pltpu.SMEM)
-        if _HAS_PLTPU and not _interpret()
-        else pl.BlockSpec((1, 1), lambda j, i: (i, j))
-    )
     return _pallas_call(
         kern,
         grid=(J, I),
@@ -808,7 +843,7 @@ def lu_trailing_update_pallas(
         ],
         out_specs=pl.BlockSpec((1, 1, nb, nb), lambda j, i: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(t_loc.shape, t_loc.dtype),
-    )(m32, pan, urow, t_loc)
+    )(m, pan, urow, t_loc)
 
 
 # ---------------------------------------------------------------------------
@@ -838,8 +873,8 @@ def ft_summa_update_pallas(
         upd = jnp.matmul(p_ref[0], u_ref[0], precision=_HIGHEST)
         o_ref[:] = (a_ref[:] + upd[None, None].astype(a_ref.dtype))
 
-        wu1 = w1_ref[0, i] * upd
-        wu2 = w2_ref[0, i] * upd
+        wu1 = w1_ref[i] * upd
+        wu2 = w2_ref[i] * upd
 
         @pl.when(i == 0)
         def _():
@@ -862,8 +897,8 @@ def ft_summa_update_pallas(
             pl.BlockSpec((1, nb, nb), lambda j, i: (i, 0, 0)),
             pl.BlockSpec((1, nb, nb), lambda j, i: (j, 0, 0)),
             pl.BlockSpec((1, 1, nb, nb), lambda j, i: (i, j, 0, 0)),
-            pl.BlockSpec((1, I), lambda j, i: (0, 0)),
-            pl.BlockSpec((1, I), lambda j, i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((2, 1, nb, nb), lambda j, i: (0, j, 0, 0)),
         ],
         out_specs=(
@@ -874,19 +909,6 @@ def ft_summa_update_pallas(
             jax.ShapeDtypeStruct(acc.shape, acc.dtype),
             jax.ShapeDtypeStruct(part.shape, part.dtype),
         ),
-        scratch_shapes=[
-            (pltpu.VMEM if _HAS_PLTPU else pltpu_vmem_stub)(
-                (2, nb, nb), part.dtype
-            )
-        ],
-    )(pan, urow, acc, w1[None, :], w2[None, :], part)
+        scratch_shapes=[pltpu.VMEM((2, nb, nb), part.dtype)],
+    )(pan, urow, acc, w1, w2, part)
     return out, part_new
-
-
-class pltpu_vmem_stub:
-    """Scratch-shape stand-in when the pltpu module is unavailable
-    (pure-CPU builds run every kernel through the interpreter, which
-    accepts plain ShapeDtypeStructs as scratch)."""
-
-    def __new__(cls, shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype)

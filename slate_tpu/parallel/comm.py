@@ -28,58 +28,25 @@ same reason (Gates et al., SC'19).
 from __future__ import annotations
 
 import contextlib
-import inspect
 import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-try:  # JAX >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
+from jax import shard_map
 
 from .mesh import COL_AXIS, ROW_AXIS
 
 PRECISE = lax.Precision.HIGHEST
 
-# keywords the installed shard_map actually accepts; the replication-check
-# flag was renamed check_rep -> check_vma across JAX releases
-_SHARD_MAP_KW = frozenset(inspect.signature(shard_map).parameters)
-_REP_ALIASES = ("check_vma", "check_rep")
-
 
 def shard_map_compat(f, mesh, in_specs, out_specs, **kw):
-    """``shard_map`` across JAX versions.
-
-    The replication/varying-manual-axes check flag was renamed
-    (``check_rep`` on older JAX, ``check_vma`` on newer): callers may pass
-    either spelling and it is mapped onto whichever the installed
-    signature accepts — if the installed shard_map predates both, the flag
-    is dropped (the ``check_vma`` TypeError class of API-drift bug;
-    slate_lint's ast pass flags raw shard_map calls so new call sites come
-    through here).  Keywords outside the known-rename set still raise, so
-    a typo'd kwarg fails fast instead of silently changing semantics."""
-    rep_vals = [kw.pop(k) for k in _REP_ALIASES if k in kw]
-    if len(rep_vals) > 1 and any(v != rep_vals[0] for v in rep_vals[1:]):
-        raise TypeError(
-            f"shard_map_compat: conflicting values for {_REP_ALIASES} "
-            f"({rep_vals}); pass one spelling"
-        )
-    if rep_vals:
-        for k in _REP_ALIASES:
-            if k in _SHARD_MAP_KW:
-                kw[k] = rep_vals[0]
-                break
-    unknown = [k for k in kw if k not in _SHARD_MAP_KW]
-    if unknown:
-        raise TypeError(
-            f"shard_map_compat: keyword(s) {unknown} not accepted by the "
-            "installed shard_map and not in the known rename set "
-            f"{_REP_ALIASES}"
-        )
+    """``jax.shard_map`` with the (f, mesh, in_specs, out_specs) order
+    every kernel uses.  The one place kernels reach shard_map through
+    (slate_lint's ast pass flags raw shard_map imports), so the installed
+    signature is validated in one spot: unknown keywords raise
+    TypeError."""
     return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 # default trailing-update segmentation for the bucketed factorization

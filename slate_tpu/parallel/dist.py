@@ -34,7 +34,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..core.grid import num_tiles
@@ -109,22 +109,40 @@ def from_dense(
     """Distribute a dense (m, n) array over ``mesh`` block-cyclically.
 
     Analogue of Matrix::fromLAPACK + insertLocalTiles + tile scatter
-    (Matrix.hh:58-112); on TPU it is a reshape + permutation + device_put.
+    (Matrix.hh:58-112); on TPU it is a reshape + permutation, compiled as
+    one program whose output is sharded over the mesh.
     """
     m, n = a.shape
-    mt = padded_tiles(m, nb, mesh)
-    nt = padded_tiles(n, nb, mesh)
-    mp, np_ = mt * nb, nt * nb
+    mp, np_ = padded_tiles(m, nb, mesh) * nb, padded_tiles(n, nb, mesh) * nb
+    no_pad = mp == m and np_ == n
+    if (isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer)
+            and a.committed and a.sharding.device_set != set(mesh.devices.flat)):
+        # one program cannot take an operand committed to other devices:
+        # move it onto the mesh first, split over it where the shape allows
+        p, q = mesh_shape(mesh)
+        spec = P(ROW_AXIS, COL_AXIS) if m % p == 0 and n % q == 0 else P()
+        a = jax.device_put(a, NamedSharding(mesh, spec))
+    return DistMatrix(
+        tiles=_cyclic_tiles(a, mesh, nb, diag_pad_one), m=m, n=n, nb=nb,
+        mesh=mesh, diag_pad=diag_pad_one or no_pad,
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _cyclic_tiles(a: jax.Array, mesh: Mesh, nb: int, diag_pad_one: bool) -> jax.Array:
+    """Dense -> sharded cyclic tile stack in ONE program.  Run op by op,
+    the pad, reshape and permutation each materialise the whole matrix
+    on one device: a 32768^2 f32 operand on a 2x2 v5e mesh ran out of HBM
+    at the permutation (PR 21).  Compiled together, XLA partitions them
+    and the result is born sharded."""
+    m, n = a.shape
+    mp, np_ = padded_tiles(m, nb, mesh) * nb, padded_tiles(n, nb, mesh) * nb
     a = jnp.pad(a, ((0, mp - m), (0, np_ - n)))
     if diag_pad_one:
         d = jnp.arange(min(m, n), min(mp, np_))
         a = a.at[d, d].set(1)
     t = to_cyclic(to_tiles(a, nb), *mesh_shape(mesh))
-    t = jax.device_put(t, tile_sharding(mesh))
-    no_pad = mp == m and np_ == n
-    return DistMatrix(
-        tiles=t, m=m, n=n, nb=nb, mesh=mesh, diag_pad=diag_pad_one or no_pad
-    )
+    return lax.with_sharding_constraint(t, tile_sharding(mesh))
 
 
 def to_dense(d: DistMatrix) -> jax.Array:

@@ -233,7 +233,8 @@ def _chol_info_dist(t_loc, i_log, j_log, nt, nb):
     bad = (~jnp.isfinite(dvals) | (dvals <= 0)) & diag_tiles
     gidx = i_log[:, None, None] * nb + jnp.arange(nb)[None, None, :] + 1
     big = nt * nb + 1
-    local_info = jnp.min(jnp.where(bad, gidx, big))
+    # int32 before the reduction: TPU lowers 64-bit all-reduces for sum only
+    local_info = jnp.min(jnp.where(bad, gidx, big)).astype(jnp.int32)
     info = lax.pmin(lax.pmin(local_info, ROW_AXIS), COL_AXIS)
     return jnp.where(info >= big, 0, info).astype(jnp.int32)
 
@@ -559,7 +560,8 @@ def _pbtrf_band_jit(at, mesh, p, q, nt, wd, la, bi):
         bad = (~jnp.isfinite(dvals) | (dvals <= 0)) & diag_tiles
         gidx = i_l[:, None, None] * nb + jnp.arange(nb)[None, None, :] + 1
         big = nt * nb + 1
-        local_info = jnp.min(jnp.where(bad, gidx, big))
+        # int32 before the reduction: TPU lowers 64-bit all-reduces for sum only
+        local_info = jnp.min(jnp.where(bad, gidx, big)).astype(jnp.int32)
         info = lax.pmin(lax.pmin(local_info, ROW_AXIS), COL_AXIS)
         info = jnp.where(info >= big, 0, info).astype(jnp.int32)
         return t_loc, info[None, None]

@@ -5,7 +5,7 @@ P8 at mesh scale).
 The reference ships ``gesv_mixed``/``posv_mixed`` (f32 factor, f64
 refinement, gesv_mixed.cc:16-44) as its high-performance solve tier.  On
 TPU the gap is not a tier, it is the product: f64 getrf measures ~52 GF/s
-against ~2 TF/s for f32 (BENCH_r05), so refinement is how a distributed
+against ~2 TF/s for f32 (pre-PR-1 figures), so refinement is how a distributed
 f64 solve should run by default.  Three pieces live here:
 
 - ``_ir_posv_jit`` / ``_ir_gesv_jit``: classic iterative refinement as ONE

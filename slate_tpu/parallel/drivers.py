@@ -671,7 +671,8 @@ def gesv_mesh(
     mixed-precision ladder by default — f32 partial-pivot factor + fused
     f64 refinement, GMRES-IR escalation, full-f64 fallback
     (Option.MixedPrecision; dist_refine.py) — because on TPU the f32
-    factor runs ~40x the emulated-f64 rate (BENCH_r05).
+    factor runs ~40x the emulated-f64 rate (pre-PR-1 figure, not
+    measured on this code).
     Option.MixedPrecision=off (or non-f64 dtype) runs the direct path,
     trace-identical to the pre-mixed driver."""
     from .dist_refine import mixed_mesh_route

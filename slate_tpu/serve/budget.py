@@ -8,7 +8,7 @@ everyone else's working set.  The ledger here is the tenant dimension of
 that bound: every queued-or-in-flight request holds a modeled-byte
 reservation against its tenant's budget, and a submit that would push
 the tenant past its budget is refused BEFORE it enters a window
-(``reject_budget`` in the RequestTrace taxonomy — the fair-share twin
+(``reject_budget`` in the RequestTrace outcome set — the fair-share twin
 of ``reject_admission``).
 
 The modeled cost of one request is the same closed form

@@ -10,7 +10,7 @@ counters, not hoped).
 
 Layering: this is the HOST half (key -> traced program identity); the
 DISK half is JAX's persistent compilation cache, which
-``enable_persistent_compilation_cache`` turns on so a restarted server
+``utils.compile_cache.enable_compile_cache`` turns on so a restarted server
 re-loads compiled binaries instead of re-running XLA.  Note the PR 10
 finding: cache-DESERIALIZED executables report an empty
 ``memory_analysis``, which is why the mem gates (obs/memory.py) force
@@ -33,7 +33,6 @@ import jax
 
 from .metrics import serve_count
 
-CACHE_DIR_ENV = "SLATE_TPU_SERVE_CACHE_DIR"
 
 
 class CacheKey(NamedTuple):
@@ -171,19 +170,3 @@ class ExecutableCache:
 # The process-wide cache the Router and smoke use; tests may build their
 # own isolated instances.
 executable_cache = ExecutableCache()
-
-
-def enable_persistent_compilation_cache(path: Optional[str] = None) -> str:
-    """Turn on JAX's disk compilation cache under ``path`` (default
-    ``$SLATE_TPU_SERVE_CACHE_DIR`` or ``~/.cache/slate_tpu_serve``) so
-    compiled executables survive process restarts.  A directory already
-    configured (e.g. the test suite's .jax_cache) is left alone."""
-    current = jax.config.jax_compilation_cache_dir
-    if current:
-        return current
-    path = path or os.environ.get(CACHE_DIR_ENV) or os.path.expanduser(
-        "~/.cache/slate_tpu_serve")
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    return path

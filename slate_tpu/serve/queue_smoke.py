@@ -211,7 +211,7 @@ def run_budget_phase(failures: list) -> dict:
     if len(rej_traces) != rejected:
         failures.append(f"budget phase: {rejected} refusals produced "
                         f"{len(rej_traces)} reject_budget terminals")
-    # a submit past the bin vocabulary is the OTHER reject taxon
+    # a submit past the bin vocabulary is the OTHER reject outcome
     try:
         q.submit("posv", _spd(rng, 64),
                  jnp.asarray(rng.standard_normal((64,))), tenant="burst")

@@ -176,7 +176,7 @@ def _make_handler(service: Service):
                 x = service.solve(op, a, b, tenant=tenant)
             except SlateError as e:
                 # budget refusals are the retry-later class; everything
-                # else in the SlateError taxonomy is the caller's operand
+                # else in the SlateError family is the caller's operand
                 code = 429 if "budget" in str(e) else 422
                 self._send_json(code, {"error": str(e)})
                 return

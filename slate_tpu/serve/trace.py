@@ -13,7 +13,7 @@ Contracts:
 
 - **Exactly one terminal outcome per request.**  ``finish`` is a
   single-shot: a second terminal is a programming error and raises.
-  The outcome taxonomy (``TERMINALS``) attributes every exit to one
+  The outcome set (``TERMINALS``) attributes every exit to one
   cause — a request that retried AND resumed terminates under the LAST
   degradation that carried it home.
 - **Disabled mode stays honest.**  ``new_trace`` returns ``None`` while

@@ -244,10 +244,10 @@ class Option(enum.Enum):
     # (ops/pallas_ops.py): "xla" (the reference semantics — today's
     # cholesky/triangular_solve/Householder dispatch chains, bitwise),
     # "pallas" (one fused on-chip kernel per panel phase: MAGMA-style
-    # blocked panels; f64/complex panels fall back to xla on a real TPU,
-    # and on CPU the kernels run under the Pallas interpreter), or
-    # "auto" (the default: pallas on a real TPU backend for MXU dtypes,
-    # xla elsewhere — CPU tier-1 stays bitwise today's results).
+    # blocked panels; f64/complex panels keep xla; on CPU the kernels run
+    # under the Pallas interpreter; on a TPU every panel kernel RAISES
+    # SlateError — Mosaic cannot lower their in-kernel dynamic_slice,
+    # PR 21), or "auto" (the default: xla on every backend).
     # Resolution order: explicit option > pallas_ops.use_panel_impl
     # context > SLATE_TPU_PANEL_IMPL environment > auto (the
     # Option.BcastImpl pattern).  The pallas forms match the XLA

@@ -1,7 +1,7 @@
 """bench.py kill-path hardening (ISSUE 9 satellite): a SIGTERM delivered
 mid-extra (what ``timeout -k`` sends before SIGKILL) must still leave a
 parseable final JSON line on stdout AND a parseable atomic partial file —
-the BENCH_r05 failure mode was rc=124 with parsed=null."""
+a pre-PR-1 failure mode was rc=124 with parsed=null."""
 
 import json
 import os

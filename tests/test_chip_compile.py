@@ -1,0 +1,163 @@
+"""Compile the default TPU path's Pallas kernels for a described v5e.
+
+No chip is attached: ``jax.experimental.topologies`` describes a v5e and
+the TPU compiler (Mosaic for the kernels) runs here, so a kernel that
+interpret mode accepts but the chip's compiler refuses fails in tier-1
+instead of on the chip.  Real widths: nb = 256, an 8 x 8 local trailing
+grid, a 63-tile panel.  The panel kernels do not lower (value-level
+``dynamic_slice``) and raise on a TPU under an explicit
+``Option.PanelImpl=pallas``.  ``_interpret()`` is steered inside each
+test; the topology is described in a module-scoped fixture, never at
+import time (only one process may load libtpu, and every xdist worker
+imports this file).  A failure to describe it fails the tests: the
+installed libtpu can always describe a v5e.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from slate_tpu.ops import pallas_ops as po
+from slate_tpu.ops.matmul import matmul_pallas
+from slate_tpu.types import SlateError
+
+NB = 256
+TRAIL = 8  # local trailing tile grid
+PANEL = 63  # panel tiles below the diagonal tile
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Trace the TPU branches: kernels go to Mosaic, auto resolves as on
+    the chip.  The persistent cache is off around these compiles (an
+    entry compiled for a described chip cannot be read back here)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(po, "_interpret", lambda: False)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+
+
+def _trailing_args(shape, mask=True):
+    view = shape((TRAIL, TRAIL, NB, NB))
+    pan = shape((TRAIL, NB, NB))
+    rest = (shape((TRAIL, TRAIL), jnp.bool_),) if mask else ()
+    return (view, pan, pan) + rest
+
+
+DEFAULT_PATH_KERNELS = {
+    "summa_update": (po.summa_update_pallas, lambda s: _trailing_args(s, mask=False)),
+    "chol_trailing_update": (po.chol_trailing_update_pallas, _trailing_args),
+    "lu_trailing_update": (po.lu_trailing_update_pallas, _trailing_args),
+    "ft_summa_update": (
+        po.ft_summa_update_pallas,
+        lambda s: _trailing_args(s, mask=False)
+        + (s((TRAIL,)), s((TRAIL,)), s((2, TRAIL, NB, NB))),
+    ),
+    "transpose": (po.transpose_pallas, lambda s: (s((TRAIL, NB, NB)),)),
+    "geadd": (
+        lambda a, b: po.geadd_pallas(2.0, a, 0.5, b),
+        lambda s: (s((TRAIL, NB, NB)), s((TRAIL, NB, NB))),
+    ),
+    "genorm_max": (po.genorm_max_pallas, lambda s: (s((TRAIL, NB, NB)),)),
+    "matmul": (matmul_pallas, lambda s: (s((2048, 2048)), s((2048, 2048)))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DEFAULT_PATH_KERNELS))
+def test_kernel_compiles_for_v5e(name, one_chip, on_tpu):
+    fn, make_args = DEFAULT_PATH_KERNELS[name]
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(fn).lower(*make_args(shape)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_auto_resolves_as_on_the_chip(on_tpu):
+    """auto: trailing updates take the kernels above (within the VMEM
+    cap); panels stay on XLA until a chip measurement says otherwise."""
+    assert po.update_active_impl() == "pallas"
+    assert po.update_engaged(jnp.float32, 2 * TRAIL * NB * NB * 4)
+    assert po.panel_active_impl() == "xla"
+    assert not po.panel_engaged(jnp.float32, NB * NB * 4)
+
+
+def _panel_args(shape):
+    return shape((NB, NB)), shape((PANEL, NB, NB))
+
+
+PANEL_KERNELS = {
+    "chol_diag_inv": lambda d, t: po.chol_diag_inv_pallas(d),
+    "chol_panel_tiles": po.chol_panel_tiles_pallas,
+    "lu_panel_tiles": po.lu_panel_tiles_pallas,
+    "lu_rowsolve_tiles": po.lu_rowsolve_tiles_pallas,
+    "qr_panel": lambda d, t: po.qr_panel_pallas(t.reshape(PANEL * NB, NB)),
+    "qr_panel_offset": lambda d, t: po.qr_panel_offset_pallas(t.reshape(PANEL * NB, NB), 0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PANEL_KERNELS))
+def test_panel_kernel_raises_on_tpu(name, one_chip, on_tpu):
+    """A panel kernel Mosaic cannot lower raises SlateError on a TPU
+    backend — never a silent drop to XLA or to the interpreter."""
+    shape = lambda dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+    with pytest.raises(SlateError, match="does not lower"):
+        jax.jit(PANEL_KERNELS[name]).lower(*_panel_args(shape))
+
+
+def test_explicit_pallas_panel_raises_through_driver(on_tpu):
+    """Option.PanelImpl=pallas on the public QR factor raises on a TPU
+    backend instead of running another lowering; auto traces XLA."""
+    from slate_tpu import api
+
+    a = jax.ShapeDtypeStruct((512, 256), jnp.float32)
+    with po.use_panel_impl("pallas"), pytest.raises(SlateError, match="does not lower"):
+        jax.eval_shape(api.qr_factor, a)
+    assert "pallas_call" not in str(jax.make_jaxpr(api.qr_factor)(a))
+
+
+@pytest.mark.parametrize("driver,fused", [("posv_mesh", True), ("gesv_mesh", False)])
+def test_mesh_solve_compiles_for_2x2(driver, fused, topo, on_tpu):
+    """The public mesh solves compile for a described 2x2 v5e mesh with
+    x64 on (as the test suite and every f64 user runs): the pmin'd info
+    scalars must stay 32-bit.  At this local grid the Cholesky trailing
+    update takes the fused kernel inside shard_map; the partial-pivot LU
+    has no fused update and its panels stay XLA under auto, so its
+    program holds no Mosaic kernel."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from slate_tpu import parallel
+    from slate_tpu.parallel.mesh import COL_AXIS, ROW_AXIS
+
+    assert jax.config.jax_enable_x64
+    mesh = parallel.make_mesh(2, 2, devices=topo.devices)
+    n = 4 * NB
+    a = jax.ShapeDtypeStruct((n, n), jnp.float32,
+                             sharding=NamedSharding(mesh, P(ROW_AXIS, COL_AXIS)))
+    b = jax.ShapeDtypeStruct((n, 4), jnp.float32, sharding=NamedSharding(mesh, P()))
+    solve = getattr(parallel, driver)
+    compiled = jax.jit(lambda a, b: solve(a, b, mesh, nb=NB)).lower(a, b).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == fused

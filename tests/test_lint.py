@@ -190,20 +190,27 @@ def test_ast_pass_flags_bad_kwarg(tmp_path):
 
 
 def test_ast_pass_rep_aliases_only_via_compat(tmp_path):
-    """check_vma/check_rep are valid ONLY through shard_map_compat; a raw
-    shard_map call with either spelling is the API-drift bug itself, and a
-    comm re-import of raw shard_map is flagged too."""
+    """check_vma is the installed spelling; the retired check_rep is
+    kwarg drift even through shard_map_compat, and a comm re-import of
+    raw shard_map is flagged too."""
     from slate_tpu.analysis.ast_checks import _installed_signatures, check_file
 
     ok = tmp_path / "ok_kernel.py"
     ok.write_text(
         "def k(shard_map_compat, f, mesh, spec, x):\n"
-        "    a = shard_map_compat(f, mesh=mesh, in_specs=spec, out_specs=spec,\n"
-        "                         check_vma=False)(x)\n"
         "    return shard_map_compat(f, mesh=mesh, in_specs=spec, out_specs=spec,\n"
-        "                            check_rep=False)(a)\n"
+        "                            check_vma=False)(x)\n"
     )
     assert check_file(str(ok), "toy/ok_kernel.py", _installed_signatures()) == []
+
+    old = tmp_path / "old_kernel.py"
+    old.write_text(
+        "def k(shard_map_compat, f, mesh, spec, x):\n"
+        "    return shard_map_compat(f, mesh=mesh, in_specs=spec, out_specs=spec,\n"
+        "                            check_rep=False)(x)\n"
+    )
+    found = check_file(str(old), "toy/old_kernel.py", _installed_signatures())
+    assert [f.rule for f in found] == ["ast-kwargs"]
 
     bad = tmp_path / "bad_kernel2.py"
     bad.write_text(
@@ -213,13 +220,7 @@ def test_ast_pass_rep_aliases_only_via_compat(tmp_path):
         "                     check_vma=False)(x)\n"
     )
     found = check_file(str(bad), "toy/bad_kernel2.py", _installed_signatures())
-    rules = sorted(f.rule for f in found)
-    assert "ast-shard-map-import" in rules
-    # on an installed JAX without check_vma, the raw call is kwarg drift
-    from slate_tpu.parallel.comm import _SHARD_MAP_KW
-
-    if "check_vma" not in _SHARD_MAP_KW:
-        assert "ast-kwargs" in rules
+    assert "ast-shard-map-import" in sorted(f.rule for f in found)
 
 
 def test_ast_pass_catches_aliased_collectives(tmp_path):
@@ -238,7 +239,7 @@ def test_ast_pass_catches_aliased_collectives(tmp_path):
     assert len(msgs) == 2 and "psum" in msgs[1] and "all_gather" in msgs[0], msgs
 
 
-def test_shard_map_compat_rejects_conflicting_aliases():
+def test_shard_map_compat_rejects_retired_check_rep():
     import pytest as _pytest
 
     from jax.sharding import Mesh, PartitionSpec as P
@@ -246,13 +247,12 @@ def test_shard_map_compat_rejects_conflicting_aliases():
     from slate_tpu.parallel.comm import shard_map_compat
 
     mesh = Mesh(np.asarray(cpu_devices(4)).reshape(2, 2), ("p", "q"))
-    with _pytest.raises(TypeError, match="conflicting"):
+    with _pytest.raises(TypeError, match="check_rep"):
         shard_map_compat(
             lambda x: x,
             mesh=mesh,
             in_specs=(P("p", "q"),),
             out_specs=P("p", "q"),
-            check_vma=True,
             check_rep=False,
         )
 

@@ -17,10 +17,11 @@ crash, the r02-r05 empty tails) are recorded under ``config.missing``
 with their rc — absence of data is part of the trajectory, not silently
 dropped.
 
-The single-chip BENCH_r0N.json partials fold in too (ISSUE 17): r04's
-fully-parsed headline + extras, and the ``[bench ...s] extra: k = v``
-progress lines recovered from r05's rc=124 timeout tail — a killed run's
-completed phases are data, not garbage.  The report's config is stamped
+Single-chip ``BENCH_r0N.json`` partials fold in too (ISSUE 17) where
+present: a fully-parsed headline + extras, or the ``[bench ...s] extra:
+k = v`` progress lines recovered from a killed run's tail — a killed
+run's completed phases are data, not garbage.  No such record is in the
+repo now; a glob that matches nothing yields an empty curve and exit 0.  The report's config is stamped
 with the emitting trace_id (the RunReport-meta convention the obs.live
 ledger uses), so this artifact is joinable against traces and ledger
 entries.
@@ -221,8 +222,8 @@ def main(argv=None) -> int:
 
     paths = sorted(glob.glob(args.glob))
     if not paths:
-        print(f"scaling_report: no artifacts match {args.glob}")
-        return 2
+        print(f"scaling_report: no artifacts match {args.glob}; "
+              "writing an empty curve")
     bench_paths = sorted(glob.glob(args.bench_glob)) if args.bench_glob else []
     rep = build(paths, args.partial, bench_paths)
 
