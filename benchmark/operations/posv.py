@@ -1,0 +1,11 @@
+"""SPD solve by Cholesky (LAPACK posv): LAWN 41's potrf and potrs flops
+for every problem of a call.  Inputs, least bytes and the check are the
+square solve's (``benchmark/solve.py``)."""
+
+from benchmark import flops
+from benchmark.solve import (call_bytes, input_body, outputs, per_problem,  # noqa: F401
+                             problems, readings)
+
+
+def call_flops(traffic) -> float:
+    return problems(traffic) * flops.posv(traffic["n"], traffic["nrhs"])
