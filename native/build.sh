@@ -8,7 +8,6 @@ PYINC=$(python3 -c "import sysconfig; print(sysconfig.get_paths()['include'])")
 PYLIB=$(python3 -c "import sysconfig; print(sysconfig.get_config_var('LIBDIR'))")
 python3 ../tools/gen_c_api.py
 python3 ../tools/gen_scalapack_api.py
-$CXX -O2 -fPIC -shared -o lib/libslatetpu_trace.so trace_svg.cc
 $CXX -O2 -fPIC -shared -I"$PYINC" -o lib/libslatetpu_c.so c_api.cc c_api_generated.cc -L"$PYLIB" -lpython3.12
 $CXX -O2 -fPIC -shared -I"$PYINC" -o lib/libslatetpu_scalapack.so c_api.cc c_api_generated.cc scalapack_api_generated.cc -L"$PYLIB" -lpython3.12
 echo "built: $(ls lib)"

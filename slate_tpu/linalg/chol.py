@@ -46,18 +46,26 @@ ArrayLike = Union[jax.Array, BaseMatrix]
 
 def _potrf_lower(a: jax.Array) -> jax.Array:
     """Recursive lower Cholesky of a full Hermitian array; NaN-poisons on
-    non-SPD input (converted to an info code by the driver)."""
+    non-SPD input (converted to an info code by the driver).  Leaf
+    factors and panel solves sit under the ``panel`` phase scope, the
+    herk updates under ``bulk``."""
+    from ..parallel.comm import phase_scope
+
     n = a.shape[0]
     if n <= _NB:
-        return jax.lax.linalg.cholesky(a)
+        with phase_scope("panel"):
+            return jax.lax.linalg.cholesky(a)
     h = _split(n)
     a11, a21, a22 = a[:h, :h], a[h:, :h], a[h:, h:]
     l11 = _potrf_lower(a11)
-    # L21 = A21 * L11^-H  (solve X L11^H = A21)
-    l21 = trsm_array(Side.Right, Uplo.Lower, Op.ConjTrans, Diag.NonUnit, 1.0, l11, a21)
-    # trailing update: A22 - L21 L21^H (herk)
-    upd = matmul(l21, jnp.conj(l21).T)
-    l22 = _potrf_lower(a22 - upd.astype(a.dtype))
+    with phase_scope("panel"):
+        # L21 = A21 * L11^-H  (solve X L11^H = A21)
+        l21 = trsm_array(Side.Right, Uplo.Lower, Op.ConjTrans, Diag.NonUnit, 1.0, l11, a21)
+    with phase_scope("bulk"):
+        # trailing update: A22 - L21 L21^H (herk)
+        upd = matmul(l21, jnp.conj(l21).T)
+        a22 = a22 - upd.astype(a.dtype)
+    l22 = _potrf_lower(a22)
     z = jnp.zeros((h, n - h), a.dtype)
     return jnp.block([[l11, z], [l21, l22]])
 
@@ -69,7 +77,13 @@ def _potrf_scan(a: jax.Array, nb: int = 256, nbuckets: int = 4) -> jax.Array:
     segmented into ``nbuckets`` statically-shrinking trailing views (cf.
     parallel.dist_chol), cutting the HBM-bound masked trailing traffic to
     ~0.47x of the full-width form at 4 buckets; every flop is an MXU
-    gemm.  Input must be full Hermitian."""
+    gemm.  Input must be full Hermitian.
+
+    Each step's work sits under the ``panel`` / ``bulk`` phase scopes and
+    the bucket boundaries under ``regroup`` (``comm.phase_scope``), so a
+    profile of the compiled program names every op's phase."""
+    from ..parallel.comm import phase_scope
+
     n = a.shape[0]
     nsteps = -(-n // nb)
     np_ = nsteps * nb
@@ -84,45 +98,50 @@ def _potrf_scan(a: jax.Array, nb: int = 256, nbuckets: int = 4) -> jax.Array:
         if k0 == k1:
             continue
         off = k0 * nb
-        view = ap[off:, off:]
+        with phase_scope("regroup"):
+            view = ap[off:, off:]
         nv = np_ - off
         rows = jnp.arange(nv)
 
         def step(k, view, off=off, nv=nv, rows=rows):
-            kk = k * nb - off  # view-local panel head
-            dblk = jax.lax.dynamic_slice(view, (kk, kk), (nb, nb))
-            col = jax.lax.dynamic_slice(view, (0, kk), (nv, nb))
-            # panel solve as explicit-inverse gemm (MAGMA-style trtri+gemm):
-            # XLA's big-rhs triangular_solve runs at ~1/10 the MXU matmul
-            # rate at (32768, 256) (measured 46 vs 4 ms), and inverting only
-            # the nb x nb diag block keeps the backward error at the same
-            # O(eps * cond(L_kk)) class.  Under Option.PanelImpl=pallas the
-            # factor + inverse pair is ONE fused on-chip kernel instead of
-            # the per-column cholesky + triangular_solve dispatch chain.
-            if panel_engaged(view.dtype, nb * nb * view.dtype.itemsize):
-                ld, linv = chol_diag_inv_pallas(dblk)
-            else:
-                ld = jax.lax.linalg.cholesky(dblk)
-                eye_nb = jnp.eye(nb, dtype=view.dtype)
-                linv = jax.lax.linalg.triangular_solve(
-                    ld[None], eye_nb[None], left_side=True, lower=True,
-                    transpose_a=False,
-                )[0]
-            linv_h = jnp.conj(linv).T if cplx else linv.T
-            sol = matmul(col, linv_h).astype(view.dtype)
-            below = (rows >= kk + nb)[:, None]
-            ondiag = ((rows >= kk) & (rows < kk + nb))[:, None]
-            dpat = jax.lax.dynamic_update_slice(
-                jnp.zeros((nv, nb), view.dtype), jnp.tril(ld), (kk, 0)
-            )
-            newcol = jnp.where(below, sol, jnp.where(ondiag, dpat, col))
-            view = jax.lax.dynamic_update_slice(view, newcol, (0, kk))
-            l21 = newcol * below.astype(view.dtype)
-            upd = matmul(l21, jnp.conj(l21).T if cplx else l21.T)
-            return view - upd.astype(view.dtype)
+            with phase_scope("panel", k):
+                kk = k * nb - off  # view-local panel head
+                dblk = jax.lax.dynamic_slice(view, (kk, kk), (nb, nb))
+                col = jax.lax.dynamic_slice(view, (0, kk), (nv, nb))
+                # panel solve as explicit-inverse gemm (MAGMA-style
+                # trtri+gemm): XLA's big-rhs triangular_solve runs at ~1/10
+                # the MXU matmul rate at (32768, 256) (measured 46 vs 4
+                # ms), and inverting only the nb x nb diag block keeps the
+                # backward error at the same O(eps * cond(L_kk)) class.
+                # Under Option.PanelImpl=pallas the factor + inverse pair
+                # is ONE fused on-chip kernel instead of the per-column
+                # cholesky + triangular_solve dispatch chain.
+                if panel_engaged(view.dtype, nb * nb * view.dtype.itemsize):
+                    ld, linv = chol_diag_inv_pallas(dblk)
+                else:
+                    ld = jax.lax.linalg.cholesky(dblk)
+                    eye_nb = jnp.eye(nb, dtype=view.dtype)
+                    linv = jax.lax.linalg.triangular_solve(
+                        ld[None], eye_nb[None], left_side=True, lower=True,
+                        transpose_a=False,
+                    )[0]
+                linv_h = jnp.conj(linv).T if cplx else linv.T
+                sol = matmul(col, linv_h).astype(view.dtype)
+                below = (rows >= kk + nb)[:, None]
+                ondiag = ((rows >= kk) & (rows < kk + nb))[:, None]
+                dpat = jax.lax.dynamic_update_slice(
+                    jnp.zeros((nv, nb), view.dtype), jnp.tril(ld), (kk, 0)
+                )
+                newcol = jnp.where(below, sol, jnp.where(ondiag, dpat, col))
+                view = jax.lax.dynamic_update_slice(view, newcol, (0, kk))
+            with phase_scope("bulk", k):
+                l21 = newcol * below.astype(view.dtype)
+                upd = matmul(l21, jnp.conj(l21).T if cplx else l21.T)
+                return view - upd.astype(view.dtype)
 
         view = jax.lax.fori_loop(k0, k1, step, view)
-        ap = ap.at[off:, off:].set(view)
+        with phase_scope("regroup"):
+            ap = ap.at[off:, off:].set(view)
     return ap[:n, :n]
 
 
@@ -517,9 +536,12 @@ def potrs(factor: TriangularMatrix, b: ArrayLike):
 
 @instrument("posv_array")
 def posv_array(a: jax.Array, b: jax.Array, uplo: Uplo = Uplo.Lower):
-    """Factor + solve (src/posv.cc). Returns (x, factor, info)."""
-    f, info = potrf_array(a, uplo)
-    x = potrs_array(f, b, uplo)
+    """Factor + solve (src/posv.cc). Returns (x, factor, info).  The two
+    halves sit under the ``potrf`` / ``potrs`` stage scopes."""
+    with jax.named_scope("potrf"):
+        f, info = potrf_array(a, uplo)
+    with jax.named_scope("potrs"):
+        x = potrs_array(f, b, uplo)
     return x, f, info
 
 

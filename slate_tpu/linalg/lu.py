@@ -97,18 +97,27 @@ def _panel_lu(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 
 def _getrf_rec(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Recursive LU of (m, n), m >= n. Returns (lu, perm)."""
+    """Recursive LU of (m, n), m >= n. Returns (lu, perm).  Leaf panels
+    and the U12 solve sit under the ``panel`` phase scope, the row
+    gathers under ``swap``, the Schur update under ``bulk``."""
+    from ..parallel.comm import phase_scope
+
     m, n = a.shape
     if n <= _PANEL_W:
-        return _panel_lu(a)
+        with phase_scope("panel"):
+            return _panel_lu(a)
     h = _split_panel(n)
     lu1, p1 = _getrf_rec(a[:, :h])
-    a2 = a[:, h:][p1]
+    with phase_scope("swap"):
+        a2 = a[:, h:][p1]
     l11 = lu1[:h, :h]
-    u12 = trsm_array(Side.Left, Uplo.Lower, Op.NoTrans, Diag.Unit, 1.0, l11, a2[:h])
-    s = a2[h:] - matmul(lu1[h:, :h], u12).astype(a.dtype)
+    with phase_scope("panel"):
+        u12 = trsm_array(Side.Left, Uplo.Lower, Op.NoTrans, Diag.Unit, 1.0, l11, a2[:h])
+    with phase_scope("bulk"):
+        s = a2[h:] - matmul(lu1[h:, :h], u12).astype(a.dtype)
     lu2, p2 = _getrf_rec(s)
-    l21 = lu1[h:, :h][p2]
+    with phase_scope("swap"):
+        l21 = lu1[h:, :h][p2]
     top = jnp.concatenate([lu1[:h], u12.reshape(h, n - h)], axis=1)
     bot = jnp.concatenate([l21, lu2], axis=1)
     perm = jnp.concatenate([p1[:h], p1[h:][p2]])
@@ -377,35 +386,41 @@ def _apply_bounded_perm(x: jax.Array, pv: jax.Array, targets: jax.Array):
 
 def _scan_step_update(out, pan, perm, piv, kk, nb: int, pv=None):
     """Shared tail of one scanned panel step: apply the panel's row swaps
-    (bounded scatter — a panel moves at most 2nb rows), write the factored
-    panel back, masked trsm for the U row block, masked trailing gemm."""
+    (bounded scatter — a panel moves at most 2nb rows; phase ``swap``),
+    write the factored panel back and solve the U row block (``panel``),
+    masked trailing gemm (``bulk``)."""
+    from ..parallel.comm import phase_scope
+
     mp, n = out.shape
     rows = jnp.arange(mp)
     cols = jnp.arange(n)
 
-    if pv is None:
-        pv = _swaps_to_perm(piv, kk, mp, nb)
-    targets = jnp.concatenate([kk + jnp.arange(nb), piv])
-    out = _apply_bounded_perm(out, pv, targets)
-    perm = _apply_bounded_perm(perm, pv, targets)
-    out = jax.lax.dynamic_update_slice(out, pan, (0, kk))
-    l11 = tri_project(
-        jax.lax.dynamic_slice(pan, (kk, 0), (nb, nb)), Uplo.Lower, Diag.Unit
-    )
-    rowblk = jax.lax.dynamic_slice(out, (kk, 0), (nb, n))
-    # row solve as explicit-inverse gemm (cf. chol._potrf_scan): the
-    # wide-rhs triangular_solve runs ~10x below the MXU matmul rate
-    linv = jax.lax.linalg.triangular_solve(
-        l11[None], jnp.eye(nb, dtype=out.dtype)[None], left_side=True,
-        lower=True, transpose_a=False, unit_diagonal=True,
-    )[0]
-    u12 = matmul(linv, rowblk).astype(out.dtype)
-    right = (cols >= kk + nb)[None, :]
-    rowblk = jnp.where(right, u12, rowblk)
-    out = jax.lax.dynamic_update_slice(out, rowblk, (kk, 0))
-    l21 = pan * ((rows >= kk + nb)[:, None]).astype(pan.dtype)
-    u12m = rowblk * right.astype(pan.dtype)
-    out = out - matmul(l21, u12m).astype(out.dtype)
+    with phase_scope("swap"):
+        if pv is None:
+            pv = _swaps_to_perm(piv, kk, mp, nb)
+        targets = jnp.concatenate([kk + jnp.arange(nb), piv])
+        out = _apply_bounded_perm(out, pv, targets)
+        perm = _apply_bounded_perm(perm, pv, targets)
+    with phase_scope("panel"):
+        out = jax.lax.dynamic_update_slice(out, pan, (0, kk))
+        l11 = tri_project(
+            jax.lax.dynamic_slice(pan, (kk, 0), (nb, nb)), Uplo.Lower, Diag.Unit
+        )
+        rowblk = jax.lax.dynamic_slice(out, (kk, 0), (nb, n))
+        # row solve as explicit-inverse gemm (cf. chol._potrf_scan): the
+        # wide-rhs triangular_solve runs ~10x below the MXU matmul rate
+        linv = jax.lax.linalg.triangular_solve(
+            l11[None], jnp.eye(nb, dtype=out.dtype)[None], left_side=True,
+            lower=True, transpose_a=False, unit_diagonal=True,
+        )[0]
+        u12 = matmul(linv, rowblk).astype(out.dtype)
+        right = (cols >= kk + nb)[None, :]
+        rowblk = jnp.where(right, u12, rowblk)
+        out = jax.lax.dynamic_update_slice(out, rowblk, (kk, 0))
+    with phase_scope("bulk"):
+        l21 = pan * ((rows >= kk + nb)[:, None]).astype(pan.dtype)
+        u12m = rowblk * right.astype(pan.dtype)
+        out = out - matmul(l21, u12m).astype(out.dtype)
     return out, perm
 
 
@@ -425,8 +440,12 @@ def getrf_scan_array(
     ``out[off:, off:]``, cutting the HBM-bound masked trailing traffic to
     ~0.47x of the full-width form at 4 buckets; finished L columns receive
     the bucket's composed row permutation in one gather at bucket end
-    (LAPACK's deferred laswp on columns < k).
+    (LAPACK's deferred laswp on columns < k).  Phase scopes as in
+    ``chol._potrf_scan``: ``panel``, ``swap``, ``bulk`` per step,
+    ``regroup`` at the bucket boundaries.
     """
+    from ..parallel.comm import phase_scope
+
     m, n = a.shape
     nmin = min(m, n)
     nsteps = -(-nmin // nb)
@@ -443,15 +462,17 @@ def getrf_scan_array(
         if k0 == k1:
             continue
         off = k0 * nb
-        view = out[off:, off:]
+        with phase_scope("regroup"):
+            view = out[off:, off:]
         mv = mp - off
 
         def body(k, carry, off=off, mv=mv):
             view, pl = carry
             kk = k * nb - off  # view-local column/row of the panel head
-            panel = jax.lax.dynamic_slice(view, (0, kk), (mv, nb))
-            # global masks shift uniformly: local row r is global off + r
-            pan, piv = _panel_lu_masked(panel, kk, nmin - off, m - off)
+            with phase_scope("panel", k):
+                panel = jax.lax.dynamic_slice(view, (0, kk), (mv, nb))
+                # global masks shift uniformly: local row r is global off + r
+                pan, piv = _panel_lu_masked(panel, kk, nmin - off, m - off)
             # the factored panel is already in post-swap row order; swapping
             # `view` rows then overwriting columns [kk, kk+nb) reconciles both
             return _scan_step_update(view, pan, pl, piv, kk, nb)
@@ -459,10 +480,12 @@ def getrf_scan_array(
         view, pl = jax.lax.fori_loop(
             k0, k1, body, (view, jnp.arange(mv))
         )
-        out = out.at[off:, off:].set(view)
-        if off:
-            out = out.at[off:, :off].set(out[off:, :off][pl])
-        perm = perm.at[off:].set(perm[off:][pl])
+        with phase_scope("regroup"):
+            out = out.at[off:, off:].set(view)
+        with phase_scope("swap"):
+            if off:
+                out = out.at[off:, :off].set(out[off:, :off][pl])
+            perm = perm.at[off:].set(perm[off:][pl])
     return LUFactors(out[:m, :n], perm[:m], _lu_info(out[:m, :n]))
 
 
@@ -637,20 +660,23 @@ def getrs_array(f: LUFactors, b: jax.Array, op: Op = Op.NoTrans) -> jax.Array:
 
 @instrument("gesv_array")
 def gesv_array(a: jax.Array, b: jax.Array, method: MethodLU = MethodLU.PartialPiv):
-    """Factor + solve (src/gesv.cc). Returns (x, factors)."""
-    if method == MethodLU.PartialPiv:
-        f = getrf_array(a)
-    elif method == MethodLU.CALU:
-        f = getrf_tntpiv_array(a)
-    elif method == MethodLU.NoPiv:
-        f = getrf_nopiv_array(a)
-    elif method == MethodLU.RBT:
+    """Factor + solve (src/gesv.cc). Returns (x, factors).  The factor
+    sits under the ``getrf`` stage scope, the solves under ``trsm``."""
+    if method == MethodLU.RBT:
         from .rbt import gesv_rbt_array
 
         return gesv_rbt_array(a, b)
-    else:
-        raise ValueError(method)
-    return getrs_array(f, b), f
+    with jax.named_scope("getrf"):
+        if method == MethodLU.PartialPiv:
+            f = getrf_array(a)
+        elif method == MethodLU.CALU:
+            f = getrf_tntpiv_array(a)
+        elif method == MethodLU.NoPiv:
+            f = getrf_nopiv_array(a)
+        else:
+            raise ValueError(method)
+    with jax.named_scope("trsm"):
+        return getrs_array(f, b), f
 
 
 def getri_array(f: LUFactors) -> jax.Array:

@@ -10,11 +10,12 @@ map) fused with xprof-style annotation:
   records nested spans, wall/compile/execute phases, comm bytes (absorbed
   from the parallel.comm trace-time audit) and XLA flop/byte estimates.
 - ``driver_span(name, **tags)`` is the instrumentation context; the
-  ``instrument`` decorator wires a driver in permanently with near-zero
-  disabled overhead.
-- ``perfetto.write_chrome_trace(path)`` exports everything as a Chrome
-  trace-event JSON that loads in ui.perfetto.dev; span names also bridge
-  into real TPU xprof traces via ``jax.profiler.TraceAnnotation``.
+  ``instrument`` decorator wires a driver in permanently.  Both always
+  open a ``jax.profiler.TraceAnnotation`` named ``PROFILER_PREFIX +
+  name`` (a microsecond with no profiler running), so a ``jax.profiler``
+  trace holds the program's spans with or without ``enable()``.
+- ``perfetto.write_chrome_trace(path)`` exports the recorded spans as a
+  Chrome trace-event JSON that loads in ui.perfetto.dev.
 - ``report`` holds the versioned RunReport schema every perf artifact
   (bench.py, tester.py, tools/northstar_sweep.py, CI smoke) emits
   through, plus the ``python -m slate_tpu.obs.report`` CLI with
@@ -49,6 +50,7 @@ from .context import (  # noqa: F401
 from .metrics import REGISTRY, MetricsRegistry, flatten_snapshot  # noqa: F401
 from .span import (  # noqa: F401
     FINISHED,
+    PROFILER_PREFIX,
     Span,
     cost_analysis_of,
     current_span,
@@ -71,6 +73,7 @@ __all__ = [
     "MetricsRegistry",
     "flatten_snapshot",
     "FINISHED",
+    "PROFILER_PREFIX",
     "Span",
     "cost_analysis_of",
     "current_span",

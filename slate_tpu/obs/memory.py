@@ -310,12 +310,14 @@ def sample(tag: str, **extra) -> dict:
     return s
 
 
-def sample_span(span) -> None:
-    """driver_span exit hook: sample at TOP-LEVEL span boundaries only
-    (nested phase spans would walk live_arrays per phase for the same
-    information).  Attaches the live-byte total to the span's metrics so
-    it rides into RunReport span rows."""
-    if span.depth != 0 or not sampling_active():
+def sample_span(span, outermost: bool) -> None:
+    """driver_span exit hook: sample at OUTERMOST span boundaries only —
+    top-level spans and the first span of each request, through which
+    the request's trace_id reaches the sample (nested phase spans would
+    walk live_arrays per phase for the same information).  Attaches the
+    live-byte total to the span's metrics so it rides into RunReport span
+    rows."""
+    if not outermost or not sampling_active():
         return
     try:
         s = sample(span.name)

@@ -1,8 +1,8 @@
 """Tagged metrics registry: counters, gauges, histograms.
 
 The single sink every instrumentation source feeds — driver spans
-(obs.span), the comm-byte audit (parallel/comm.py via span absorption),
-and the coarse named timers (utils/trace.py ``block``).  Deliberately
+(obs.span) and the comm-byte audit (parallel/comm.py via span
+absorption).  Deliberately
 tiny: a metric is (name, frozen tag set) -> scalar state, snapshots are
 plain JSON-able dicts, and nothing here imports jax so the registry can
 be used from tooling that never builds a mesh.

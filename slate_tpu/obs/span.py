@@ -4,14 +4,19 @@ driver flows through.
 ``driver_span(name, **tags)`` is the TPU-native fusion of the reference's
 ``trace::Block`` RAII regions with xprof-style annotation: it times the
 region, nests (thread-local stack), bridges the name into real TPU
-profiles via ``jax.profiler.TraceAnnotation`` when available, and absorbs
-the comm-byte audit (parallel/comm.py) so every collective traced inside
-the span lands in the metrics registry tagged with the span's name.
+profiles via ``jax.profiler.TraceAnnotation``, and absorbs the comm-byte
+audit (parallel/comm.py) so every collective traced inside the span lands
+in the metrics registry tagged with the span's name.
 
-Everything is gated on ``enable()`` / the ``SLATE_TPU_OBS`` env var; when
-disabled a span is a shared null object and the per-call overhead is one
-attribute load and one ``if`` — cheap enough to leave permanently wired
-into every driver (the acceptance bar: not measurable in tier-1 runtime).
+The profiler annotation is always on: with observability off a span (and
+an ``instrument``-ed driver) opens only the ``TraceAnnotation`` named
+``PROFILER_PREFIX + name`` and yields a shared null object — no registry,
+no comm audit, no memory sample, no record.  An annotation costs well
+under 2 us with no profiler running (0.49 us bare, 1.9 us through this
+generator context manager, measured on a v5e host), so every driver
+keeps it; a ``jax.profiler`` trace then holds the program's spans on the
+device ops' clock without ``enable()``.  Everything else is gated on
+``enable()`` / the ``SLATE_TPU_OBS`` env var.
 """
 
 from __future__ import annotations
@@ -23,7 +28,14 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from .metrics import REGISTRY
+
+# profiler-side span names carry this prefix, so a trace reader can tell
+# the program's spans from JAX's own host events; registry tags keep the
+# bare name
+PROFILER_PREFIX = "slate_tpu/"
 
 # finished-span records for the Perfetto exporter; bounded so a long
 # sweep cannot grow without limit
@@ -169,7 +181,8 @@ def driver_span(name: str, **tags):
     every driver in this repo; lint and the audit tools are
     single-threaded by construction)."""
     if not _enabled:
-        yield _NULL
+        with TraceAnnotation(PROFILER_PREFIX + name):
+            yield _NULL
         return
 
     from ..parallel import comm  # lazy: obs must not import parallel at module load
@@ -189,14 +202,8 @@ def driver_span(name: str, **tags):
     span = Span(name, tags, len(st), parent.name if parent else None)
     st.append(span)
 
-    ann = None
-    try:  # xprof bridge — slate phase names inside real TPU traces
-        import jax
-
-        ann = jax.profiler.TraceAnnotation(name)
-        ann.__enter__()
-    except Exception:
-        ann = None
+    ann = TraceAnnotation(PROFILER_PREFIX + name)
+    ann.__enter__()
 
     # capture audited collectives traced inside this span; propagate=True
     # re-appends the records outward on exit so enclosing audits
@@ -216,11 +223,7 @@ def driver_span(name: str, **tags):
         span.t1 = time.perf_counter()
         sched_cm.__exit__(None, None, None)
         audit_cm.__exit__(None, None, None)
-        if ann is not None:
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:
-                pass
+        ann.__exit__(None, None, None)
         st.pop()
 
         dur = span.t1 - span.t0
@@ -261,14 +264,18 @@ def driver_span(name: str, **tags):
             for op, nbytes, mult, _ph, st, pairs in sched_records
             if pairs
         ][:64]
-        # memory sampling at driver_span boundaries (ISSUE 9): top-level
-        # spans only, and only while obs is on — the disabled path above
-        # never reaches here, so disabled mode makes zero live_arrays
-        # calls (asserted by tests/test_mem.py)
+        # memory sampling at driver_span boundaries: top-level
+        # spans and the outermost span of each request, and only while
+        # obs is on — the disabled path above never reaches here, so
+        # disabled mode makes zero live_arrays calls (asserted by
+        # tests/test_mem.py)
+        rid = tags.get("trace_id")
+        outermost = parent is None or (
+            rid is not None and parent.tags.get("trace_id") != rid)
         try:
             from . import memory as _memory
 
-            _memory.sample_span(span)
+            _memory.sample_span(span, outermost)
         except Exception:
             pass
         record = {
@@ -325,19 +332,20 @@ def _oom_note(name: str, exc: BaseException) -> None:
 
 def instrument(name: Optional[str] = None, **static_tags) -> Callable:
     """Decorator wiring a driver into the observability layer.  With
-    observability disabled the wrapper is a bare passthrough (plus an
-    exception-path OOM forensics hook — no jaxpr change, no overhead off
-    the error path); enabled, the call runs inside
-    ``driver_span(name, **shape_tags)``."""
+    observability disabled the call runs inside the span's profiler
+    annotation only (plus an exception-path OOM forensics hook — no jaxpr
+    change); enabled, it runs inside ``driver_span(name, **shape_tags)``."""
 
     def deco(fn: Callable) -> Callable:
         span_name = name or fn.__name__
+        profiler_name = PROFILER_PREFIX + span_name
 
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
             if not _enabled:
                 try:
-                    return fn(*args, **kwargs)
+                    with TraceAnnotation(profiler_name):
+                        return fn(*args, **kwargs)
                 except Exception as e:
                     _oom_note(span_name, e)
                     raise

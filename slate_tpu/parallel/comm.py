@@ -102,13 +102,26 @@ def sched_audit(propagate: bool = False):
 
 @contextlib.contextmanager
 def phase_scope(phase: str, step=None):
-    """Tag collectives traced inside as belonging to loop phase ``phase``
-    of step ``step`` (``panel`` / ``bcast`` / ``bulk``).  Pure trace-time
-    bookkeeping: no jaxpr change, ever — kernels stay trace-identical
-    whether or not a schedule capture is listening."""
+    """Tag work traced inside as loop phase ``phase`` of step ``step``.
+
+    Two marks, both metadata only — no op, numeric or collective changes,
+    and the jaxpr stays identical:
+
+    - collectives traced inside carry the phase in ``sched_audit``
+      records (the obs.schedule capture);
+    - every op traced inside sits under ``jax.named_scope(phase)``, so
+      the compiled program's op metadata (the ``op_name`` a profiler
+      trace reports per device op) names the phase.
+
+    The factor loops' vocabulary: ``panel`` (diagonal-block factor, panel
+    solves, the pivot search), ``swap`` (row interchanges), ``bcast``
+    (panel broadcasts), ``bulk`` (the trailing update, ``narrow`` pieces
+    included) and ``regroup`` (bucket-boundary slices and writes of the
+    scanned single-chip factors)."""
     _PHASE_CTX.append((phase, _step_id(step)))
     try:
-        yield
+        with jax.named_scope(phase):
+            yield
     finally:
         _PHASE_CTX.pop()
 

@@ -134,15 +134,17 @@ def _cyclic_tiles(a: jax.Array, mesh: Mesh, nb: int, diag_pad_one: bool) -> jax.
     the pad, reshape and permutation each materialise the whole matrix
     on one device: a 32768^2 f32 operand on a 2x2 v5e mesh ran out of HBM
     at the permutation (PR 21).  Compiled together, XLA partitions them
-    and the result is born sharded."""
+    and the result is born sharded.  Its ops sit under the
+    ``redistribute`` stage scope."""
     m, n = a.shape
     mp, np_ = padded_tiles(m, nb, mesh) * nb, padded_tiles(n, nb, mesh) * nb
-    a = jnp.pad(a, ((0, mp - m), (0, np_ - n)))
-    if diag_pad_one:
-        d = jnp.arange(min(m, n), min(mp, np_))
-        a = a.at[d, d].set(1)
-    t = to_cyclic(to_tiles(a, nb), *mesh_shape(mesh))
-    return lax.with_sharding_constraint(t, tile_sharding(mesh))
+    with jax.named_scope("redistribute"):
+        a = jnp.pad(a, ((0, mp - m), (0, np_ - n)))
+        if diag_pad_one:
+            d = jnp.arange(min(m, n), min(mp, np_))
+            a = a.at[d, d].set(1)
+        t = to_cyclic(to_tiles(a, nb), *mesh_shape(mesh))
+        return lax.with_sharding_constraint(t, tile_sharding(mesh))
 
 
 def to_dense(d: DistMatrix) -> jax.Array:

@@ -315,10 +315,12 @@ def _nopiv_step(t_loc, k, p, q, i_log, j_log, r, c, roff=0, coff=0, panel_done=F
     """One FULL right-looking LU tile step — the strict schedule: panel
     phase followed immediately by the trailing gemm (the depth-0 form the
     pipelined kernels must reproduce bitwise)."""
-    t_loc, payload = _nopiv_panel(
-        t_loc, k, p, q, i_log, j_log, r, c, roff, coff, panel_done
-    )
-    return _nopiv_bulk(t_loc, payload)
+    with phase_scope("panel", k):
+        t_loc, payload = _nopiv_panel(
+            t_loc, k, p, q, i_log, j_log, r, c, roff, coff, panel_done
+        )
+    with phase_scope("bulk", k):
+        return _nopiv_bulk(t_loc, payload)
 
 
 def _lu_info_dist(t_loc, i_log, j_log, nt, nb):
@@ -666,7 +668,8 @@ def _tntpiv_jit(at, mesh, p, q, nt, m_true, la, bi, pi, nm=False):
                 t_loc, rowperm, pl, g = out
             else:
                 t_loc, rowperm, pl = out
-            t_loc = _nopiv_bulk(t_loc, pl)  # drain the last deferred gemm
+            with phase_scope("bulk", nt - 1):
+                t_loc = _nopiv_bulk(t_loc, pl)  # drain the last deferred gemm
         info = _lu_info_dist(t_loc, i_log, j_log, nt, nb)
         if nm:
             gz = _lu_growth_out(
@@ -926,10 +929,12 @@ def _pp_panel_and_swaps(t_loc, rowperm, k, p, q, r, c, nt, m_true,
 
     Returns (t_loc, rowperm): all nb transpositions applied and the
     factored panel written back into the owning column's window rows."""
-    flat, piv_pos = _pp_panel_factor(t_loc, k, p, q, r, c, nt, m_true, s_r, wlr)
-    return _pp_apply_swaps(
-        t_loc, rowperm, flat, piv_pos, k, p, q, r, c, nt, s_r, wlr, s_cw, wlsw
-    )
+    with phase_scope("panel", k):
+        flat, piv_pos = _pp_panel_factor(t_loc, k, p, q, r, c, nt, m_true, s_r, wlr)
+    with phase_scope("swap", k):
+        return _pp_apply_swaps(
+            t_loc, rowperm, flat, piv_pos, k, p, q, r, c, nt, s_r, wlr, s_cw, wlsw
+        )
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9))
@@ -988,18 +993,23 @@ def _pp_jit(at, mesh, p, q, nt, m_true, la, bi, pi, nm=False):
                     g = probe(t_loc, g)
                 else:
                     t_loc, rowperm, pl = carry
-                t_loc = _nopiv_narrow(t_loc, pl, k, p, q, with_row=False)
-                flat, piv_pos = _pp_panel_factor(
-                    t_loc, k, p, q, r, c, nt, m_true, zero, mtl
-                )
-                t_loc = _nopiv_bulk(t_loc, pl, excl_kc=k // q)
-                t_loc, rowperm = _pp_apply_swaps(
-                    t_loc, rowperm, flat, piv_pos, k, p, q, r, c, nt,
-                    zero, mtl, zero, ntl,
-                )
-                t_loc, pl_new = _nopiv_panel(
-                    t_loc, k, p, q, i_log, j_log, r, c, panel_done=True
-                )
+                with phase_scope("bulk", k):
+                    t_loc = _nopiv_narrow(t_loc, pl, k, p, q, with_row=False)
+                with phase_scope("panel", k):
+                    flat, piv_pos = _pp_panel_factor(
+                        t_loc, k, p, q, r, c, nt, m_true, zero, mtl
+                    )
+                with phase_scope("bulk", k):
+                    t_loc = _nopiv_bulk(t_loc, pl, excl_kc=k // q)
+                with phase_scope("swap", k):
+                    t_loc, rowperm = _pp_apply_swaps(
+                        t_loc, rowperm, flat, piv_pos, k, p, q, r, c, nt,
+                        zero, mtl, zero, ntl,
+                    )
+                with phase_scope("panel", k):
+                    t_loc, pl_new = _nopiv_panel(
+                        t_loc, k, p, q, i_log, j_log, r, c, panel_done=True
+                    )
                 return ((t_loc, rowperm, pl_new, g) if nm
                         else (t_loc, rowperm, pl_new))
 
@@ -1015,7 +1025,8 @@ def _pp_jit(at, mesh, p, q, nt, m_true, la, bi, pi, nm=False):
                 t_loc, rowperm, pl, g = out
             else:
                 t_loc, rowperm, pl = out
-            t_loc = _nopiv_bulk(t_loc, pl)  # drain the last deferred gemm
+            with phase_scope("bulk", nt - 1):
+                t_loc = _nopiv_bulk(t_loc, pl)  # drain the last deferred gemm
         info = _lu_info_dist(t_loc, i_log, j_log, nt, nb)
         if nm:
             gz = _lu_growth_out(
@@ -1027,8 +1038,10 @@ def _pp_jit(at, mesh, p, q, nt, m_true, la, bi, pi, nm=False):
     if nm:
         out_specs = out_specs + (P(ROW_AXIS, COL_AXIS),)
     # post-pivot row solve dispatches by PanelImpl; update pinned xla —
-    # see _tntpiv_jit
-    with bcast_impl_scope(bi), panel_impl_scope(pi), update_impl_scope("xla"):
+    # see _tntpiv_jit.  The program's ops sit under the ``getrf`` stage
+    # scope, each step's under its phase (panel, swap, bcast, bulk)
+    with bcast_impl_scope(bi), panel_impl_scope(pi), update_impl_scope("xla"), \
+            jax.named_scope("getrf"):
         out = shard_map_compat(
             kernel,
             mesh=mesh,
@@ -1215,6 +1228,8 @@ def _permute_rows_jit(bt, perm, mesh, p, q):
         new = all_b[st % p, st // p, :, sr, :]  # (mtl, nb, ntl, nb)
         return jnp.transpose(new, (0, 2, 1, 3))
 
-    return shard_map_compat(
-        kernel, mesh=mesh, in_specs=(spec, P()), out_specs=spec, check_vma=False
-    )(bt, perm)
+    with jax.named_scope("redistribute"):
+        return shard_map_compat(
+            kernel, mesh=mesh, in_specs=(spec, P()), out_specs=spec,
+            check_vma=False,
+        )(bt, perm)

@@ -191,7 +191,7 @@ def _trsm_a_jit(at, bt, mesh, p, q, nt, uplo, op, diag, la=0, bi="psum"):
 
         return prefetch_bcast(nt, la, fetch, consume, b_loc)
 
-    with bcast_impl_scope(bi):
+    with bcast_impl_scope(bi), jax.named_scope("trsm"):
         return shard_map_compat(
             kernel, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
             check_vma=False,
@@ -267,7 +267,7 @@ def _trsm_jit(at, bt, mesh, p, q, nt, uplo, op, diag, la=0, bi="psum"):
 
         return prefetch_bcast(nt, la, fetch, consume, b_loc)
 
-    with bcast_impl_scope(bi):
+    with bcast_impl_scope(bi), jax.named_scope("trsm"):
         return shard_map_compat(
             kernel, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
             check_vma=False,
@@ -368,7 +368,7 @@ def _trsm_right_jit(at, bt, mesh, p, q, nt, uplo, op, diag, la=0, bi="psum"):
 
         return prefetch_bcast(nt, la, fetch, consume, b_loc)
 
-    with bcast_impl_scope(bi):
+    with bcast_impl_scope(bi), jax.named_scope("trsm"):
         return shard_map_compat(
             kernel, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
             check_vma=False,

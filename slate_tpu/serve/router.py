@@ -262,7 +262,8 @@ class Router:
         trs: List[Optional[rtrace.RequestTrace]] = (
             list(traces) if traces is not None else [None] * len(requests))
         try:
-            return self._solve_batch_inner(requests, trs, tenants)
+            with obs.driver_span("serve.solve_batch"):
+                return self._solve_batch_inner(requests, trs, tenants)
         except Exception:
             for tr in trs:
                 if tr is not None and tr.outcome is None:
@@ -270,6 +271,19 @@ class Router:
             raise
 
     def _solve_batch_inner(self, requests, traces, tenants=None):
+        # host phases as driver spans (serve.admit, serve.stack,
+        # serve.lookup, serve.dispatch, serve.info, serve.unstack): a
+        # profiler trace names the Router's host time with obs off
+        with obs.driver_span("serve.admit"):
+            groups, padded = self._admit_all(requests, traces, tenants)
+        out: List[Optional[jax.Array]] = [None] * len(requests)
+        for (op, klass, m, nrhs, _dt), idxs in groups.items():
+            self._solve_group(requests, traces, padded, out, op, klass, m, idxs)
+        return out  # type: ignore[return-value]
+
+    def _admit_all(self, requests, traces, tenants):
+        """Bin, admit, classify and pad every request; group them by
+        (op, class, bin, nrhs, dtype).  Returns (groups, padded)."""
         groups: Dict[Tuple, List[int]] = {}
         padded: List[Optional[Tuple[jax.Array, jax.Array]]] = [None] * len(requests)
         for i, (op, a, b) in enumerate(requests):
@@ -313,13 +327,16 @@ class Router:
             padded[i] = (pad_to_bin(a, m), pad_rhs_to_bin(bd, m))
             groups.setdefault(
                 (op, klass, m, bd.shape[1], str(a.dtype)), []).append(i)
+        return groups, padded
 
-        out: List[Optional[jax.Array]] = [None] * len(requests)
-        for (op, klass, m, nrhs, _dt), idxs in groups.items():
-            trs = [traces[i] for i in idxs]
-            for tr in trs:
-                if tr is not None:
-                    tr.batch = len(idxs)
+    def _solve_group(self, requests, traces, padded, out, op, klass, m, idxs):
+        """Stack one group, run its program and write each request's
+        solution into ``out``."""
+        trs = [traces[i] for i in idxs]
+        for tr in trs:
+            if tr is not None:
+                tr.batch = len(idxs)
+        with obs.driver_span("serve.stack"):
             a_stack = jnp.stack([padded[i][0] for i in idxs])
             b_stack = jnp.stack([padded[i][1] for i in idxs])
             try:
@@ -328,13 +345,13 @@ class Router:
                 for tr in trs:
                     rtrace.finish(tr, "reject_admission")
                 raise
-            record_batch_size(op, len(idxs))
-            if self._mesh_resilient(op):
-                xs, info = self._solve_group_mesh(op, a_stack, b_stack, trs)
-            else:
-                key = self._key_for(op, klass, (a_stack, b_stack),
-                                    len(idxs))
-                live = any(tr is not None for tr in trs)
+        record_batch_size(op, len(idxs))
+        if self._mesh_resilient(op):
+            xs, info = self._solve_group_mesh(op, a_stack, b_stack, trs)
+        else:
+            live = any(tr is not None for tr in trs)
+            with obs.driver_span("serve.lookup"):
+                key = self._key_for(op, klass, (a_stack, b_stack), len(idxs))
                 # the membership probe exists only for the tracer's
                 # hit/miss label; untraced dispatch skips it
                 hit = self.cache.contains(key) if live else False
@@ -343,42 +360,38 @@ class Router:
                     prog = self.cache.get_or_build(
                         key, lambda op=op, klass=klass: _build_batched(
                             op, klass))
-                with rtrace.phase_all(trs, "solve"):
-                    # the dispatch itself runs inside a driver span
-                    # (ISSUE 17): with obs on, the batched path gets a
-                    # span record (and its depth-0 memory sample)
-                    # carrying the ambient trace_id/tenant — the join
-                    # point the unified Perfetto export correlates the
-                    # request track against; with obs off this is the
-                    # shared null span and dispatch is untouched
-                    with obs.driver_span("serve.dispatch", op=op,
-                                         klass=klass, batch=len(idxs)):
-                        xs, info = prog(a_stack, b_stack)
-                        if live:
-                            # fence so the span (and the SLA latency)
-                            # covers the execution, not just the
-                            # dispatch — the untraced path keeps JAX's
-                            # async semantics
-                            jax.block_until_ready(xs)
-            serve_count("batches")
-            serve_count("batched_solves", len(idxs))
+            with rtrace.phase_all(trs, "solve"):
+                # with obs on, the dispatch span carries the ambient
+                # trace_id/tenant — the join point the unified Perfetto
+                # export correlates the request track against
+                with obs.driver_span("serve.dispatch", op=op,
+                                     klass=klass, batch=len(idxs)):
+                    xs, info = prog(a_stack, b_stack)
+                    if live:
+                        # fence so the span (and the SLA latency) covers
+                        # the execution, not just the dispatch — the
+                        # untraced path keeps JAX's async semantics
+                        jax.block_until_ready(xs)
+        serve_count("batches")
+        serve_count("batched_solves", len(idxs))
+        with obs.driver_span("serve.info"):
             infos = np.asarray(info)
-            bad = [idxs[j] for j, v in enumerate(infos) if v != 0]
-            if bad:
-                for j, i in enumerate(idxs):
-                    if infos[j] != 0:
-                        rtrace.finish(traces[i], "failed_info")
-                # never silently serve a failed factorization's output
-                raise SlateError(
-                    f"serve: {op} batch reported nonzero info for request "
-                    f"indices {bad} — operand(s) not factorizable in the "
-                    f"{klass} class")
+        bad = [idxs[j] for j, v in enumerate(infos) if v != 0]
+        if bad:
+            for j, i in enumerate(idxs):
+                if infos[j] != 0:
+                    rtrace.finish(traces[i], "failed_info")
+            # never silently serve a failed factorization's output
+            raise SlateError(
+                f"serve: {op} batch reported nonzero info for request "
+                f"indices {bad} — operand(s) not factorizable in the "
+                f"{klass} class")
+        with obs.driver_span("serve.unstack"):
             for j, i in enumerate(idxs):
                 n = requests[i][1].shape[0]
                 xi = xs[j, :n]
                 out[i] = xi[:, 0] if requests[i][2].ndim == 1 else xi
                 rtrace.finish(traces[i])  # note-attributed served terminal
-        return out  # type: ignore[return-value]
 
     def solve(self, op: str, a: jax.Array, b: jax.Array,
               tenant: Optional[str] = None) -> jax.Array:

@@ -60,14 +60,17 @@ def _sync(out):
 
 
 def _time(fn, *args, label: str = ""):
-    from slate_tpu.utils.trace import Trace
+    """(result, seconds) of one call after a warm-up call.  The timed call
+    runs inside a ``tester/<label>`` profiler annotation, so a ``--trace``
+    profile marks it among the warm-ups."""
+    import jax
 
     _sync(fn(*args))  # warm/compile (and drain the dispatch queue)
-    t0 = time.perf_counter()
-    out = _sync(fn(*args))
-    t1 = time.perf_counter()
-    if Trace.enabled():
-        Trace.add(label or getattr(fn, "__name__", "op"), 0, t0, t1)
+    name = "tester/" + (label or getattr(fn, "__name__", "op"))
+    with jax.profiler.TraceAnnotation(name):
+        t0 = time.perf_counter()
+        out = _sync(fn(*args))
+        t1 = time.perf_counter()
     return out, t1 - t0
 
 
@@ -112,7 +115,7 @@ def run_gemm_mesh(n, dtype, rng, check, grid):
     a, b = _rand(rng, n, n, dtype), _rand(rng, n, n, dtype)
     nb = max(8, min(64, n // max(*_make_grid_dims(grid))))
     c, t = _time(lambda x, y: gemm_mesh(1.0, x, y, mesh, nb=nb),
-                 jnp.asarray(a), jnp.asarray(b))
+                 jnp.asarray(a), jnp.asarray(b), label="gemm_mesh")
     err = 0.0
     if check:
         ref = a @ b
@@ -134,7 +137,7 @@ def run_posv_mesh(n, dtype, rng, check, grid):
     a = g @ g.conj().T + n * np.eye(n, dtype=dtype)
     b = _rand(rng, n, 2, dtype)
     (x, info), t = _time(lambda aa, bb: posv_mesh(aa, bb, mesh, nb=16),
-                         jnp.asarray(a), jnp.asarray(b))
+                         jnp.asarray(a), jnp.asarray(b), label="posv_mesh")
     err = np.abs(a @ np.asarray(x) - b).max() / np.abs(b).max() if check else 0.0
     return err, t, n**3 / 3 / t / 1e9, int(info) == 0 and err < 100 * n * _eps(dtype)
 
@@ -148,7 +151,7 @@ def run_gesv_mesh(n, dtype, rng, check, grid):
     a = _rand(rng, n, n, dtype)
     b = _rand(rng, n, 2, dtype)
     (x, info), t = _time(lambda aa, bb: gesv_tntpiv_mesh(aa, bb, mesh, nb=16),
-                         jnp.asarray(a), jnp.asarray(b))
+                         jnp.asarray(a), jnp.asarray(b), label="gesv_tntpiv_mesh")
     x = np.asarray(x)
     err = (np.abs(a @ x - b).max() / (np.abs(a).max() * max(1, np.abs(x).max()) * n)
            if check else 0.0)
@@ -169,7 +172,7 @@ def run_gemm(n, dtype, rng, check, precision=None):
     a, b = _rand(rng, n, n, dtype), _rand(rng, n, n, dtype)
     aj, bj = jnp.asarray(a), jnp.asarray(b)
     prec = precision or _mul_prec(None)
-    c, t = _time(lambda x, y: matmul(x, y, precision=prec), aj, bj)
+    c, t = _time(lambda x, y: matmul(x, y, precision=prec), aj, bj, label="gemm")
     gflops = 2 * n**3 / t / 1e9
     err = 0.0
     if check:
@@ -222,7 +225,7 @@ def run_gesv(n, dtype, rng, check):
 
     a = _rand(rng, n, n, dtype)
     b = _rand(rng, n, 8, dtype)
-    (x, f), t = _time(lambda aa, bb: gesv_array(aa, bb), jnp.asarray(a), jnp.asarray(b))
+    (x, f), t = _time(gesv_array, jnp.asarray(a), jnp.asarray(b))
     gflops = (2 * n**3 / 3 + 2 * n**2 * 8) / t / 1e9
     err = np.abs(a @ np.asarray(x) - b).max() / (np.abs(b).max() * np.abs(a).sum(1).max()) if check else 0.0
     return err, t, gflops, err < 30 * n * _eps(dtype)
@@ -337,9 +340,11 @@ def main(argv=None):
                     help="also run scipy/LAPACK and report the comparison "
                          "(reference tester's ScaLAPACK ref mode)")
     ap.add_argument("--trace", default="",
-                    help="write a timeline of the sweep via "
-                         "slate_tpu.utils.trace to this path (SVG, or "
-                         "Chrome-trace/Perfetto JSON for a .json path)")
+                    help="write a jax.profiler trace of the sweep to this "
+                         "directory: each timed call (a tester/<routine> "
+                         "span), the library's slate_tpu/* spans and the "
+                         "device ops on one clock (open it in TensorBoard "
+                         "or xprof)")
     ap.add_argument("--report", default="",
                     help="write a slate_tpu.obs RunReport JSON of the sweep "
                          "(also enables observability: driver spans + comm "
@@ -372,12 +377,8 @@ def main(argv=None):
 
     rng = np.random.default_rng(args.seed)
     check = args.check == "y"
-    tracer = None
     if args.trace:
-        from slate_tpu.utils.trace import Trace
-
-        Trace.on()
-        tracer = Trace
+        jax.profiler.start_trace(args.trace)
     if args.report:
         from slate_tpu import obs
 
@@ -436,10 +437,9 @@ def main(argv=None):
                 report_values[f"{key}_seconds"] = round(t, 6)
                 print(f"{rname:<10} {prefix:<4} {n:>7} {err:>10.2e} {status:>6} "
                       f"{t:>9.4f} {gflops:>10.1f}{refcol}")
-    if tracer is not None:
-        out = tracer.finish(args.trace)
-        tracer.off()
-        print(f"trace written to {out}")
+    if args.trace:
+        jax.profiler.stop_trace()
+        print(f"trace written to {args.trace}")
     if args.report:
         import os
 

@@ -113,28 +113,6 @@ def test_c_api_dposv_and_gels():
     assert np.abs(aa.T @ (aa @ xx - bb)).max() < 1e-9
 
 
-def test_trace_svg():
-    import time
-
-    from slate_tpu.utils import trace
-
-    if shutil.which("g++") is None and not os.path.exists(
-        os.path.join(_ROOT, "native", "lib", "libslatetpu_trace.so")
-    ):
-        pytest.skip("no g++")
-    trace.Trace.on()
-    with trace.block("gemm", lane=0):
-        time.sleep(0.002)
-    with trace.block("trsm", lane=1):
-        time.sleep(0.001)
-    out = trace.Trace.finish("/tmp/slate_tpu_trace_test.svg")
-    trace.Trace.off()
-    assert out is not None
-    svg = open(out).read()
-    assert svg.startswith("<svg") and "gemm" in svg and "trsm" in svg
-    assert trace.timers["gemm"] > 0
-
-
 def test_tester_cli():
     r = subprocess.run(
         ["python", os.path.join(_ROOT, "tester.py"), "gemm", "--dim", "64", "--type", "s"],

@@ -117,14 +117,6 @@ def test_span_propagates_records_to_outer_audit(fresh_obs):
     assert probe["metrics"]["comm_bytes"] == outer[0][1]
 
 
-def test_timer_blocks_feed_metrics(fresh_obs):
-    from slate_tpu.utils import trace
-
-    with trace.block("phase_x"):
-        pass
-    assert obs.REGISTRY.counter_value("timer_seconds", timer="phase_x") > 0
-
-
 # ---------------------------------------------------------------------------
 # measure(): wall/compile/execute phases + cost analysis
 # ---------------------------------------------------------------------------
@@ -155,15 +147,13 @@ def test_perfetto_export_schema_and_nesting(fresh_obs, tmp_path):
     with obs.driver_span("parent_op", n=16):
         with obs.driver_span("child_op"):
             pass
-    path = perfetto.write_chrome_trace(str(tmp_path / "trace.json"),
-                                       legacy_events=[("legacy", 2, 0.0, 0.5)])
+    path = perfetto.write_chrome_trace(str(tmp_path / "trace.json"))
     with open(path) as f:
         tr = json.load(f)
     assert perfetto.validate_chrome_trace(tr) == []
     evs = {e["name"]: e for e in tr["traceEvents"]}
     assert evs["child_op"]["args"]["parent"] == "parent_op"
     assert evs["parent_op"]["args"]["n"] == "16"
-    assert evs["legacy"]["tid"] == 102 and evs["legacy"]["dur"] == 0.5e6
     for e in (evs["parent_op"], evs["child_op"]):
         assert e["ph"] == "X" and e["ts"] >= 0 and e["dur"] >= 0
 
@@ -174,32 +164,6 @@ def test_perfetto_validator_catches_garbage():
     bad = {"traceEvents": [{"name": "", "ph": "X", "ts": -1}]}
     errs = perfetto.validate_chrome_trace(bad)
     assert any("name" in e for e in errs) and any("ts" in e for e in errs)
-
-
-def test_trace_finish_json_fallback_without_native_writer(tmp_path, monkeypatch):
-    """ISSUE 2 satellite: Trace.finish used to DROP all collected events
-    when the native SVG writer was missing — now they survive as a
-    Chrome-trace JSON, and are kept entirely when even that write fails."""
-    from slate_tpu.utils.trace import Trace
-    from slate_tpu.utils import trace as trace_mod
-
-    monkeypatch.setattr(trace_mod, "_load_writer", lambda: None)
-    Trace.on()
-    Trace.add("ev_a", 0, 0.0, 1.0)
-    Trace.add("ev_b", 1, 0.5, 2.0)
-    # write failure (directory does not exist): events must be KEPT
-    out = Trace.finish(str(tmp_path / "missing_dir" / "t.svg"))
-    assert out is None
-    assert len(Trace._events) == 2
-    # fallback success: JSON written next to the requested path
-    out = Trace.finish(str(tmp_path / "t.svg"))
-    assert out == str(tmp_path / "t.svg.json")
-    with open(out) as f:
-        tr = json.load(f)
-    assert perfetto.validate_chrome_trace(tr) == []
-    assert {e["name"] for e in tr["traceEvents"]} >= {"ev_a", "ev_b"}
-    assert Trace._events == []
-    Trace.off()
 
 
 # ---------------------------------------------------------------------------
@@ -335,23 +299,6 @@ def test_check_defaults_to_headline_values_only(tmp_path):
     assert vals_all["span_count|span=short_op"] == 4.0
     assert set(vals_all) > set(vals_default)
     obs.reset()
-
-
-def test_legacy_t0_aligns_mixed_timebases():
-    spans = [{"name": "sp", "tags": {}, "t0": 100.0, "t1": 101.0,
-              "depth": 0, "parent": None, "metrics": {}}]
-    # legacy clock started at perf_counter()=99.5; its event at +1.0s is
-    # absolute 100.5 = 0.5s after the span base in the merged trace
-    tr = perfetto.chrome_trace(spans=spans,
-                               legacy_events=[("lg", 0, 1.0, 1.25)],
-                               legacy_t0=99.5)
-    evs = {e["name"]: e for e in tr["traceEvents"]}
-    assert evs["sp"]["ts"] == 0.0
-    assert evs["lg"]["ts"] == pytest.approx(0.5e6)
-    assert evs["lg"]["dur"] == pytest.approx(0.25e6)
-    # without legacy_t0 the legacy track keeps its own zero (old behavior)
-    tr2 = perfetto.chrome_trace(spans=spans, legacy_events=[("lg", 0, 1.0, 1.25)])
-    assert {e["name"]: e for e in tr2["traceEvents"]}["lg"]["ts"] == pytest.approx(1.0e6)
 
 
 def test_check_cli_inconclusive_on_unreadable_artifacts(tmp_path):
