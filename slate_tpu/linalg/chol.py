@@ -25,6 +25,7 @@ from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..blas3.blas3 import _NB, _split, trsm_array
 from ..core.matrix import (
@@ -42,6 +43,15 @@ from ..ops.pallas_ops import chol_diag_inv_pallas, panel_engaged
 from ..types import Diag, Op, Options, Side, Uplo
 
 ArrayLike = Union[jax.Array, BaseMatrix]
+
+
+def _row_major(x: jax.Array) -> jax.Array:
+    """``x`` pinned row-major.  64-bit elements (f64, c64) are left to
+    layout assignment: a TPU rewrites them into 32-bit pairs, and that
+    rewrite cannot carry a layout constraint."""
+    if x.dtype.itemsize > 4:
+        return x
+    return with_layout_constraint(x, Layout((0, 1)))  # XLA's {1,0}
 
 
 def _potrf_lower(a: jax.Array) -> jax.Array:
@@ -79,6 +89,26 @@ def _potrf_scan(a: jax.Array, nb: int = 256, nbuckets: int = 4) -> jax.Array:
     ~0.47x of the full-width form at 4 buckets; every flop is an MXU
     gemm.  Input must be full Hermitian.
 
+    Each k-step updates the loop's trailing view in place, and its order
+    is what lets it: (1) the panel column leaves the carry as one
+    materialized (nv, nb) value (``optimization_barrier``), and the
+    diagonal block, the panel solve and ``l21`` are built from it; (2)
+    ``view - l21 l21^H`` is the step's only op that reads or writes the
+    whole view; (3) the finished column goes back with one
+    ``dynamic_update_slice``, last.  Written the other way round (column
+    written first, then the update over a view whose column a fusion
+    still reads), copy insertion cannot alias the update's output with
+    the carry and copies the whole view every step; without the barrier
+    a TPU reads the diagonal block through a column-major copy of the
+    whole view.  The order changes no finite value: ``l21`` is zero in the
+    panel rows and above, so ``l21 l21^H`` is zero in the panel columns
+    (a failed pivot still NaN-poisons the diagonal from its block on).  The
+    carry is pinned row-major, the update's output layout (``_row_major``):
+    left to layout assignment, a TPU carries it column-major, as the
+    panel column's ops prefer, and converts the whole view to and from
+    that every step.  Each such copy reads and writes the whole view, as
+    the update does.
+
     Each step's work sits under the ``panel`` / ``bulk`` phase scopes and
     the bucket boundaries under ``regroup`` (``comm.phase_scope``), so a
     profile of the compiled program names every op's phase."""
@@ -104,10 +134,13 @@ def _potrf_scan(a: jax.Array, nb: int = 256, nbuckets: int = 4) -> jax.Array:
         rows = jnp.arange(nv)
 
         def step(k, view, off=off, nv=nv, rows=rows):
+            view = _row_major(view)
             with phase_scope("panel", k):
                 kk = k * nb - off  # view-local panel head
-                dblk = jax.lax.dynamic_slice(view, (kk, kk), (nb, nb))
-                col = jax.lax.dynamic_slice(view, (0, kk), (nv, nb))
+                col = jax.lax.optimization_barrier(
+                    jax.lax.dynamic_slice(view, (0, kk), (nv, nb))
+                )
+                dblk = jax.lax.dynamic_slice(col, (kk, 0), (nb, nb))
                 # panel solve as explicit-inverse gemm (MAGMA-style
                 # trtri+gemm): XLA's big-rhs triangular_solve runs at ~1/10
                 # the MXU matmul rate at (32768, 256) (measured 46 vs 4
@@ -133,11 +166,13 @@ def _potrf_scan(a: jax.Array, nb: int = 256, nbuckets: int = 4) -> jax.Array:
                     jnp.zeros((nv, nb), view.dtype), jnp.tril(ld), (kk, 0)
                 )
                 newcol = jnp.where(below, sol, jnp.where(ondiag, dpat, col))
-                view = jax.lax.dynamic_update_slice(view, newcol, (0, kk))
             with phase_scope("bulk", k):
                 l21 = newcol * below.astype(view.dtype)
                 upd = matmul(l21, jnp.conj(l21).T if cplx else l21.T)
-                return view - upd.astype(view.dtype)
+                view = view - upd.astype(view.dtype)
+            with phase_scope("panel", k):
+                view = jax.lax.dynamic_update_slice(view, newcol, (0, kk))
+            return _row_major(view)
 
         view = jax.lax.fori_loop(k0, k1, step, view)
         with phase_scope("regroup"):
@@ -465,6 +500,15 @@ def _potrf_f64_form(n: int, concrete: bool, ozaki_dispatch: bool,
                                    itemsize=itemsize)
 
 
+def _pivot_info(l: jax.Array) -> jax.Array:
+    """info of a factor read off its diagonal: 0, else 1 + the index of
+    the first pivot that is not finite and positive (every factor form
+    NaN-poisons the diagonal from a failed diagonal block on)."""
+    d = jnp.real(jnp.diagonal(l))
+    bad = ~(jnp.isfinite(d) & (d > 0))
+    return jnp.where(jnp.any(bad), jnp.argmax(bad) + 1, 0).astype(jnp.int32)
+
+
 @instrument("potrf_array")
 def potrf_array(a: jax.Array, uplo: Uplo = Uplo.Lower) -> Tuple[jax.Array, jax.Array]:
     """Factor A = L L^H (or U^H U). ``a`` holds the uplo triangle (other
@@ -500,9 +544,7 @@ def potrf_array(a: jax.Array, uplo: Uplo = Uplo.Lower) -> Tuple[jax.Array, jax.A
         l = _potrf_scan(full)
     else:
         l = _potrf_lower(full)
-    d = jnp.real(jnp.diagonal(l))
-    bad = ~(jnp.isfinite(d) & (d > 0))
-    info = jnp.where(jnp.any(bad), jnp.argmax(bad) + 1, 0).astype(jnp.int32)
+    info = _pivot_info(l)
     l = tri_project(l, Uplo.Lower)
     if uplo == Uplo.Upper:
         return jnp.conj(l).T, info

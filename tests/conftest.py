@@ -31,3 +31,34 @@ def rng():
 
 def cpu_devices(n=8):
     return jax.devices("cpu")[:n]
+
+
+def loop_view_copies(hlo: str, min_dim: int) -> dict:
+    """{nv: copies of the view} for every while loop of compiled HLO text
+    whose carry holds a square f32 (nv, nv) view with nv >= min_dim; the
+    copies are the loop body's ``copy`` / ``copy-start`` instructions
+    whose result has the view's shape."""
+    import re
+
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line.strip())
+    out = {}
+    for name in set(re.findall(r"body=%?([\w.\-]+)", hlo)):
+        lines = comps[name]
+        param = next(l for l in lines if "parameter(0)" in l)
+        square = [int(m) for m, k in re.findall(r"f32\[(\d+),(\d+)\]", param) if m == k]
+        if not square or max(square) < min_dim:
+            continue
+        nv = max(square)
+        shape = f"f32[{nv},{nv}]"
+        out[nv] = [l for l in lines
+                   if (m := re.match(r"\S+ = (.*?) copy(?:-start)?\(", l))
+                   and shape in m.group(1)]
+    return out

@@ -161,3 +161,40 @@ def test_mesh_solve_compiles_for_2x2(driver, fused, topo, on_tpu):
     solve = getattr(parallel, driver)
     compiled = jax.jit(lambda a, b: solve(a, b, mesh, nb=NB)).lower(a, b).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == fused
+
+
+def test_potrf_scan_carry_in_place_for_v5e(one_chip, on_tpu):
+    """The scanned Cholesky's loop carry stays in place on a TPU: no
+    whole-view copy in any bucket's loop body.  Left to layout
+    assignment, a TPU carries the view column-major, as the panel
+    column's ops prefer, and converts it to and from the update's
+    row-major output every k-step.  The input is symmetrized as
+    ``potrf_array`` gives it; a small n shows the same layout choice as
+    n = 30720."""
+    from conftest import loop_view_copies
+    from slate_tpu.core.matrix import symmetrize
+    from slate_tpu.linalg.chol import _potrf_scan
+    from slate_tpu.types import Uplo
+
+    n, nb = 1024, 128
+    a = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
+    factor = lambda a: _potrf_scan(symmetrize(a, Uplo.Lower, conj=False), nb=nb)
+    copies = loop_view_copies(jax.jit(factor).lower(a).compile().as_text(), min_dim=2 * nb)
+    assert sorted(copies) == [256, 512, 768, 1024], copies
+    assert not any(copies.values()), copies
+
+
+@pytest.mark.parametrize("dtype", [jnp.complex64, jnp.float64])
+def test_potrf_scan_64bit_elements_compile_for_v5e(dtype, one_chip, on_tpu):
+    """A TPU rewrites 64-bit elements into 32-bit pairs, and that rewrite
+    refuses a layout constraint: the scanned Cholesky pins only 32-bit
+    carries, so complex64 (``potrf_array``'s path past n = 16384) and
+    float64 still compile."""
+    from slate_tpu.core.matrix import symmetrize
+    from slate_tpu.linalg.chol import _potrf_scan
+    from slate_tpu.types import Uplo
+
+    cplx = jnp.issubdtype(dtype, jnp.complexfloating)
+    a = jax.ShapeDtypeStruct((512, 512), dtype, sharding=one_chip)
+    factor = lambda a: _potrf_scan(symmetrize(a, Uplo.Lower, conj=cplx), nb=128)
+    assert "while" in jax.jit(factor).lower(a).compile().as_text()

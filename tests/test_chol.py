@@ -234,3 +234,113 @@ def test_potrf_ll_ozaki_cached(cond):
     eps = np.finfo(np.float64).eps
     gate = 8 * n * eps * (1 if cond is None else np.sqrt(cond))
     assert resid < gate, (resid, gate)
+
+
+def test_potrf_scan_carry_updated_in_place():
+    # Each k-step of the scanned Cholesky must update its fori_loop's
+    # trailing view in place: no copy of a whole view inside a loop body.
+    # This guards XLA's copy insertion only (the CPU compile shows the
+    # copy a step order that blocks aliasing brings back); the layout
+    # copies a TPU adds around a column-major carry are guarded by
+    # tests/test_chip_compile.py and show in the chip's device trace.
+    import jax
+    from conftest import loop_view_copies
+    from slate_tpu.linalg.chol import _potrf_scan
+
+    n, nb = 512, 64
+    spec = jax.ShapeDtypeStruct((n, n), jnp.float32)
+    hlo = jax.jit(lambda a: _potrf_scan(a, nb=nb, nbuckets=4)).lower(spec).compile().as_text()
+    copies = loop_view_copies(hlo, min_dim=2 * nb)
+    # one loop per bucket, views n, 3n/4, n/2, n/4
+    assert sorted(copies) == [128, 256, 384, 512], copies
+    assert not any(copies.values()), copies
+
+def _potrf_scan_dus_first(a, nb, nbuckets):
+    """The scanned Cholesky's earlier k-step order, kept as the reference:
+    write the finished panel column into the carry first, then subtract
+    ``l21 l21^T`` over the whole view."""
+    import jax
+
+    n = a.shape[0]
+    nsteps = -(-n // nb)
+    np_ = nsteps * nb
+    ap = jnp.pad(a, ((0, np_ - n), (0, np_ - n)))
+    dpad = jnp.arange(n, np_)
+    ap = ap.at[dpad, dpad].set(1)
+    bounds = [nsteps * g // nbuckets for g in range(nbuckets)] + [nsteps]
+    for g in range(nbuckets):
+        k0, k1 = bounds[g], bounds[g + 1]
+        if k0 == k1:
+            continue
+        off = k0 * nb
+        view = ap[off:, off:]
+        nv = np_ - off
+        rows = jnp.arange(nv)
+
+        def step(k, view, off=off, nv=nv, rows=rows):
+            kk = k * nb - off
+            dblk = jax.lax.dynamic_slice(view, (kk, kk), (nb, nb))
+            col = jax.lax.dynamic_slice(view, (0, kk), (nv, nb))
+            ld = jax.lax.linalg.cholesky(dblk)
+            linv = jax.lax.linalg.triangular_solve(
+                ld[None], jnp.eye(nb, dtype=view.dtype)[None], left_side=True,
+                lower=True, transpose_a=False,
+            )[0]
+            sol = jnp.matmul(col, linv.T, precision="highest").astype(view.dtype)
+            below = (rows >= kk + nb)[:, None]
+            ondiag = ((rows >= kk) & (rows < kk + nb))[:, None]
+            dpat = jax.lax.dynamic_update_slice(
+                jnp.zeros((nv, nb), view.dtype), jnp.tril(ld), (kk, 0)
+            )
+            newcol = jnp.where(below, sol, jnp.where(ondiag, dpat, col))
+            view = jax.lax.dynamic_update_slice(view, newcol, (0, kk))
+            l21 = newcol * below.astype(view.dtype)
+            upd = jnp.matmul(l21, l21.T, precision="highest")
+            return view - upd.astype(view.dtype)
+
+        view = jax.lax.fori_loop(k0, k1, step, view)
+        ap = ap.at[off:, off:].set(view)
+    return ap[:n, :n]
+
+
+def _scan_fixture(kind, n, dtype, seed):
+    rng = np.random.default_rng(seed)
+    g = rng.standard_normal((n, n))
+    if kind == "well":
+        a = (g + g.T) / (2 * np.sqrt(n)) + 3 * np.eye(n)
+    else:  # test_potrf_scan_ill_conditioned's geometric spectrum
+        q, _ = np.linalg.qr(g)
+        a = (q * 1e6 ** (-np.arange(n) / (n - 1))) @ q.T
+        a = (a + a.T) / 2
+    return a.astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("kind", ["well", "ill"])
+def test_potrf_scan_step_order_bitwise(kind, dtype):
+    # the in-place step order (column out, whole-view update, column
+    # written last) gives bitwise the L of the column-first order
+    import jax
+    from slate_tpu.linalg.chol import _potrf_scan
+
+    a = jnp.asarray(_scan_fixture(kind, 300, dtype, seed=43))
+    assert a.dtype == dtype
+    got = jax.jit(lambda x: _potrf_scan(x, nb=64, nbuckets=4))(a)
+    ref = jax.jit(lambda x: _potrf_scan_dus_first(x, nb=64, nbuckets=4))(a)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+def test_potrf_scan_non_spd_info():
+    # An indefinite input whose first bad pivot (row 512) lies inside the
+    # third of four buckets (rows 384..575 at nb 64): the reordered
+    # update must neither move nor hide the NaN poisoning, so potrf's
+    # info reads the same pivot off the scan's L as off the recursion's.
+    from slate_tpu.linalg.chol import _pivot_info, _potrf_lower, _potrf_scan
+
+    n, p = 768, 512  # p also starts a 256-row leaf of the recursion
+    a = _scan_fixture("well", n, np.float32, seed=44)
+    a[p, p] = -1.0
+    a = jnp.asarray(a)
+    info_scan = int(_pivot_info(_potrf_scan(a, nb=64, nbuckets=4)))
+    info_lower = int(_pivot_info(_potrf_lower(a)))
+    assert info_scan == info_lower == p + 1
