@@ -108,6 +108,19 @@ def lu_solve(a: ArrayLike, b: ArrayLike, method: MethodLU = MethodLU.PartialPiv)
     return x
 
 
+def lu_solve_mixed(a: ArrayLike, b: ArrayLike, opts: Optional[Options] = None):
+    """Mixed-precision LU solve (slate::gesv_mixed_gmres): a float32 LU
+    (Option.MethodLU; under MethodLU.NoPiv its trailing updates run at
+    Option.Precision) preconditions GMRES-IR in a's precision, which stops
+    on HPL's test ||b - A x||_inf <= 16 u n ||A||_inf ||x||_inf (HPL-MxP's
+    solve).  Returns RefineResult(x, iters, converged, info): iters the
+    GMRES steps of the slowest column, info the factor's LAPACK code."""
+    from .linalg.refine import _gesv_gmres
+
+    res, _ = _gesv_gmres(blas3._arr(a), blas3._arr(b), opts, restart=30)
+    return res
+
+
 def lu_solve_using_factor(f, b: ArrayLike, op: Op = Op.NoTrans):
     return lu.getrs_array(f, blas3._arr(b), op)
 

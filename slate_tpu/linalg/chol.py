@@ -80,14 +80,51 @@ def _potrf_lower(a: jax.Array) -> jax.Array:
     return jnp.block([[l11, z], [l21, l22]])
 
 
-def _potrf_scan(a: jax.Array, nb: int = 256, nbuckets: int = 4) -> jax.Array:
-    """Single-program scanned lower Cholesky: lax.fori_loop over panels
+def _scan_factor(a: jax.Array, nb: int, nbuckets: int, step) -> jax.Array:
+    """The step driver of the scanned single-chip factors (``_potrf_scan``,
+    ``lu._getrf_nopiv_scan``): one ``lax.fori_loop`` over nb-wide panels
     with static shapes, O(1) HLO size in n (the recursive trace explodes
-    at north-star sizes — cf. lu.getrf_scan_array).  The k-range is
-    segmented into ``nbuckets`` statically-shrinking trailing views (cf.
-    parallel.dist_chol), cutting the HBM-bound masked trailing traffic to
-    ~0.47x of the full-width form at 4 buckets; every flop is an MXU
-    gemm.  Input must be full Hermitian.
+    at north-star sizes).  ``a`` is padded with an identity tail to a
+    multiple of nb; the k-range is segmented into ``nbuckets``
+    statically-shrinking trailing views (cf. parallel.dist_chol), cutting
+    the HBM-bound masked trailing traffic to ~0.47x of the full-width form
+    at 4 buckets.  ``step(k, view, off, rows)`` factors panel k of the
+    bucket's view (``off`` its global head, ``rows`` its row indices) and
+    returns the view updated in place; the driver pins the carry
+    row-major around it (``_row_major``) and puts the bucket boundaries
+    under the ``regroup`` phase scope."""
+    from ..parallel.comm import phase_scope
+
+    n = a.shape[0]
+    nsteps = -(-n // nb)
+    np_ = nsteps * nb
+    ap = jnp.pad(a, ((0, np_ - n), (0, np_ - n)))
+    dpad = jnp.arange(n, np_)
+    ap = ap.at[dpad, dpad].set(1)
+
+    bounds = [nsteps * g // nbuckets for g in range(nbuckets)] + [nsteps]
+    for g in range(nbuckets):
+        k0, k1 = bounds[g], bounds[g + 1]
+        if k0 == k1:
+            continue
+        off = k0 * nb
+        with phase_scope("regroup"):
+            view = ap[off:, off:]
+        rows = jnp.arange(np_ - off)
+
+        def body(k, view, off=off, rows=rows):
+            return _row_major(step(k, _row_major(view), off, rows))
+
+        view = jax.lax.fori_loop(k0, k1, body, view)
+        with phase_scope("regroup"):
+            ap = ap.at[off:, off:].set(view)
+    return ap[:n, :n]
+
+
+def _potrf_scan(a: jax.Array, nb: int = 256, nbuckets: int = 4) -> jax.Array:
+    """Single-program scanned lower Cholesky over ``_scan_factor``'s
+    shrinking bucketed views; every flop is an MXU gemm.  Input must be
+    full Hermitian.
 
     Each k-step updates the loop's trailing view in place, and its order
     is what lets it: (1) the panel column leaves the carry as one
@@ -114,70 +151,50 @@ def _potrf_scan(a: jax.Array, nb: int = 256, nbuckets: int = 4) -> jax.Array:
     profile of the compiled program names every op's phase."""
     from ..parallel.comm import phase_scope
 
-    n = a.shape[0]
-    nsteps = -(-n // nb)
-    np_ = nsteps * nb
-    ap = jnp.pad(a, ((0, np_ - n), (0, np_ - n)))
-    dpad = jnp.arange(n, np_)
-    ap = ap.at[dpad, dpad].set(1)
     cplx = jnp.issubdtype(a.dtype, jnp.complexfloating)
 
-    bounds = [nsteps * g // nbuckets for g in range(nbuckets)] + [nsteps]
-    for g in range(nbuckets):
-        k0, k1 = bounds[g], bounds[g + 1]
-        if k0 == k1:
-            continue
-        off = k0 * nb
-        with phase_scope("regroup"):
-            view = ap[off:, off:]
-        nv = np_ - off
-        rows = jnp.arange(nv)
+    def step(k, view, off, rows):
+        nv = rows.shape[0]
+        with phase_scope("panel", k):
+            kk = k * nb - off  # view-local panel head
+            col = jax.lax.optimization_barrier(
+                jax.lax.dynamic_slice(view, (0, kk), (nv, nb))
+            )
+            dblk = jax.lax.dynamic_slice(col, (kk, 0), (nb, nb))
+            # panel solve as explicit-inverse gemm (MAGMA-style
+            # trtri+gemm): XLA's big-rhs triangular_solve runs at ~1/10
+            # the MXU matmul rate at (32768, 256) (measured 46 vs 4
+            # ms), and inverting only the nb x nb diag block keeps the
+            # backward error at the same O(eps * cond(L_kk)) class.
+            # Under Option.PanelImpl=pallas the factor + inverse pair
+            # is ONE fused on-chip kernel instead of the per-column
+            # cholesky + triangular_solve dispatch chain.
+            if panel_engaged(view.dtype, nb * nb * view.dtype.itemsize):
+                ld, linv = chol_diag_inv_pallas(dblk)
+            else:
+                ld = jax.lax.linalg.cholesky(dblk)
+                eye_nb = jnp.eye(nb, dtype=view.dtype)
+                linv = jax.lax.linalg.triangular_solve(
+                    ld[None], eye_nb[None], left_side=True, lower=True,
+                    transpose_a=False,
+                )[0]
+            linv_h = jnp.conj(linv).T if cplx else linv.T
+            sol = matmul(col, linv_h).astype(view.dtype)
+            below = (rows >= kk + nb)[:, None]
+            ondiag = ((rows >= kk) & (rows < kk + nb))[:, None]
+            dpat = jax.lax.dynamic_update_slice(
+                jnp.zeros((nv, nb), view.dtype), jnp.tril(ld), (kk, 0)
+            )
+            newcol = jnp.where(below, sol, jnp.where(ondiag, dpat, col))
+        with phase_scope("bulk", k):
+            l21 = newcol * below.astype(view.dtype)
+            upd = matmul(l21, jnp.conj(l21).T if cplx else l21.T)
+            view = view - upd.astype(view.dtype)
+        with phase_scope("panel", k):
+            view = jax.lax.dynamic_update_slice(view, newcol, (0, kk))
+        return view
 
-        def step(k, view, off=off, nv=nv, rows=rows):
-            view = _row_major(view)
-            with phase_scope("panel", k):
-                kk = k * nb - off  # view-local panel head
-                col = jax.lax.optimization_barrier(
-                    jax.lax.dynamic_slice(view, (0, kk), (nv, nb))
-                )
-                dblk = jax.lax.dynamic_slice(col, (kk, 0), (nb, nb))
-                # panel solve as explicit-inverse gemm (MAGMA-style
-                # trtri+gemm): XLA's big-rhs triangular_solve runs at ~1/10
-                # the MXU matmul rate at (32768, 256) (measured 46 vs 4
-                # ms), and inverting only the nb x nb diag block keeps the
-                # backward error at the same O(eps * cond(L_kk)) class.
-                # Under Option.PanelImpl=pallas the factor + inverse pair
-                # is ONE fused on-chip kernel instead of the per-column
-                # cholesky + triangular_solve dispatch chain.
-                if panel_engaged(view.dtype, nb * nb * view.dtype.itemsize):
-                    ld, linv = chol_diag_inv_pallas(dblk)
-                else:
-                    ld = jax.lax.linalg.cholesky(dblk)
-                    eye_nb = jnp.eye(nb, dtype=view.dtype)
-                    linv = jax.lax.linalg.triangular_solve(
-                        ld[None], eye_nb[None], left_side=True, lower=True,
-                        transpose_a=False,
-                    )[0]
-                linv_h = jnp.conj(linv).T if cplx else linv.T
-                sol = matmul(col, linv_h).astype(view.dtype)
-                below = (rows >= kk + nb)[:, None]
-                ondiag = ((rows >= kk) & (rows < kk + nb))[:, None]
-                dpat = jax.lax.dynamic_update_slice(
-                    jnp.zeros((nv, nb), view.dtype), jnp.tril(ld), (kk, 0)
-                )
-                newcol = jnp.where(below, sol, jnp.where(ondiag, dpat, col))
-            with phase_scope("bulk", k):
-                l21 = newcol * below.astype(view.dtype)
-                upd = matmul(l21, jnp.conj(l21).T if cplx else l21.T)
-                view = view - upd.astype(view.dtype)
-            with phase_scope("panel", k):
-                view = jax.lax.dynamic_update_slice(view, newcol, (0, kk))
-            return _row_major(view)
-
-        view = jax.lax.fori_loop(k0, k1, step, view)
-        with phase_scope("regroup"):
-            ap = ap.at[off:, off:].set(view)
-    return ap[:n, :n]
+    return _scan_factor(a, nb, nbuckets, step)
 
 
 def _potrf_and_inv(a: jax.Array) -> Tuple[jax.Array, jax.Array]:

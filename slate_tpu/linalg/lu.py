@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from ..blas3.blas3 import _NB, _split, split_pow2, trsm_array
 from ..core.matrix import BaseMatrix, Matrix, band_project, tri_project
 from ..ops.matmul import matmul
-from ..types import Diag, MethodLU, Op, Option, Options, Side, Uplo, get_option
+from ..types import Diag, MethodLU, Op, Option, Options, Precision, Side, Uplo, get_option
 
 ArrayLike = Union[jax.Array, BaseMatrix]
 
@@ -494,18 +494,88 @@ def getrf_scan_array(
 # ---------------------------------------------------------------------------
 
 
-def _getrf_nopiv_rec(a: jax.Array) -> jax.Array:
+_GETRF_NOPIV_SCAN_MIN_N = 16384  # above this the recursive trace is too large
+
+
+def _schur_product(l: jax.Array, u: jax.Array, precision: Precision) -> jax.Array:
+    """``l @ u`` of a trailing update at the caller's tier.  Under
+    ``Precision.Fast`` a float32 update takes bfloat16 operands and
+    accumulates in float32 (HPL-MxP's tensor-core form, the MXU's single
+    pass), written out so every backend rounds alike; other tiers and
+    dtypes go through ``ops.matmul``."""
+    if precision == Precision.Fast and l.dtype == jnp.float32:
+        return jnp.matmul(l.astype(jnp.bfloat16), u.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+    return matmul(l, u, precision=precision)
+
+
+def _getrf_nopiv_rec(a: jax.Array, precision: Precision = Precision.Highest) -> jax.Array:
     n = min(a.shape)
     if n <= _NB:
         return _nopiv_base(a)
     h = _split(n)
     a11, a12, a21, a22 = a[:h, :h], a[:h, h:], a[h:, :h], a[h:, h:]
-    lu11 = _nopiv_base(a11) if h <= _NB else _getrf_nopiv_rec(a11)
+    lu11 = _nopiv_base(a11) if h <= _NB else _getrf_nopiv_rec(a11, precision)
     u12 = trsm_array(Side.Left, Uplo.Lower, Op.NoTrans, Diag.Unit, 1.0, lu11, a12)
     l21 = trsm_array(Side.Right, Uplo.Upper, Op.NoTrans, Diag.NonUnit, 1.0, lu11, a21)
-    s = a22 - matmul(l21, u12).astype(a.dtype)
-    lu22 = _getrf_nopiv_rec(s)
+    s = a22 - _schur_product(l21, u12, precision).astype(a.dtype)
+    lu22 = _getrf_nopiv_rec(s, precision)
     return jnp.block([[lu11, u12], [l21, lu22]])
+
+
+def _getrf_nopiv_scan(
+    a: jax.Array, nb: int = 256, nbuckets: int = 4, precision: Precision = Precision.Highest
+) -> jax.Array:
+    """Single-program scanned LU without pivoting of a square array, on
+    ``chol._scan_factor``'s shrinking bucketed views, with
+    ``chol._potrf_scan``'s step order so that each k-step updates the
+    view in place: (1) the panel column and the panel row leave the carry
+    as materialized values (``optimization_barrier``); (2) ``view - L21
+    U12`` is the step's only op that reads or writes the whole view; (3)
+    the finished column and row go back last.  The panel is the nb x nb
+    diagonal block's unblocked LU, then ``L21 = A21 U11^-1`` and ``U12 =
+    L11^-1 A12`` as explicit-inverse gemms at full precision; the update
+    runs at ``precision`` (``_schur_product``).  ``L21`` is zero in the
+    panel rows and above and ``U12`` in the panel columns and left of
+    them, so the update leaves the panel as it was.  Phase scopes as in
+    ``_potrf_scan``: ``panel`` and ``bulk`` per step, ``regroup`` at the
+    bucket boundaries."""
+    from ..parallel.comm import phase_scope
+    from .chol import _scan_factor
+
+    def step(k, view, off, rows):
+        nv = rows.shape[0]
+        dt = view.dtype
+        with phase_scope("panel", k):
+            kk = k * nb - off  # view-local panel head
+            col, row = jax.lax.optimization_barrier((
+                jax.lax.dynamic_slice(view, (0, kk), (nv, nb)),
+                jax.lax.dynamic_slice(view, (kk, 0), (nb, nv)),
+            ))
+            lu11 = _nopiv_base(jax.lax.dynamic_slice(col, (kk, 0), (nb, nb)))
+            eye = jnp.eye(nb, dtype=dt)[None]
+            linv = jax.lax.linalg.triangular_solve(
+                lu11[None], eye, left_side=True, lower=True, unit_diagonal=True)[0]
+            uinv = jax.lax.linalg.triangular_solve(
+                lu11[None], eye, left_side=True, lower=False)[0]
+            below = rows >= kk + nb
+            ondiag = (rows >= kk) & (rows < kk + nb)
+            l21 = jnp.where(below[:, None], matmul(col, uinv).astype(dt), 0)
+            u12 = jnp.where(below[None, :], matmul(linv, row).astype(dt), 0)
+            newcol = jnp.where(below[:, None], l21, jnp.where(
+                ondiag[:, None],
+                jax.lax.dynamic_update_slice(jnp.zeros((nv, nb), dt), lu11, (kk, 0)), col))
+            newrow = jnp.where(below[None, :], u12, jnp.where(
+                ondiag[None, :],
+                jax.lax.dynamic_update_slice(jnp.zeros((nb, nv), dt), lu11, (0, kk)), row))
+        with phase_scope("bulk", k):
+            view = view - _schur_product(l21, u12, precision).astype(dt)
+        with phase_scope("panel", k):
+            view = jax.lax.dynamic_update_slice(view, newcol, (0, kk))
+            view = jax.lax.dynamic_update_slice(view, newrow, (kk, 0))
+        return view
+
+    return _scan_factor(a, nb, nbuckets, step)
 
 
 def _nopiv_base(a: jax.Array) -> jax.Array:
@@ -524,8 +594,14 @@ def _nopiv_base(a: jax.Array) -> jax.Array:
     return jax.lax.fori_loop(0, min(m, n), step, a)
 
 
-def getrf_nopiv_array(a: jax.Array) -> LUFactors:
-    lu = _getrf_nopiv_rec(a)
+def getrf_nopiv_array(a: jax.Array, precision: Precision = Precision.Highest) -> LUFactors:
+    """LU without pivoting (src/getrf_nopiv.cc); the trailing updates run
+    at ``precision`` (``_schur_product``).  Square arrays from
+    ``_GETRF_NOPIV_SCAN_MIN_N`` take the scanned single-program form."""
+    if a.ndim == 2 and a.shape[0] == a.shape[1] >= _GETRF_NOPIV_SCAN_MIN_N:
+        lu = _getrf_nopiv_scan(a, precision=precision)
+    else:
+        lu = _getrf_nopiv_rec(a, precision)
     return LUFactors(lu, jnp.arange(a.shape[0]), _lu_info(lu))
 
 

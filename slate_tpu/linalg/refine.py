@@ -10,6 +10,7 @@ Generic over a (factor, solve) pair so LU and Cholesky share the loop; the
 convergence gate mirrors the reference: stop when the residual satisfies
 ``||r|| <= ||x|| * ||A|| * eps * sqrt(n) * stesp`` and fall back to the full
 high-precision solver after max_iter failures when UseFallbackSolver is set.
+GMRES-IR stops on HPL's test instead (``_gmres``).
 """
 
 from __future__ import annotations
@@ -22,7 +23,8 @@ import jax.numpy as jnp
 from ..core.matrix import symmetrize
 from ..ops.matmul import matmul
 from ..ops.tile_ops import genorm
-from ..types import Norm, Option, Options, Uplo, get_option
+from ..obs import instrument
+from ..types import MethodLU, Norm, Option, Options, Precision, Uplo, get_option
 
 Array = jax.Array
 
@@ -192,96 +194,255 @@ def posv_mixed_array(
 # ---------------------------------------------------------------------------
 
 
+_MV_ROWS = 512  # rows per block of the refinement's reads of A
+
+
+def _split_pair(a: Array, lo_dtype) -> Tuple[Array, Array]:
+    """``a`` as (hi, lo) in ``lo_dtype`` with hi + lo = a to twice that
+    precision (the 48 bits a TPU keeps of a float64): hi is ``a`` rounded,
+    and is what the factor reads.  A TPU splits every float64 array it
+    reads into such a float32 pair, and would split the whole of A again
+    for the refinement's loop: the refinement reads this pair instead."""
+    hi = a.astype(lo_dtype)
+    return hi, (a - hi.astype(a.dtype)).astype(lo_dtype)
+
+
+def _row_blocks(hi: Array, lo: Array, dtype, fn: Callable[[Array], Array]) -> Array:
+    """``fn`` (one value per row) of the matrix hi + lo in ``dtype``, over
+    blocks of ``_MV_ROWS`` rows, so the widened block is the only
+    temporary; the last block may overlap the one before it and writes the
+    same rows again.  The refinement's products with A are elementwise
+    products and row sums here, not dots: a TPU emulates a float64 dot by
+    splitting both operands into float32 pieces (an (8, n, n) temporary for
+    A, 18 GiB at n 24576), and ``ops.matmul``'s Ozaki dispatch splits them
+    too."""
+    rows = hi.shape[0]
+    widen = lambda h, l: fn(h.astype(dtype) + l.astype(dtype))
+    if rows <= _MV_ROWS:
+        return widen(hi, lo)
+
+    def block(i, out):
+        r0 = jnp.minimum(i * _MV_ROWS, rows - _MV_ROWS)
+        part = widen(jax.lax.dynamic_slice_in_dim(hi, r0, _MV_ROWS),
+                     jax.lax.dynamic_slice_in_dim(lo, r0, _MV_ROWS))
+        return jax.lax.dynamic_update_slice_in_dim(out, part, r0, 0)
+
+    shape = jax.ShapeDtypeStruct((_MV_ROWS,) + hi.shape[1:], dtype)
+    out = jnp.zeros(rows, jax.eval_shape(fn, shape).dtype)
+    return jax.lax.fori_loop(0, -(-rows // _MV_ROWS), block, out)
+
+
+def _givens_step(j, h: Array, cs: Array, sn: Array, g: Array):
+    """Fold Hessenberg column ``j`` (``h``, length m + 1) into the QR of the
+    least-squares problem min ||beta e1 - H y||: apply rotations 0..j-1,
+    then form rotation j, which zeroes ``h[j + 1]`` and also rotates the
+    right side ``g``.  Returns (R's column j, cs, sn, g); ``|g[j + 1]|`` is
+    then the least-squares residual of the first j + 1 columns."""
+
+    def rotate(i, h):
+        hi, hi1 = h[i], h[i + 1]
+        return h.at[i].set(cs[i] * hi + sn[i] * hi1).at[i + 1].set(
+            -jnp.conj(sn[i]) * hi + cs[i] * hi1)
+
+    h = jax.lax.fori_loop(0, j, rotate, h)
+    top, low = h[j], h[j + 1]
+    atop, alow = jnp.abs(top), jnp.abs(low)
+    t = jnp.hypot(atop, alow)
+    phase = jnp.where(atop == 0, 1, top / jnp.where(atop == 0, 1, atop))
+    c = jnp.where(t == 0, 1, atop / jnp.where(t == 0, 1, t))
+    s = jnp.where(t == 0, 0, phase * jnp.conj(low) / jnp.where(t == 0, 1, t))
+    gj = g[j]
+    g = g.at[j].set(c * gj).at[j + 1].set(-jnp.conj(s) * gj)
+    return (h.at[j].set(phase * t).at[j + 1].set(0), cs.at[j].set(c),
+            sn.at[j].set(s.astype(sn.dtype)), g)
+
+
+def _upper_solve(r: Array, g: Array, j) -> Array:
+    """y with R[:j, :j] y[:j] = g[:j] and y[j:] = 0, R (m, m) upper
+    triangular in its first ``j`` columns: back substitution on scalars."""
+    m = r.shape[1]
+    used = jnp.arange(m) < j
+    r = jnp.where(used[:, None] & used[None, :], r[:m], jnp.eye(m, dtype=r.dtype))
+    g = jnp.where(used, g[:m], 0)
+
+    def back(i, y):
+        k = m - 1 - i
+        return y.at[k].set((g[k] - r[k] @ y) / r[k, k])
+
+    return jax.lax.fori_loop(0, m, back, jnp.zeros_like(g))
+
+
 def _gmres(
     matvec: Callable[[Array], Array],
     precond: Callable[[Array], Array],
     b: Array,
-    x0: Array,
     restart: int,
-    tol: Array,
-    max_restarts: int,
-) -> Tuple[Array, Array]:
-    """Left-preconditioned restarted GMRES on a single RHS vector.
+    max_steps: int,
+    cte: Array,
+) -> Tuple[Array, Array, Array, Array]:
+    """Right-preconditioned flexible restarted GMRES (FGMRES) on one
+    right-hand side, from x0 = precond(b).  Returns (x, ||b - A x||_inf,
+    GMRES steps, converged).
 
-    Static-shape Arnoldi: the Krylov basis lives in a fixed (restart+1, n)
-    buffer inside ``lax.fori_loop`` — the XLA-friendly form of the
-    reference's dynamic rotation loop (gesv_mixed_gmres.cc)."""
-    n = b.shape[0]
-    dtype = b.dtype
-    m = restart
+    The stop test is HPL's, on the unpreconditioned residual in b's own
+    precision, with ||b||_inf dropped from its denominator (stricter):
+    ||b - A x||_inf <= cte ||x||_inf, cte = 16 u n ||A||_inf.  It is
+    measured after x0 and after every cycle (phase ``residual``).  Inside
+    a cycle the Givens-rotated residual |g[j + 1]| stops the Arnoldi loop
+    early: with right preconditioning it is ||b - A x_j||_2 (exactly so in
+    exact arithmetic, the Arnoldi vectors being orthonormal), which bounds
+    the inf-norm.  Each step keeps its preconditioned direction
+    ``z = precond(v)`` (flexible GMRES: a low-precision preconditioner is
+    not one fixed linear operator) and x moves along Z y.  The Arnoldi
+    basis lives in static (restart + 1, n) buffers (the reference's
+    rotation loop, gesv_mixed_gmres.cc, in XLA form); orthogonalization is
+    classical Gram-Schmidt applied twice.  Phases: ``residual`` (products
+    with A), ``precond`` (the low-precision solves), ``arnoldi`` (basis,
+    rotations and the small triangular solve)."""
+    from ..parallel.comm import phase_scope
 
-    def restart_body(rs, carry):
-        x, _ = carry
-        r = precond(b - matvec(x))
-        beta = jnp.linalg.norm(r)
-        v0 = r / jnp.where(beta == 0, 1, beta)
-        V = jnp.zeros((m + 1, n), dtype).at[0].set(v0)
-        H = jnp.zeros((m + 1, m), dtype)
+    n, dtype, m = b.shape[0], b.dtype, restart
+    rdt = jnp.real(b).dtype
+    rows = jnp.arange(m + 1)
 
-        def arnoldi(j, vh):
-            V, H = vh
-            w = precond(matvec(V[j]))
-            # modified Gram-Schmidt against all m+1 rows (rows > j are zero)
-            h = matmul(jnp.conj(V), w[:, None])[:, 0]
-            mask = (jnp.arange(m + 1) <= j).astype(dtype)
-            h = h * mask
-            w = w - matmul(h[None, :], V)[0]
-            hn = jnp.linalg.norm(w)
-            H = H.at[:, j].set(h + 0).at[j + 1, j].set(hn.astype(dtype))
-            V = V.at[j + 1].set(w / jnp.where(hn == 0, 1, hn))
-            return V, H
+    def measure(x):
+        with phase_scope("residual"):
+            r = b - matvec(x)
+            rn = jnp.max(jnp.abs(r))
+        return r, rn, rn <= cte * jnp.max(jnp.abs(x))
 
-        V, H = jax.lax.fori_loop(0, m, arnoldi, (V, H))
-        # solve least squares min || beta e1 - H y ||
-        e1 = jnp.zeros(m + 1, dtype).at[0].set(beta.astype(dtype))
-        y = jnp.linalg.lstsq(H, e1)[0]
-        x = x + matmul(y[None, :], V[:m])[0]
-        rnorm = jnp.linalg.norm(precond(b - matvec(x)))
-        return x, rnorm
+    def cycle(c):
+        x, r, _, steps, _ = c
+        with phase_scope("arnoldi"):
+            beta = jnp.linalg.norm(r)
+            v = jnp.zeros((m + 1, n), dtype).at[0].set(r / jnp.where(beta == 0, 1, beta))
+            g = jnp.zeros(m + 1, dtype).at[0].set(beta.astype(dtype))
+            tol = cte * jnp.max(jnp.abs(x))
+        arn0 = (jnp.int32(0), v, jnp.zeros((m, n), dtype), jnp.zeros((m + 1, m), dtype),
+                jnp.zeros(m, rdt), jnp.zeros(m, dtype), g, beta)
 
+        def more(s):
+            j, *_, est = s
+            return (j < m) & (steps + j < max_steps) & ~(est <= tol)
+
+        def arnoldi(s):
+            j, v, z, hm, cs, sn, g, _ = s
+            with phase_scope("precond"):
+                zj = precond(v[j])
+            with phase_scope("residual"):
+                w = matvec(zj)
+            with phase_scope("arnoldi"):
+                keep = (rows <= j).astype(dtype)
+                h = jnp.sum(jnp.conj(v) * w, axis=-1) * keep
+                w = w - jnp.sum(h[:, None] * v, axis=0)
+                h2 = jnp.sum(jnp.conj(v) * w, axis=-1) * keep
+                w = w - jnp.sum(h2[:, None] * v, axis=0)
+                hn = jnp.linalg.norm(w)
+                h = (h + h2).at[j + 1].set(hn.astype(dtype))
+                v = v.at[j + 1].set(w / jnp.where(hn == 0, 1, hn))
+                col, cs, sn, g = _givens_step(j, h, cs, sn, g)
+            return (j + 1, v, z.at[j].set(zj), hm.at[:, j].set(col), cs, sn, g,
+                    jnp.abs(g[j + 1]))
+
+        j, _, z, hm, _, _, g, _ = jax.lax.while_loop(more, arnoldi, arn0)
+        with phase_scope("arnoldi"):
+            x = x + jnp.sum(_upper_solve(hm, g, j)[:, None] * z, axis=0)
+        r, rn, done = measure(x)
+        return x, r, rn, steps + j, done
+
+    with phase_scope("precond"):
+        x0 = precond(b)
+    r0, rn0, done0 = measure(x0)
     # while_loop, not fori_loop + cond: under the multi-RHS vmap a
-    # batched-predicate cond lowers to both-branches-execute + select,
-    # so converged columns would keep paying full Arnoldi cycles for all
-    # max_restarts trips.  A while_loop's batched cond is ANY-lane: the
-    # batch stops at the SLOWEST column's cycle count, and unbatched
-    # semantics are unchanged (loop while unconverged, at most
-    # max_restarts cycles).
-    def cont(c):
-        i, _x, rn = c
-        return (i < max_restarts) & (rn > tol)
+    # batched-predicate cond lowers to both-branches-execute + select, and
+    # a while_loop's batched predicate stops at the slowest column
+    x, _, rn, steps, done = jax.lax.while_loop(
+        lambda c: ~c[4] & (c[3] < max_steps), cycle,
+        (x0, r0, rn0, jnp.int32(0), done0))
+    return x, rn, steps, done
 
-    def step(c):
-        i, x, rn = c
-        x, rn = restart_body(i, (x, rn))
-        return i + 1, x, rn
 
-    _, x, rnorm = jax.lax.while_loop(
-        cont, step,
-        (jnp.int32(0), x0, jnp.asarray(jnp.inf, jnp.real(b).dtype)),
-    )
-    return x, rnorm
+def _gmres_multi_rhs(hi, lo, b, precond, restart, max_restarts):
+    """GMRES-IR on each column of ``b`` ((n,) or (n, k)) for A = hi + lo
+    (``_split_pair``), stopping on HPL's test (``_gmres``).  Returns (x
+    like b, worst ||b - A x||_inf, most GMRES steps of any column, every
+    column converged).
+
+    The columns are independent Krylov solves with identical static
+    shapes, so the single-RHS solver is ``vmap``ped over them — ONE
+    compiled program for any B width.  At most ``restart *
+    max_restarts`` steps per column."""
+    from ..parallel.comm import phase_scope
+
+    n, dtype = b.shape[0], b.dtype
+    unit = jnp.finfo(dtype).eps / 2  # HPL's eps, LAPACK dlamch('E')
+    with phase_scope("residual"):
+        anorm = jnp.max(_row_blocks(hi, lo, dtype, lambda m: jnp.sum(jnp.abs(m), axis=-1)))
+    cte = 16 * unit * n * anorm
+
+    def one(bv):
+        matvec = lambda v: _row_blocks(hi, lo, dtype, lambda m: jnp.sum(m * v, axis=-1))
+        return _gmres(matvec, precond, bv, restart, restart * max_restarts, cte)
+
+    if b.ndim == 1:
+        return one(b)
+    x, rn, steps, done = jax.vmap(one, in_axes=1, out_axes=(1, 0, 0, 0))(b)
+    return x, jnp.max(rn), jnp.max(steps), jnp.all(done)
+
+
+def _lu_factor_lo(a_lo: Array, opts: Optional[Options]):
+    """The low-precision LU that preconditions GMRES-IR: Option.MethodLU
+    (partial pivoting by default, CALU, or NoPiv); the no-pivot factor's
+    trailing updates run at Option.Precision (``lu._schur_product``)."""
+    from ..blas3.blas3 import _mul_prec
+    from .lu import getrf_array, getrf_nopiv_array, getrf_tntpiv_array
+
+    method = MethodLU(get_option(opts, Option.MethodLU, MethodLU.PartialPiv))
+    precision = _mul_prec(opts)
+    if method == MethodLU.NoPiv:
+        return getrf_nopiv_array(a_lo, precision)
+    if precision != Precision.Highest:
+        raise ValueError(f"Option.Precision {precision.value!r} needs MethodLU.NoPiv; "
+                         f"the {method.value} factor runs at full precision")
+    if method == MethodLU.PartialPiv:
+        return getrf_array(a_lo)
+    if method == MethodLU.CALU:
+        return getrf_tntpiv_array(a_lo)
+    raise ValueError(f"GMRES-IR has no {method.value} factor")
+
+
+@instrument("gesv_mixed_gmres")
+def _gesv_gmres(a: Array, b: Array, opts: Optional[Options], restart: int):
+    """The LU GMRES-IR core: (RefineResult, worst ||b - A x||_inf)."""
+    from .lu import getrs_array
+
+    lo_dtype = jnp.complex64 if jnp.issubdtype(a.dtype, jnp.complexfloating) else jnp.float32
+    hi, lo = _split_pair(a, lo_dtype)
+    with jax.named_scope("getrf"):
+        f = _lu_factor_lo(hi, opts)
+    with jax.named_scope("gmres"):
+        precond = lambda v: getrs_array(f, v.astype(lo_dtype)[:, None])[:, 0].astype(a.dtype)
+        x, resid, steps, done = _gmres_multi_rhs(
+            hi, lo, b, precond, restart, get_option(opts, Option.MaxIterations, 30))
+    return RefineResult(x, steps, done, f.info), resid
 
 
 def gesv_mixed_gmres_array(
     a: Array, b: Array, opts: Optional[Options] = None, restart: int = 30
 ) -> Tuple[Array, Array]:
     """GMRES-IR: low-precision LU as preconditioner for high-precision GMRES
-    (src/gesv_mixed_gmres.cc). b may be (n,) or (n, 1). Returns (x, resid)."""
-    from .lu import getrf_array, getrs_array
-
-    lo_dtype = jnp.complex64 if jnp.issubdtype(a.dtype, jnp.complexfloating) else jnp.float32
-    f = getrf_array(a.astype(lo_dtype))
-    precond = lambda v: getrs_array(f, v.astype(lo_dtype)[:, None])[:, 0].astype(a.dtype)
-    matvec = lambda v: matmul(a, v[:, None])[:, 0].astype(a.dtype)
-    return _gmres_multi_rhs(
-        a, b, matvec, precond, restart, get_option(opts, Option.MaxIterations, 30)
-    )
+    (src/gesv_mixed_gmres.cc). b may be (n,) or (n, 1).  The factor follows
+    Option.MethodLU / Option.Precision (``_lu_factor_lo``); GMRES stops on
+    HPL's test (``_gmres``).  Returns (x, ||b - A x||_inf), the worst over
+    the columns; ``api.lu_solve_mixed`` returns the step count too."""
+    res, resid = _gesv_gmres(a, b, opts, restart)
+    return res.x, resid
 
 
 def posv_mixed_gmres_array(
     a: Array, b: Array, uplo: Uplo = Uplo.Lower, opts: Optional[Options] = None, restart: int = 30
 ) -> Tuple[Array, Array]:
-    """src/posv_mixed_gmres.cc analogue."""
+    """src/posv_mixed_gmres.cc analogue.  Returns (x, ||b - A x||_inf)."""
     from .chol import potrf_array, potrs_array
 
     lo_dtype = jnp.complex64 if jnp.issubdtype(a.dtype, jnp.complexfloating) else jnp.float32
@@ -289,29 +450,7 @@ def posv_mixed_gmres_array(
     a_full = symmetrize(a, uplo, conj=conj)
     f, _ = potrf_array(a.astype(lo_dtype), uplo)
     precond = lambda v: potrs_array(f, v.astype(lo_dtype)[:, None], uplo)[:, 0].astype(a.dtype)
-    matvec = lambda v: matmul(a_full, v[:, None])[:, 0].astype(a.dtype)
-    return _gmres_multi_rhs(
-        a, b, matvec, precond, restart, get_option(opts, Option.MaxIterations, 30)
-    )
-
-
-def _gmres_multi_rhs(a, b, matvec, precond, restart, max_restarts):
-    """Solve each RHS column with _gmres; returns (x like b, worst resid).
-
-    The columns are independent Krylov solves with identical static
-    shapes, so the single-RHS solver is ``vmap``ped over them — ONE
-    compiled program for any B width (the predecessor re-traced ``_gmres``
-    per column in a Python loop: B with 30 columns compiled 30 copies of
-    the whole Arnoldi program)."""
-    eps = jnp.finfo(a.dtype).eps
-    rdtype = jnp.real(a).dtype
-    scale = jnp.sqrt(jnp.asarray(float(a.shape[0]), rdtype)) * eps
-
-    def one(bv):
-        tol = (scale * jnp.linalg.norm(bv)).astype(rdtype)
-        return _gmres(matvec, precond, bv, jnp.zeros_like(bv), restart, tol, max_restarts)
-
-    if b.ndim == 1:
-        return one(b)
-    x, rnorms = jax.vmap(one, in_axes=1, out_axes=(1, 0))(b)
-    return x, jnp.max(rnorms)
+    x, resid, _, _ = _gmres_multi_rhs(
+        *_split_pair(a_full, lo_dtype), b, precond, restart,
+        get_option(opts, Option.MaxIterations, 30))
+    return x, resid

@@ -198,3 +198,35 @@ def test_potrf_scan_64bit_elements_compile_for_v5e(dtype, one_chip, on_tpu):
     a = jax.ShapeDtypeStruct((512, 512), dtype, sharding=one_chip)
     factor = lambda a: _potrf_scan(symmetrize(a, Uplo.Lower, conj=cplx), nb=128)
     assert "while" in jax.jit(factor).lower(a).compile().as_text()
+
+
+@pytest.mark.parametrize("precision", ["fast", "highest"])
+def test_getrf_nopiv_scan_carry_in_place_for_v5e(precision, one_chip, on_tpu):
+    """The scanned LU without pivoting shares the Cholesky scan's step
+    driver and order, and likewise keeps its carry in place on a TPU at
+    either update tier: no whole-view copy in any bucket's loop body."""
+    from conftest import loop_view_copies
+    from slate_tpu.linalg.lu import _getrf_nopiv_scan
+    from slate_tpu.types import Precision
+
+    n, nb = 1024, 128
+    a = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
+    factor = lambda a: _getrf_nopiv_scan(a, nb=nb, precision=Precision(precision))
+    copies = loop_view_copies(jax.jit(factor).lower(a).compile().as_text(), min_dim=2 * nb)
+    assert sorted(copies) == [256, 512, 768, 1024], copies
+    assert not any(copies.values()), copies
+
+
+def test_lu_solve_mixed_compiles_for_v5e(one_chip, on_tpu):
+    """HPL-MxP's solve compiles for a v5e with x64 on: the float64 GMRES
+    loops, Givens rotations and row-block products lower there."""
+    from slate_tpu import api
+    from slate_tpu.types import MethodLU, Option, Precision
+
+    assert jax.config.jax_enable_x64
+    n = 1024
+    a = jax.ShapeDtypeStruct((n, n), jnp.float64, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((n, 1), jnp.float64, sharding=one_chip)
+    opts = {Option.MethodLU: MethodLU.NoPiv, Option.Precision: Precision.Fast}
+    compiled = jax.jit(lambda a, b: api.lu_solve_mixed(a, b, opts)).lower(a, b).compile()
+    assert "while" in compiled.as_text()
