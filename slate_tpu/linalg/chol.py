@@ -25,7 +25,6 @@ from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..blas3.blas3 import _NB, _split, trsm_array
 from ..core.matrix import (
@@ -40,18 +39,10 @@ from ..core.matrix import (
 )
 from ..ops.matmul import matmul
 from ..ops.pallas_ops import chol_diag_inv_pallas, panel_engaged
+from ..ops.tile_ops import row_major
 from ..types import Diag, Op, Options, Side, Uplo
 
 ArrayLike = Union[jax.Array, BaseMatrix]
-
-
-def _row_major(x: jax.Array) -> jax.Array:
-    """``x`` pinned row-major.  64-bit elements (f64, c64) are left to
-    layout assignment: a TPU rewrites them into 32-bit pairs, and that
-    rewrite cannot carry a layout constraint."""
-    if x.dtype.itemsize > 4:
-        return x
-    return with_layout_constraint(x, Layout((0, 1)))  # XLA's {1,0}
 
 
 def _potrf_lower(a: jax.Array) -> jax.Array:
@@ -91,7 +82,7 @@ def _scan_factor(a: jax.Array, nb: int, nbuckets: int, step) -> jax.Array:
     at 4 buckets.  ``step(k, view, off, rows)`` factors panel k of the
     bucket's view (``off`` its global head, ``rows`` its row indices) and
     returns the view updated in place; the driver pins the carry
-    row-major around it (``_row_major``) and puts the bucket boundaries
+    row-major around it (``row_major``) and puts the bucket boundaries
     under the ``regroup`` phase scope."""
     from ..parallel.comm import phase_scope
 
@@ -113,7 +104,7 @@ def _scan_factor(a: jax.Array, nb: int, nbuckets: int, step) -> jax.Array:
         rows = jnp.arange(np_ - off)
 
         def body(k, view, off=off, rows=rows):
-            return _row_major(step(k, _row_major(view), off, rows))
+            return row_major(step(k, row_major(view), off, rows))
 
         view = jax.lax.fori_loop(k0, k1, body, view)
         with phase_scope("regroup"):
@@ -140,7 +131,7 @@ def _potrf_scan(a: jax.Array, nb: int = 256, nbuckets: int = 4) -> jax.Array:
     whole view.  The order changes no finite value: ``l21`` is zero in the
     panel rows and above, so ``l21 l21^H`` is zero in the panel columns
     (a failed pivot still NaN-poisons the diagonal from its block on).  The
-    carry is pinned row-major, the update's output layout (``_row_major``):
+    carry is pinned row-major, the update's output layout (``row_major``):
     left to layout assignment, a TPU carries it column-major, as the
     panel column's ops prefer, and converts the whole view to and from
     that every step.  Each such copy reads and writes the whole view, as

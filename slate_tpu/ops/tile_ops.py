@@ -18,6 +18,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..types import Diag, Norm, NormScope, Uplo
 from ..core.matrix import band_project, tri_project
@@ -109,6 +110,15 @@ def transpose(a: jax.Array, conj: bool = False) -> jax.Array:
         return transpose_pallas(a)
     at = jnp.swapaxes(a, -1, -2)
     return jnp.conj(at) if conj else at
+
+
+def row_major(x: jax.Array) -> jax.Array:
+    """``x`` (2-D) pinned row-major, XLA's {1,0}.  64-bit elements (f64,
+    c64) are left to layout assignment: a TPU rewrites them into 32-bit
+    pairs, and that rewrite cannot carry a layout constraint."""
+    if x.dtype.itemsize > 4:
+        return x
+    return with_layout_constraint(x, Layout((0, 1)))
 
 
 # ---------------------------------------------------------------------------

@@ -163,6 +163,40 @@ def test_mesh_solve_compiles_for_2x2(driver, fused, topo, on_tpu):
     assert ("tpu_custom_call" in compiled.as_text()) == fused
 
 
+def test_pp_panel_slab_row_major_for_v5e(topo, on_tpu):
+    """The mesh LU's pivoted panel on a described 2x2 v5e, as
+    ``gesv_mesh`` runs it: every column loop carries its sub-block as a
+    row-major f32[ib, rows] slab (rows on the lanes, so a narrow slab
+    pads nothing) and copies nothing larger than one slab column, and no
+    panel-sized array is copied anywhere in the program."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from slate_tpu import parallel
+    from slate_tpu.parallel.dist_lu import _pp_jit, _pp_sub_width
+    from slate_tpu.parallel.mesh import COL_AXIS, ROW_AXIS
+
+    mesh = parallel.make_mesh(2, 2, devices=topo.devices)
+    nt = 4
+    rows, ib = nt // 2 * NB, _pp_sub_width(NB)
+    t = jax.ShapeDtypeStruct((nt, nt, NB, NB), jnp.float32,
+                             sharding=NamedSharding(mesh, P(ROW_AXIS, COL_AXIS)))
+    hlo = _pp_jit.lower(t, mesh, 2, 2, nt, nt * NB, 1, "auto", "auto").compile().as_text()
+    slab = f"f32[{ib},{rows}]{{1,0"
+    loops = re.findall(r"= \((.*?)\) while\(.*?body=%?([\w.\-]+)", hlo)
+    col_loops = [body for carry, body in loops if slab in carry]
+    assert len(col_loops) == NB // ib, loops
+
+    def copied(text):  # shapes of the f32 copies in ``text``
+        return re.findall(r"= (f32\[[\d,]*\])\{\S* copy(?:-start)?\(", text)
+
+    assert not {f"f32[{rows},{NB}]", f"f32[{NB},{rows}]"} & set(copied(hlo))
+    for body in col_loops:
+        text = hlo.split(f"\n%{body} ", 1)[1].split("\n}", 1)[0]
+        assert set(copied(text)) <= {f"f32[{ib},1]"}, body  # one slab column
+
+
 def test_potrf_scan_carry_in_place_for_v5e(one_chip, on_tpu):
     """The scanned Cholesky's loop carry stays in place on a TPU: no
     whole-view copy in any bucket's loop body.  Left to layout
