@@ -154,12 +154,11 @@ def bench_getrf():
 
 
 # ---------------------------------------------------------------------------
-# panel microbenches (ISSUE 6): the fused Pallas panel kernels vs their XLA
-# reference chains, at the mesh kernels' panel shape (nb = 256, 63 below
-# tiles = one n = 16384 panel column).  These isolate exactly the latency
-# story the fused kernels target — SURVEY "Hard parts": potrf f32 runs at
-# ~2.4 TF/s while gemm f32 hits ~101 TF/s because the panel phase is nb
-# tiny dispatches; the kernel collapses it to ONE.
+# panel microbenches (ISSUE 6): the XLA panel chains at the mesh kernels'
+# panel shape (nb = 256, 63 below tiles = one n = 16384 panel column).
+# These isolate the panel latency story — SURVEY "Hard parts": potrf f32
+# runs at ~2.4 TF/s while gemm f32 hits ~101 TF/s because the panel phase
+# is nb tiny dispatches.
 # ---------------------------------------------------------------------------
 
 NB_PANEL = 256
@@ -177,59 +176,39 @@ def _panel_operands(kind):
     return jnp.asarray(d), jnp.asarray(tiles)
 
 
-def bench_panel_potrf(impl):
-    """One potrf panel phase: diag factor (+inverse) then 63 tile solves.
-    xla = today's cholesky + batched-trsm chain; pallas = the fused
-    chol_panel_tiles kernel."""
-    from slate_tpu.ops.pallas_ops import chol_panel_tiles_pallas
-
+def bench_panel_potrf():
+    """One potrf panel phase: the cholesky + batched-trsm chain over the
+    diag tile and 63 tile solves."""
     d, tiles = _panel_operands("potrf")
-    if impl == "pallas":
 
-        @jax.jit
-        def run(d, t):
-            lkk, solved = chol_panel_tiles_pallas(d, t)
-            return jnp.sum(jnp.abs(lkk)) + jnp.sum(solved[:, :1, :1])
-
-    else:
-
-        @jax.jit
-        def run(d, t):
-            lkk = jax.lax.linalg.cholesky(d)
-            solved = jax.lax.linalg.triangular_solve(
-                jnp.broadcast_to(lkk.T, t.shape), t,
-                left_side=False, lower=False, transpose_a=False,
-            )
-            return jnp.sum(jnp.abs(lkk)) + jnp.sum(solved[:, :1, :1])
+    @jax.jit
+    def run(d, t):
+        lkk = jax.lax.linalg.cholesky(d)
+        solved = jax.lax.linalg.triangular_solve(
+            jnp.broadcast_to(lkk.T, t.shape), t,
+            left_side=False, lower=False, transpose_a=False,
+        )
+        return jnp.sum(jnp.abs(lkk)) + jnp.sum(solved[:, :1, :1])
 
     t = _timeit(run, d, tiles)
     flops = NB_PANEL**3 / 3.0 + L_PANEL * NB_PANEL**3
     return flops / t / 1e9
 
 
-def bench_panel_getrf(impl):
+def bench_panel_getrf():
     """One LU-nopiv panel-column phase (diag L\\U + 63 right-solves)."""
     from slate_tpu.linalg.lu import _getrf_nopiv_rec
-    from slate_tpu.ops.pallas_ops import lu_panel_tiles_pallas
 
     d, tiles = _panel_operands("getrf")
-    if impl == "pallas":
 
-        @jax.jit
-        def run(d, t):
-            lu, solved = lu_panel_tiles_pallas(d, t)
-            return jnp.sum(jnp.abs(lu)) + jnp.sum(solved[:, :1, :1])
-
-    else:
-
-        @jax.jit
-        def run(d, t):
-            lu = _getrf_nopiv_rec(d)
-            solved = jax.lax.linalg.triangular_solve(
-                jnp.broadcast_to(jnp.triu(lu), t.shape), t,
-                left_side=False, lower=False, transpose_a=False,
-            )
-            return jnp.sum(jnp.abs(lu)) + jnp.sum(solved[:, :1, :1])
+    @jax.jit
+    def run(d, t):
+        lu = _getrf_nopiv_rec(d)
+        solved = jax.lax.linalg.triangular_solve(
+            jnp.broadcast_to(jnp.triu(lu), t.shape), t,
+            left_side=False, lower=False, transpose_a=False,
+        )
+        return jnp.sum(jnp.abs(lu)) + jnp.sum(solved[:, :1, :1])
 
     t = _timeit(run, d, tiles)
     flops = 2.0 * NB_PANEL**3 / 3.0 + L_PANEL * NB_PANEL**3
@@ -343,30 +322,21 @@ def bench_update_getrf(impl):
     return 2.0 * MTL_UPD * NTL_UPD * NB_UPD**3 / t / 1e9
 
 
-def bench_panel_qr(impl):
+def bench_panel_qr():
     """One tall-skinny Householder panel (m = 16384, w = 64) WITH the
     compact-WY T accumulation — the CAQR / two-stage building block."""
     from slate_tpu.linalg.qr import _larft, _panel_qr
-    from slate_tpu.ops.pallas_ops import qr_panel_pallas
 
     m, w = L_PANEL * NB_PANEL + NB_PANEL, 64
     a = jnp.asarray(
         np.random.default_rng(8).standard_normal((m, w)).astype(np.float32)
     )
-    if impl == "pallas":
 
-        @jax.jit
-        def run(a):
-            vr, tau, t = qr_panel_pallas(a)
-            return jnp.sum(jnp.abs(tau)) + jnp.sum(t[:1])
-
-    else:
-
-        @jax.jit
-        def run(a):
-            vr, tau = _panel_qr(a)
-            t = _larft(vr, tau)
-            return jnp.sum(jnp.abs(tau)) + jnp.sum(t[:1])
+    @jax.jit
+    def run(a):
+        vr, tau = _panel_qr(a)
+        t = _larft(vr, tau)
+        return jnp.sum(jnp.abs(tau)) + jnp.sum(t[:1])
 
     t = _timeit(run, a)
     return 2.0 * m * w * w / t / 1e9
@@ -673,14 +643,10 @@ def main():
         ("gemm_bf16_gflops", lambda: bench_gemm(jnp.bfloat16, 64, jnp.float32)),
         ("gemm_int8_gops", lambda: bench_gemm(jnp.int8, 64, jnp.int32)),
         ("gemm_f32_gflops", lambda: bench_gemm(jnp.float32, 32)),
-        # fused-panel story (ISSUE 6): the same panel phase under both
-        # lowerings — the pallas/xla ratio IS the panel speedup headline
-        ("panel_potrf_xla_gflops", lambda: bench_panel_potrf("xla")),
-        ("panel_potrf_pallas_gflops", lambda: bench_panel_potrf("pallas")),
-        ("panel_getrf_xla_gflops", lambda: bench_panel_getrf("xla")),
-        ("panel_getrf_pallas_gflops", lambda: bench_panel_getrf("pallas")),
-        ("panel_qr_xla_gflops", lambda: bench_panel_qr("xla")),
-        ("panel_qr_pallas_gflops", lambda: bench_panel_qr("pallas")),
+        # panel story (ISSUE 6): the XLA panel phases alone
+        ("panel_potrf_xla_gflops", bench_panel_potrf),
+        ("panel_getrf_xla_gflops", bench_panel_getrf),
+        ("panel_qr_xla_gflops", bench_panel_qr),
         # fused trailing-update story (PR 20): the k-step's OTHER side —
         # the grid-wide consume — under both Option.UpdateImpl lowerings
         ("update_summa_xla_gflops", lambda: bench_update_summa("xla")),
@@ -720,11 +686,6 @@ def main():
             extras[name] = f"failed: {type(e).__name__}"
             _progress(f"extra: {name} failed: {e!r:.200}")
         _emit(gflops, extras)  # atomic checkpoint after every metric
-    for kind in ("potrf", "getrf", "qr"):
-        px = extras.get(f"panel_{kind}_xla_gflops")
-        pp = extras.get(f"panel_{kind}_pallas_gflops")
-        if isinstance(px, float) and isinstance(pp, float) and px > 0:
-            extras[f"panel_{kind}_pallas_speedup"] = round(pp / px, 2)
     for kind in ("summa", "potrf", "getrf"):
         ux = extras.get(f"update_{kind}_xla_gflops")
         up = extras.get(f"update_{kind}_pallas_gflops")

@@ -309,29 +309,6 @@ if ring["comm_bytes"] != base["comm_bytes"]:
 print(f"ci: cross-impl comm bytes equal ({ring['comm_bytes']:.0f} B/dev)")
 PY
 
-# fused-panel cross-impl pass (ISSUE 6): re-run both smokes under the
-# explicit Pallas panel lowering — on this CPU harness every fused panel
-# kernel runs under the Pallas interpreter, so Option.PanelImpl=pallas is
-# exercised end-to-end (dist potrf / LU-nopiv panels, the ABFT fused
-# trailing-update+checksum consume) on every commit.  The default runs
-# above cover auto -> xla (bitwise today's schedules); slate_lint covers
-# the pallas jaxprs via the *_panel_pallas registry variants.
-SLATE_TPU_PANEL_IMPL=pallas python -m slate_tpu.obs.smoke --out artifacts/obs_panel
-SLATE_TPU_PANEL_IMPL=pallas python -m slate_tpu.ft.smoke --out artifacts/ft_panel
-
-# panel parity artifact: regenerate the fused-kernel vs XLA-reference
-# RunReports and gate the backward-error parity (QR must be bitwise; the
-# explicit-inverse panels must stay within the threshold class).  The
-# tool gates internally; the obs.report --check pass re-validates the
-# COMMITTED artifact shape through the standard CLI (the acceptance
-# gate) — one threshold source for both.
-PANEL_PARITY_THRESHOLD=3
-python tools/panel_report.py --out artifacts/obs \
-    --threshold "$PANEL_PARITY_THRESHOLD"
-python -m slate_tpu.obs.report --check \
-    artifacts/obs/panel_pallas.report.json artifacts/obs/panel_xla.report.json \
-    --threshold "$PANEL_PARITY_THRESHOLD"
-
 # fused trailing-update cross-impl pass (PR 20): re-run the smokes under
 # the explicit Pallas trailing-update lowering — on this CPU harness the
 # one-kernel fused updates (SUMMA stationary-C consume, potrf trailing
@@ -378,14 +355,11 @@ python -m slate_tpu.obs.report --check \
 # smoke asserts the acceptance surface — off is jaxpr-identical to the
 # direct path, auto and the Ozaki int8 residual meet the refine.py gate,
 # the GMRES tier converges, the ir.* counters land in a schema-valid
-# RunReport — then re-runs under the ring broadcast and Pallas panel
-# lowerings to prove opts thread end-to-end into the f32 factor AND the
-# refinement loop's residual SUMMA.
+# RunReport — then re-runs under the ring broadcast to prove opts thread
+# end-to-end into the f32 factor AND the refinement loop's residual SUMMA.
 python -m slate_tpu.parallel.mixed_smoke --out artifacts/mixed
 SLATE_TPU_BCAST_IMPL=ring python -m slate_tpu.parallel.mixed_smoke \
     --out artifacts/mixed_ring
-SLATE_TPU_PANEL_IMPL=pallas python -m slate_tpu.parallel.mixed_smoke \
-    --out artifacts/mixed_panel
 
 # mixed accuracy artifact: regenerate the off-vs-auto RunReports and gate
 # the residual-gate parity (the mixed ladder may not be numerically worse

@@ -10,8 +10,8 @@ fails the run on any cell that does not hold:
                           kernel, the serve tracer is host-side), or —
                           with no base — its own re-trace under the
                           option's off/neutral-forcing context
-                          (NumMonitor off, PanelImpl xla, obs forced on
-                          must all leave the jaxpr untouched).
+                          (NumMonitor off, UpdateImpl xla, obs forced
+                          on must all leave the jaxpr untouched).
 ``zero_extra_collectives``  the audited comm-record MULTISET —
                           (op, payload bytes, audit multiplicity)
                           tuples from ``comm_audit`` — equals the
@@ -25,8 +25,8 @@ fails the run on any cell that does not hold:
 
 Two registry-completeness checks run first, so a new driver cannot ship
 with an undeclared contract: every contract-bearing ``Option``
-(Checkpoint / NumMonitor / FaultTolerance / Lookahead / PanelImpl /
-BcastImpl / serve_queue) must be consumed by at least one declaration,
+(Checkpoint / NumMonitor / FaultTolerance / Lookahead / BcastImpl /
+UpdateImpl / serve_queue) must be consumed by at least one declaration,
 and every naming-convention variant (``*_num`` / ``*_ckpt*`` /
 ``*_abft*`` / ``*_flight`` / ``*_queue``) must declare (or belong to a
 family that declares) the matching contract.
@@ -66,8 +66,8 @@ from .findings import Finding  # noqa: E402
 # the Router's own programs — service-off is byte-identical dispatch).
 CONTRACT_OPTIONS = (
     Option.Checkpoint, Option.NumMonitor, Option.FaultTolerance,
-    Option.Lookahead, Option.PanelImpl, Option.BcastImpl,
-    Option.UpdateImpl, "obs", "serve_queue",
+    Option.Lookahead, Option.BcastImpl, Option.UpdateImpl, "obs",
+    "serve_queue",
 )
 
 # naming-convention rules: (predicate kind, token, option, scope).
@@ -159,10 +159,6 @@ def _off_context(option):
         from ..obs.numerics import use_num_monitor
 
         return use_num_monitor("off")
-    if option is Option.PanelImpl:
-        from ..ops.pallas_ops import use_panel_impl
-
-        return use_panel_impl("xla")
     if option is Option.UpdateImpl:
         from ..ops.pallas_ops import use_update_impl
 

@@ -203,7 +203,6 @@ def _gemm_f32(ctx):
 
 @register("potrf_dist", contracts=(
     Contract(Option.NumMonitor, "off_jaxpr_identical"),
-    Contract(Option.PanelImpl, "off_jaxpr_identical"),
 ))
 def _potrf(ctx):
     from ..parallel.dist_chol import potrf_dist
@@ -222,7 +221,6 @@ def _pbtrf(ctx):
 
 @register("getrf_nopiv_dist", contracts=(
     Contract(Option.NumMonitor, "off_jaxpr_identical"),
-    Contract(Option.PanelImpl, "off_jaxpr_identical"),
 ))
 def _getrf_nopiv(ctx):
     from ..parallel.dist_lu import getrf_nopiv_dist
@@ -770,9 +768,8 @@ def _ft_spec(armed: bool, op: str):
     return jnp.asarray(ints), jnp.asarray(vals)
 
 
-def _ft_gemm_build(ctx, armed, panel_impl=None):
+def _ft_gemm_build(ctx, armed):
     from ..ft import abft
-    from ..ops.pallas_ops import resolve_panel_impl
     from ..parallel.comm import resolve_bcast_impl
     from ..parallel.dist import DistMatrix, from_dense, to_dense
 
@@ -784,22 +781,20 @@ def _ft_gemm_build(ctx, armed, panel_impl=None):
         ad = from_dense(a_aug, ctx.mesh, NB)
         bd = from_dense(b_aug, ctx.mesh, NB)
         cd = from_dense(c_aug, ctx.mesh, NB)
-        out, disc = abft._ft_summa_jit(
+        out = abft._ft_summa_jit(
             ad.tiles, bd.tiles, cd.tiles, 1.0, 0.0,
-            ctx.mesh, ctx.p, ctx.q, kt, 1, resolve_bcast_impl(),
-            resolve_panel_impl(panel_impl), mt, fi, fv,
+            ctx.mesh, ctx.p, ctx.q, kt, 1, resolve_bcast_impl(), fi, fv,
         )
         dense = to_dense(DistMatrix(
             tiles=out, m=a_aug.shape[0], n=b_aug.shape[1], nb=NB, mesh=ctx.mesh,
         ))
-        return abft._gemm_residual(dense, NB, mt, nt), disc
+        return abft._gemm_residual(dense, NB, mt, nt)
 
     return fn, (a, b)
 
 
-def _ft_factor_build(ctx, op, armed, panel_impl=None):
+def _ft_factor_build(ctx, op, armed):
     from ..ft import abft
-    from ..ops.pallas_ops import resolve_panel_impl
     from ..parallel.comm import resolve_bcast_impl
     from ..parallel.dist import DistMatrix, from_dense, to_dense
 
@@ -812,8 +807,7 @@ def _ft_factor_build(ctx, op, armed, panel_impl=None):
         aug, mt, _ = abft._encode_factor(x, NB, ctx.mesh, with_cols=is_lu)
         d = from_dense(aug, ctx.mesh, NB)
         out_t, info = kern(
-            d.tiles, ctx.mesh, ctx.p, ctx.q, mt, 1, resolve_bcast_impl(),
-            resolve_panel_impl(panel_impl), fi, fv,
+            d.tiles, ctx.mesh, ctx.p, ctx.q, mt, 1, resolve_bcast_impl(), fi, fv,
         )
         dense = to_dense(DistMatrix(
             tiles=out_t, m=aug.shape[0], n=aug.shape[1], nb=NB, mesh=ctx.mesh,
@@ -861,90 +855,6 @@ def _ft_lu_detect(ctx):
 ))
 def _ft_lu_correct(ctx):
     return _ft_factor_build(ctx, "getrf_nopiv", armed=True)
-
-
-# ---------------------------------------------------------------------------
-# fused-panel variants (ISSUE 6): the Option.PanelImpl=pallas lowerings
-# under the gate.  The default entries above trace the XLA panel forms
-# (auto resolves to xla on the CPU trace mesh, keeping them bitwise
-# today's schedules); these pin the fused Pallas panel kernels — the
-# interpret-mode pallas_call sub-jaxprs are walked by the same passes, so
-# declared axis names, audit_scope coverage, and Precision.HIGHEST on the
-# in-kernel MXU dots all stay under the gate.
-# ---------------------------------------------------------------------------
-
-
-@register("potrf_dist_panel_pallas", tags=("panel",), contracts=(
-    Contract(Option.PanelImpl, "bytes_invariant", "potrf_dist"),
-))
-def _potrf_pallas(ctx):
-    from ..parallel.dist_chol import potrf_dist
-
-    a = ctx.dist(kind="spd", diag_pad=True)
-    return (lambda x: potrf_dist(x, panel_impl="pallas")), (a,)
-
-
-@register("getrf_nopiv_dist_panel_pallas", tags=("panel",), contracts=(
-    Contract(Option.PanelImpl, "bytes_invariant", "getrf_nopiv_dist"),
-))
-def _getrf_nopiv_pallas(ctx):
-    from ..parallel.dist_lu import getrf_nopiv_dist
-
-    a = ctx.dist(kind="tril", diag_pad=True)
-    return (lambda x: getrf_nopiv_dist(x, panel_impl="pallas")), (a,)
-
-
-@register("gemm_abft_panel_pallas", tags=("panel", "ft"))
-def _ft_gemm_pallas(ctx):
-    """The fused trailing-update+checksum SUMMA consume (and its online
-    Huang-Abraham discrepancy reduction) under the gate.  No
-    bytes_invariant contract: the fused path's online discrepancy adds
-    one deliberate psum up each mesh column that the XLA lowering skips."""
-    return _ft_gemm_build(ctx, armed=False, panel_impl="pallas")
-
-
-@register("potrf_abft_panel_pallas", tags=("panel", "ft"), contracts=(
-    Contract(Option.PanelImpl, "bytes_invariant", "potrf_abft_detect"),
-))
-def _ft_potrf_pallas(ctx):
-    return _ft_factor_build(ctx, "potrf", armed=False, panel_impl="pallas")
-
-
-@register("getrf_tntpiv_panel_pallas", tags=("panel",), contracts=(
-    Contract(Option.PanelImpl, "bytes_invariant", "getrf_tntpiv_dist"),
-))
-def _getrf_tnt_pallas(ctx):
-    """CALU with the post-pivot panel factor/solve fused (the tournament
-    pivot search itself has no Pallas dispatch site — PR 20)."""
-    from ..parallel.dist_lu import getrf_tntpiv_dist
-
-    a = ctx.dist(diag_pad=True)
-    return (lambda x: getrf_tntpiv_dist(x, panel_impl="pallas")), (a,)
-
-
-@register("getrf_pp_panel_pallas", tags=("panel",), contracts=(
-    Contract(Option.PanelImpl, "bytes_invariant", "getrf_pp_dist"),
-))
-def _getrf_pp_pallas(ctx):
-    """Partial-pivot LU with the panel-row solve fused (the in-loop
-    column factor IS the pivot search, so only the row solve dispatches
-    — PR 20)."""
-    from ..parallel.dist_lu import getrf_pp_dist
-
-    a = ctx.dist(diag_pad=True)
-    return (lambda x: getrf_pp_dist(x, panel_impl="pallas")), (a,)
-
-
-@register("geqrf_dist_panel_pallas", tags=("panel",), contracts=(
-    Contract(Option.PanelImpl, "bytes_invariant", "geqrf_dist"),
-))
-def _geqrf_pallas(ctx):
-    """CAQR with the offset panel factor + larft fused (PR 20: the
-    formerly-pinned dist_qr panels now dispatch by Option.PanelImpl)."""
-    from ..parallel.dist_qr import geqrf_dist
-
-    a = ctx.dist()
-    return (lambda x: geqrf_dist(x, panel_impl="pallas")), (a,)
 
 
 # ---------------------------------------------------------------------------
@@ -1580,7 +1490,7 @@ def _potrf_ckpt_seg(ctx):
 
     a = ctx.dist(kind="spd", diag_pad=True)
     return (lambda t: ckpt._potrf_seg_jit(
-        t, 0.0, ctx.mesh, ctx.p, ctx.q, a.nt, N, 1, a.nt, "auto", "xla",
+        t, 0.0, ctx.mesh, ctx.p, ctx.q, a.nt, N, 1, a.nt, "auto",
         False)), (a.tiles,)
 
 
@@ -1590,7 +1500,7 @@ def _getrf_nopiv_ckpt_seg(ctx):
 
     a = ctx.dist(kind="tril", diag_pad=True)
     return (lambda t: ckpt._lu_seg_jit(
-        t, 0.0, ctx.mesh, ctx.p, ctx.q, a.nt, N, 1, a.nt, "auto", "xla",
+        t, 0.0, ctx.mesh, ctx.p, ctx.q, a.nt, N, 1, a.nt, "auto",
         False)), (a.tiles,)
 
 

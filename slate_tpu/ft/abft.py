@@ -46,12 +46,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..obs import instrument
-from ..ops.pallas_ops import (
-    ft_summa_update_pallas,
-    panel_engaged,
-    panel_impl_scope,
-    resolve_panel_impl,
-)
 from ..parallel.comm import (
     PRECISE,
     all_gather_a,
@@ -63,7 +57,6 @@ from ..parallel.comm import (
     local_indices,
     pipelined_factor_loop,
     prefetch_bcast,
-    psum_a,
     resolve_bcast_impl,
     shard_map_compat,
 )
@@ -127,31 +120,19 @@ def _hit3(x, hit, li, mode, value):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
-def _ft_summa_jit(at, bt, ct, alpha, beta, mesh, p, q, kt, la, bi, pi, mt,
-                  fi, fv):
-    """Checksum-carrying SUMMA.  ``mt`` is the DATA tile-row count of the
-    augmented grid (checksum tile rows sit at logical rows mt, mt+1).
-
-    Returns (product tiles, online_disc): under ``pi = pallas`` each
-    consume step runs the fused trailing-update+checksum kernel
-    (ops.pallas_ops.ft_summa_update_pallas) — the MXU update and the
-    Huang-Abraham weighted row sums accumulate in ONE pass over the
-    trailing tiles — and ``online_disc`` is the on-device max
-    discrepancy |recomputed weighted sums - carried checksum rows| at
-    loop end (an in-pass detector for update-stream corruption; the host
-    verify on the dense output stays the repair authority).  Under the
-    XLA lowering ``online_disc`` is the -1 sentinel (no extra pass is
-    run; detection is host-side as before)."""
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9, 10))
+def _ft_summa_jit(at, bt, ct, alpha, beta, mesh, p, q, kt, la, bi, fi, fv):
+    """Checksum-carrying SUMMA over the augmented grid (checksum tile
+    rows and columns ride the product like data tiles; the host verify
+    on the dense output detects, locates and repairs)."""
     spec = P(ROW_AXIS, COL_AXIS)
 
     def kernel(a_loc, b_loc, fi, fv):
         mtl, _, nb, _ = a_loc.shape
         ntl = b_loc.shape[1]
         dtype = a_loc.dtype
-        r, c, i_log, _ = local_indices(p, q, mtl, ntl)
+        r, c, _, _ = local_indices(p, q, mtl, ntl)
         slots = _slots(fi, fv)
-        fused = panel_engaged(dtype, nb * nb * a_loc.dtype.itemsize)
 
         def fetch(k):
             acol_own = lax.dynamic_slice_in_dim(a_loc, k // q, 1, axis=1)[:, 0]
@@ -179,48 +160,25 @@ def _ft_summa_jit(at, bt, ct, alpha, beta, mesh, p, q, kt, la, bi, pi, mt,
                 acc = _hit4(acc, hit, fti // p, ftj // q, fmode, val)
             return acc
 
-        data_row = i_log < mt  # unit/ramp weights vanish on checksum rows
-        w1 = data_row.astype(dtype)
-        w2 = ((i_log + 1) * data_row).astype(dtype)
-
-        def consume(k, panels, state):
+        def consume(k, panels, acc):
             acol, brow = panels
-            acc, part = state
-            if fused:
-                acc, part = ft_summa_update_pallas(acc, acol, brow, w1, w2, part)
-            else:
-                acc = acc + jnp.einsum(
-                    "iab,jbc->ijac", acol, brow, precision=PRECISE
-                ).astype(dtype)
-            return trail_hits(k, acc), part
+            acc = acc + jnp.einsum(
+                "iab,jbc->ijac", acol, brow, precision=PRECISE
+            ).astype(dtype)
+            return trail_hits(k, acc)
 
         acc0 = jnp.zeros((mtl, ntl, nb, nb), dtype)
-        part0 = jnp.zeros((2, ntl, nb, nb), dtype)
-        acc, part = prefetch_bcast(kt, la, fetch, consume, (acc0, part0))
-        if not fused:
-            return acc, jnp.full((1, 1), -1.0, jnp.float32)
-        # online discrepancy: global weighted data-row sums (one psum up
-        # each mesh column) minus the CARRIED checksum-row tiles, judged
-        # on the checksum rows' owners and pmax-replicated
-        ws = psum_a(part, ROW_AXIS)  # (2, ntl, nb, nb)
-        d = jnp.zeros((), jnp.float32)
-        for s in range(CSR):
-            own = (mt + s) % p == r
-            carried = acc[jnp.minimum((mt + s) // p, mtl - 1)]
-            ds = jnp.where(own, jnp.abs(ws[s] - carried), 0)
-            d = jnp.maximum(d, jnp.max(ds).astype(jnp.float32))
-        disc = lax.pmax(lax.pmax(d, ROW_AXIS), COL_AXIS)
-        return acc, disc[None, None]
+        return prefetch_bcast(kt, la, fetch, consume, acc0)
 
-    with bcast_impl_scope(bi), panel_impl_scope(pi):
-        prod, disc = shard_map_compat(
+    with bcast_impl_scope(bi):
+        prod = shard_map_compat(
             kernel,
             mesh=mesh,
             in_specs=(spec, spec, P(), P()),
-            out_specs=(spec, P(ROW_AXIS, COL_AXIS)),
+            out_specs=spec,
             check_vma=False,
         )(at, bt, fi, fv)
-    return (alpha * prod + beta * ct).astype(at.dtype), jnp.max(disc)
+    return (alpha * prod + beta * ct).astype(at.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -228,8 +186,8 @@ def _ft_summa_jit(at, bt, ct, alpha, beta, mesh, p, q, kt, la, bi, pi, mt,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7))
-def _ft_potrf_jit(at, mesh, p, q, nt, la, bi, pi, fi, fv):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6))
+def _ft_potrf_jit(at, mesh, p, q, nt, la, bi, fi, fv):
     spec = P(ROW_AXIS, COL_AXIS)
 
     def kernel(t_loc, fi, fv):
@@ -260,8 +218,8 @@ def _ft_potrf_jit(at, mesh, p, q, nt, la, bi, pi, fi, fv):
             kc = k // q
             dtile = bcast_diag_tile(view, k, p, q, nb)
             pcol = lax.dynamic_slice_in_dim(view, kc, 1, axis=1)[:, 0]
-            # factor + panel solve dispatch by Option.PanelImpl — the
-            # checksum rows ride the solved stack like any other tile
+            # factor + panel solve — the checksum rows ride the solved
+            # stack like any other tile
             lkk, solved = _chol_panel_factor_solve(dtile, pcol, cplx)
             below = (i_log > k)[:, None, None]
             on_diag = (i_log == k)[:, None, None]
@@ -343,7 +301,7 @@ def _ft_potrf_jit(at, mesh, p, q, nt, la, bi, pi, fi, fv):
         info = jnp.where(info >= big, 0, info).astype(jnp.int32)
         return t_loc, info[None, None]
 
-    with bcast_impl_scope(bi), panel_impl_scope(pi):
+    with bcast_impl_scope(bi):
         lt, info = shard_map_compat(
             kernel,
             mesh=mesh,
@@ -359,8 +317,8 @@ def _ft_potrf_jit(at, mesh, p, q, nt, la, bi, pi, fi, fv):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7))
-def _ft_lu_jit(at, mesh, p, q, nt, la, bi, pi, fi, fv):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6))
+def _ft_lu_jit(at, mesh, p, q, nt, la, bi, fi, fv):
     spec = P(ROW_AXIS, COL_AXIS)
 
     def kernel(t_loc, fi, fv):
@@ -431,7 +389,7 @@ def _ft_lu_jit(at, mesh, p, q, nt, la, bi, pi, fi, fv):
         info = jnp.where(info >= big, 0, info).astype(jnp.int32)
         return t_loc, info[None, None]
 
-    with bcast_impl_scope(bi), panel_impl_scope(pi):
+    with bcast_impl_scope(bi):
         lut, info = shard_map_compat(
             kernel,
             mesh=mesh,
@@ -1078,7 +1036,7 @@ def _factor_result(out_np, n: int, nb: int, mesh) -> DistMatrix:
 
 def _factor_ft(
     op: str, a, mesh, nb: int, policy: FtPolicy, lookahead,
-    bcast_impl=None, panel_impl=None, _rerun: bool = False,
+    bcast_impl=None, _rerun: bool = False,
 ):
     is_lu = op == "getrf_nopiv"
     a = jnp.asarray(a)
@@ -1093,7 +1051,6 @@ def _factor_ft(
     kern = _ft_lu_jit if is_lu else _ft_potrf_jit
     out_t, info = kern(
         d.tiles, mesh, p, q, mt, la, resolve_bcast_impl(bcast_impl),
-        resolve_panel_impl(panel_impl),
         jnp.asarray(ints), jnp.asarray(vals, jnp.result_type(float)),
     )
     inject.consume(op)
@@ -1115,8 +1072,7 @@ def _factor_ft(
                 FtReport(op=op),
             )
         res2, info2, rep2 = _factor_ft(
-            op, a, mesh, nb, policy, lookahead, bcast_impl, panel_impl,
-            _rerun=True,
+            op, a, mesh, nb, policy, lookahead, bcast_impl, _rerun=True,
         )
         if int(info2) == 0:  # first breakdown was fault-induced
             count("ft.detected", op)
@@ -1149,8 +1105,7 @@ def _factor_ft(
     # re-detect on the rerun and escalate above
     count("ft.recomputed", op)
     res, info2, rep2 = _factor_ft(
-        op, a, mesh, nb, policy, lookahead, bcast_impl, panel_impl,
-        _rerun=True,
+        op, a, mesh, nb, policy, lookahead, bcast_impl, _rerun=True,
     )
     rep2.action = "recomputed"
     rep2.detections = dets + rep2.detections
@@ -1236,7 +1191,7 @@ def _gemm_try_repair(out_np, drn, dcn, verdR, verdC, nb, mt, nt):
 
 def _gemm_ft(
     alpha, a, b, mesh, nb: int, beta, cin, policy: FtPolicy, lookahead,
-    bcast_impl=None, panel_impl=None, _rerun: bool = False,
+    bcast_impl=None, _rerun: bool = False,
 ):
     a, b = jnp.asarray(a), jnp.asarray(b)
     if a.shape[1] != b.shape[0]:
@@ -1248,19 +1203,12 @@ def _gemm_ft(
     cd = from_dense(c_aug, mesh, nb)
     la = la_depth(lookahead, kt)
     ints, vals = inject.spec_arrays("gemm")
-    out_t, online_disc = _ft_summa_jit(
+    out_t = _ft_summa_jit(
         ad.tiles, bd.tiles, cd.tiles, alpha, beta, mesh, p, q, kt, la,
-        resolve_bcast_impl(bcast_impl), resolve_panel_impl(panel_impl), mt,
+        resolve_bcast_impl(bcast_impl),
         jnp.asarray(ints), jnp.asarray(vals, jnp.result_type(float)),
     )
     inject.consume("gemm")
-    if float(online_disc) >= 0:
-        # fused-kernel path: record the in-pass Huang-Abraham discrepancy
-        # (the single-pass detector; the host verify below stays the
-        # repair authority and catches post-update corruption too)
-        from ..obs import REGISTRY as _OBS
-
-        _OBS.gauge_set("ft.online_disc", float(online_disc), op="gemm")
     out_np = np.asarray(
         to_dense(DistMatrix(tiles=out_t, m=a_aug.shape[0], n=b_aug.shape[1],
                             nb=nb, mesh=mesh))
@@ -1288,7 +1236,7 @@ def _gemm_ft(
     count("ft.recomputed", "gemm")
     out2, rep2 = _gemm_ft(
         alpha, a, b, mesh, nb, beta, cin, policy, lookahead, bcast_impl,
-        panel_impl, _rerun=True,
+        _rerun=True,
     )
     rep2.action = "recomputed"
     rep2.detections = dets + rep2.detections
@@ -1312,16 +1260,9 @@ def _bi_opt(opts: Optional[Options]):
     return get_option(opts, Option.BcastImpl)
 
 
-def _pi_opt(opts: Optional[Options]):
-    from ..types import Option, get_option
-
-    return get_option(opts, Option.PanelImpl)
-
-
 def gemm_ft(
     alpha, a, b, mesh, nb: int = 256, beta=0.0, c=None,
     policy: FtPolicy = FtPolicy.Correct, lookahead=None, bcast_impl=None,
-    panel_impl=None,
 ) -> Tuple[jax.Array, FtReport]:
     """ABFT SUMMA: C = alpha A B + beta C with carried checksums.
     Returns (dense C, FtReport); raises FtError per policy.  The checksum
@@ -1332,12 +1273,12 @@ def gemm_ft(
 
         return gemm_mesh(alpha, a, b, mesh, nb, beta, c), FtReport(op="gemm")
     return _gemm_ft(alpha, a, b, mesh, nb, beta, c, policy, lookahead,
-                    bcast_impl, panel_impl)
+                    bcast_impl)
 
 
 def potrf_ft(
     a, mesh, nb: int = 256, policy: FtPolicy = FtPolicy.Correct, lookahead=None,
-    bcast_impl=None, panel_impl=None,
+    bcast_impl=None,
 ) -> Tuple[DistMatrix, jax.Array, FtReport]:
     """ABFT mesh Cholesky.  Returns (L DistMatrix, info, FtReport)."""
     if policy == FtPolicy.Off:
@@ -1345,13 +1286,12 @@ def potrf_ft(
 
         l, info = potrf_mesh(a, mesh, nb)
         return l, info, FtReport(op="potrf")
-    return _factor_ft("potrf", a, mesh, nb, policy, lookahead, bcast_impl,
-                      panel_impl)
+    return _factor_ft("potrf", a, mesh, nb, policy, lookahead, bcast_impl)
 
 
 def getrf_nopiv_ft(
     a, mesh, nb: int = 256, policy: FtPolicy = FtPolicy.Correct, lookahead=None,
-    bcast_impl=None, panel_impl=None,
+    bcast_impl=None,
 ) -> Tuple[DistMatrix, jax.Array, FtReport]:
     """ABFT mesh LU-nopiv.  Returns (LU DistMatrix, info, FtReport)."""
     if policy == FtPolicy.Off:
@@ -1360,7 +1300,7 @@ def getrf_nopiv_ft(
         lu, info = getrf_nopiv_mesh(a, mesh, nb)
         return lu, info, FtReport(op="getrf_nopiv")
     return _factor_ft("getrf_nopiv", a, mesh, nb, policy, lookahead,
-                      bcast_impl, panel_impl)
+                      bcast_impl)
 
 
 # opts-driven wrappers with the plain mesh-driver signatures, used by
@@ -1372,7 +1312,7 @@ def gemm_mesh_ft(alpha, a, b, mesh, nb=256, beta=0.0, c=None,
                  opts: Optional[Options] = None) -> jax.Array:
     out, _ = gemm_ft(alpha, a, b, mesh, nb, beta, c,
                      policy=resolve_policy(opts), lookahead=_la_opt(opts),
-                     bcast_impl=_bi_opt(opts), panel_impl=_pi_opt(opts))
+                     bcast_impl=_bi_opt(opts))
     return out
 
 
@@ -1389,8 +1329,7 @@ def her2k_mesh_ft(alpha, a, b, mesh, nb=256, beta=0.0, c=None,
 @instrument("potrf_mesh_ft")
 def potrf_mesh_ft(a, mesh, nb=256, opts: Optional[Options] = None):
     l, info, _ = potrf_ft(a, mesh, nb, policy=resolve_policy(opts),
-                          lookahead=_la_opt(opts), bcast_impl=_bi_opt(opts),
-                          panel_impl=_pi_opt(opts))
+                          lookahead=_la_opt(opts), bcast_impl=_bi_opt(opts))
     return l, info
 
 
@@ -1398,8 +1337,7 @@ def potrf_mesh_ft(a, mesh, nb=256, opts: Optional[Options] = None):
 def getrf_nopiv_mesh_ft(a, mesh, nb=256, opts: Optional[Options] = None):
     lu, info, _ = getrf_nopiv_ft(a, mesh, nb, policy=resolve_policy(opts),
                                  lookahead=_la_opt(opts),
-                                 bcast_impl=_bi_opt(opts),
-                                 panel_impl=_pi_opt(opts))
+                                 bcast_impl=_bi_opt(opts))
     return lu, info
 
 

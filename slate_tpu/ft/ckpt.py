@@ -80,7 +80,6 @@ from jax.sharding import PartitionSpec as P
 from ..core.tiling import cyclic_perm, inv_perm
 from ..obs import instrument
 from ..obs.numerics import resolve_num_monitor
-from ..ops.pallas_ops import panel_impl_scope, resolve_panel_impl
 from ..parallel.comm import (
     audit_scope,
     bcast_impl_scope,
@@ -198,7 +197,6 @@ class Checkpoint:
     nb: int
     grid: Tuple[int, int]  # (p, q) the snapshot was taken on
     bcast_impl: str
-    panel_impl: str
     num_monitor: bool
     tiles: np.ndarray  # LOGICAL-order padded tile grid
     rowperm: Optional[np.ndarray] = None
@@ -230,7 +228,7 @@ class Checkpoint:
         meta = dict(
             op=self.op, step=self.step, every=self.every, m=self.m,
             n=self.n, nb=self.nb, grid=list(self.grid),
-            bcast_impl=self.bcast_impl, panel_impl=self.panel_impl,
+            bcast_impl=self.bcast_impl,
             num_monitor=self.num_monitor, growth_abort=self.growth_abort,
             async_snapshots=self.async_snapshots,
         )
@@ -264,7 +262,7 @@ class Checkpoint:
                 op=meta["op"], step=int(meta["step"]),
                 every=int(meta["every"]), m=int(meta["m"]), n=int(meta["n"]),
                 nb=int(meta["nb"]), grid=tuple(meta["grid"]),
-                bcast_impl=meta["bcast_impl"], panel_impl=meta["panel_impl"],
+                bcast_impl=meta["bcast_impl"],
                 num_monitor=bool(meta["num_monitor"]), tiles=z["tiles"],
                 rowperm=(z["rowperm"] if "rowperm" in z.files else None),
                 gauges=gauges, arrays=arrs,
@@ -314,8 +312,8 @@ def _logical_to_cyclic(t: np.ndarray, p: int, q: int) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8, 9, 10, 11))
-def _potrf_seg_jit(at, g, mesh, p, q, nt, n_true, k0, k1, bi, pi, nm):
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8, 9, 10))
+def _potrf_seg_jit(at, g, mesh, p, q, nt, n_true, k0, k1, bi, nm):
     spec = P(ROW_AXIS, COL_AXIS)
 
     def kernel(t_loc, g_in):
@@ -380,7 +378,7 @@ def _potrf_seg_jit(at, g, mesh, p, q, nt, n_true, k0, k1, bi, pi, nm):
         gg = lax.pmin(lax.pmin(gg, ROW_AXIS), COL_AXIS)
         return t_loc, gg[None, None]
 
-    with bcast_impl_scope(bi), panel_impl_scope(pi):
+    with bcast_impl_scope(bi):
         lt, g_out = shard_map_compat(
             kernel, mesh=mesh, in_specs=(spec, P()),
             out_specs=(spec, P(ROW_AXIS, COL_AXIS)), check_vma=False,
@@ -424,8 +422,8 @@ def _potrf_fin_jit(at, g, mesh, p, q, nt, n_true, nm):
     return jnp.max(info), gz[0, 0]
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8, 9, 10, 11))
-def _lu_seg_jit(at, g, mesh, p, q, nt, m_true, k0, k1, bi, pi, nm):
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8, 9, 10))
+def _lu_seg_jit(at, g, mesh, p, q, nt, m_true, k0, k1, bi, nm):
     spec = P(ROW_AXIS, COL_AXIS)
 
     def kernel(t_loc, g_in):
@@ -474,7 +472,7 @@ def _lu_seg_jit(at, g, mesh, p, q, nt, m_true, k0, k1, bi, pi, nm):
         gg = lax.pmax(lax.pmax(gg, ROW_AXIS), COL_AXIS)
         return t_loc, gg[None, None]
 
-    with bcast_impl_scope(bi), panel_impl_scope(pi):
+    with bcast_impl_scope(bi):
         lt, g_out = shard_map_compat(
             kernel, mesh=mesh, in_specs=(spec, P()),
             out_specs=(spec, P(ROW_AXIS, COL_AXIS)), check_vma=False,
@@ -571,7 +569,7 @@ def _pp_seg_jit(at, rowperm, g, mesh, p, q, nt, m_true, k0, k1, bi, nm):
             gg = jnp.zeros((), jnp.float32)
         return t_loc, rowperm[None], gg[None, None]
 
-    with bcast_impl_scope(bi), panel_impl_scope("xla"):  # see _pp_jit
+    with bcast_impl_scope(bi):
         lt, perm, g_out = shard_map_compat(
             kernel, mesh=mesh, in_specs=(spec, P(), P()),
             out_specs=(spec, P(ROW_AXIS), P(ROW_AXIS, COL_AXIS)),
@@ -603,10 +601,7 @@ def _qr_seg_jit(at, tls, tvs, tts, mesh, p, q, m_true, k0, k1, bi):
         with audit_scope(k1 - k0):
             return lax.fori_loop(k0, k1, step, (t_loc, tl_loc, tv, tt))
 
-    # pinned xla (see _pp_jit): the committed segment artifacts record
-    # the XLA panel traces, and in interpret mode pallas is bitwise-
-    # equal anyway, so chained-vs-fused comparisons stay exact
-    with bcast_impl_scope(bi), panel_impl_scope("xla"):
+    with bcast_impl_scope(bi):
         return shard_map_compat(
             kernel, mesh=mesh,
             in_specs=(spec, P(ROW_AXIS), P(), P()),
@@ -645,7 +640,7 @@ def _qr_seg_nm_jit(at, tls, tvs, tts, g, mesh, p, q, m_true, k0, k1, bi):
         gg = lax.pmax(lax.pmax(gg, ROW_AXIS), COL_AXIS)
         return t_loc, tl_loc, tv, tt, gg[None, None]
 
-    with bcast_impl_scope(bi), panel_impl_scope("xla"):  # see _qr_seg_jit
+    with bcast_impl_scope(bi):
         t, tls, tvs, tts, g_out = shard_map_compat(
             kernel, mesh=mesh,
             in_specs=(spec, P(ROW_AXIS), P(), P(), P()),
@@ -739,13 +734,13 @@ def _he2hb_seg_nm_jit(at, vqs, tqs, g, mesh, p, q, n_true, nb, k0, k1, bi):
 # ---------------------------------------------------------------------------
 
 
-def _seg_dispatch(op, st, mesh, p, q, nt, m_true, k0, k1, bi, pi, nm):
+def _seg_dispatch(op, st, mesh, p, q, nt, m_true, k0, k1, bi, nm):
     if op == "potrf":
         st["tiles"], g = _potrf_seg_jit(
-            st["tiles"], st["g"], mesh, p, q, nt, m_true, k0, k1, bi, pi, nm)
+            st["tiles"], st["g"], mesh, p, q, nt, m_true, k0, k1, bi, nm)
     elif op == "getrf_nopiv":
         st["tiles"], g = _lu_seg_jit(
-            st["tiles"], st["g"], mesh, p, q, nt, m_true, k0, k1, bi, pi, nm)
+            st["tiles"], st["g"], mesh, p, q, nt, m_true, k0, k1, bi, nm)
     elif op == "getrf_pp":
         st["tiles"], st["rowperm"], g = _pp_seg_jit(
             st["tiles"], st["rowperm"], st["g"], mesh, p, q, nt, m_true,
@@ -779,7 +774,7 @@ def _seg_dispatch(op, st, mesh, p, q, nt, m_true, k0, k1, bi, pi, nm):
         st["g"] = g
 
 
-def _snapshot(op, d: DistMatrix, st, k, every, bi, pi, nm,
+def _snapshot(op, d: DistMatrix, st, k, every, bi, nm,
               ga: bool = False, asnap: bool = False) -> Checkpoint:
     p, q = mesh_shape(d.mesh)
     gauges: Dict[str, np.ndarray] = {}
@@ -790,7 +785,7 @@ def _snapshot(op, d: DistMatrix, st, k, every, bi, pi, nm,
     arrays = {kk: np.asarray(st[kk]) for kk in _MULTI_KEYS.get(op, ())}
     ck = Checkpoint(
         op=op, step=int(k), every=int(every), m=d.m, n=d.n, nb=d.nb,
-        grid=(p, q), bcast_impl=bi, panel_impl=pi, num_monitor=nm,
+        grid=(p, q), bcast_impl=bi, num_monitor=nm,
         tiles=_cyclic_to_logical(np.asarray(st["tiles"]), p, q),
         rowperm=(np.asarray(st["rowperm"]) if "rowperm" in st else None),
         gauges=gauges, arrays=arrays, growth_abort=ga,
@@ -810,10 +805,10 @@ class _PendingSnapshot:
     stay immutable while the next segment computes — the materialized
     Checkpoint is bitwise-equal to the sync path's."""
 
-    def __init__(self, op, d, st, k, every, bi, pi, nm, ga=False):
+    def __init__(self, op, d, st, k, every, bi, nm, ga=False):
         # shallow copy: _seg_dispatch REBINDS st entries (functional
         # updates), so the captured references keep the boundary values
-        self._args = (op, d, dict(st), k, every, bi, pi, nm, ga, True)
+        self._args = (op, d, dict(st), k, every, bi, nm, ga, True)
         for v in self._args[2].values():
             start = getattr(v, "copy_to_host_async", None)
             if start is not None:
@@ -883,7 +878,7 @@ def _multi_init(op: str, d: DistMatrix, st: dict, nsteps: int) -> None:
         st["tqs"] = jnp.zeros((max(nsteps, 1), nb, nb), dtype)
 
 
-def _run(op: str, d: DistMatrix, k_from: int, every: int, bi: str, pi: str,
+def _run(op: str, d: DistMatrix, k_from: int, every: int, bi: str,
          nm: bool, rowperm=None, gauges=None,
          ckpt0: Optional[Checkpoint] = None, arrays=None,
          async_snap: bool = False, growth_abort: bool = False):
@@ -956,13 +951,13 @@ def _run(op: str, d: DistMatrix, k_from: int, every: int, bi: str, pi: str,
                 # the strict-schedule step helpers stop early — and dies
                 # there; the partial carry is discarded with it
                 _seg_dispatch(op, dict(st), mesh, p, q, nt, m_true,
-                              k, kill.k, bi, pi, nm)
+                              k, kill.k, bi, nm)
                 count("ft.ckpt_inseg_kills", op)
             count("ft.ckpt_kills", op)
             count("ft.ckpt_lost_steps", op, float(kill.k - k))
             fence()  # an in-flight host copy survives the preemption
             raise Preempted(op, kill.k, last)
-        _seg_dispatch(op, st, mesh, p, q, nt, m_true, k, k2, bi, pi, nm)
+        _seg_dispatch(op, st, mesh, p, q, nt, m_true, k, k2, bi, nm)
         if growth_abort and nm and "amax0" in st:
             a0 = float(st["amax0"])
             growth = float(st["g"]) / a0 if a0 > 0 else 0.0
@@ -974,11 +969,11 @@ def _run(op: str, d: DistMatrix, k_from: int, every: int, bi: str, pi: str,
         if k < nt:
             if async_snap:
                 fence()  # previous copy fences only now, one interval late
-                pending = _PendingSnapshot(op, d, st, k, every, bi, pi, nm,
+                pending = _PendingSnapshot(op, d, st, k, every, bi, nm,
                                            growth_abort)
                 count("ft.ckpt_async_snapshots", op)
             else:
-                last = _snapshot(op, d, st, k, every, bi, pi, nm,
+                last = _snapshot(op, d, st, k, every, bi, nm,
                                  growth_abort)
     fence()  # account the final interior snapshot's overlap + bytes
     return _finish(op, d, st, nm)
@@ -986,7 +981,7 @@ def _run(op: str, d: DistMatrix, k_from: int, every: int, bi: str, pi: str,
 
 # ---------------------------------------------------------------------------
 # Public drivers (Option.Checkpoint off routes to the fused kernels:
-# trace-identical — the PanelImpl/NumMonitor off-mode contract)
+# trace-identical — the NumMonitor off-mode contract)
 # ---------------------------------------------------------------------------
 
 
@@ -998,7 +993,6 @@ def _check_square(a: DistMatrix, who: str) -> None:
 
 @instrument("potrf_ckpt")
 def potrf_ckpt(a: DistMatrix, every=None, bcast_impl: Optional[str] = None,
-               panel_impl: Optional[str] = None,
                num_monitor: Optional[str] = None, async_snapshots=None):
     """Checkpointed mesh Cholesky: ``potrf_dist`` results (bitwise) with
     the carry snapshotted every ``every`` steps (Option.Checkpoint; None
@@ -1009,11 +1003,9 @@ def potrf_ckpt(a: DistMatrix, every=None, bcast_impl: Optional[str] = None,
     segment (bitwise-equal either way)."""
     ev = resolve_checkpoint(every)
     if ev is None:
-        return potrf_dist(a, bcast_impl=bcast_impl, panel_impl=panel_impl,
-                          num_monitor=num_monitor)
+        return potrf_dist(a, bcast_impl=bcast_impl, num_monitor=num_monitor)
     _check_square(a, "potrf_ckpt")
     return _run("potrf", a, 0, ev, resolve_bcast_impl(bcast_impl),
-                resolve_panel_impl(panel_impl),
                 resolve_num_monitor(num_monitor) == "on",
                 async_snap=resolve_ckpt_async(async_snapshots))
 
@@ -1021,7 +1013,6 @@ def potrf_ckpt(a: DistMatrix, every=None, bcast_impl: Optional[str] = None,
 @instrument("getrf_nopiv_ckpt")
 def getrf_nopiv_ckpt(a: DistMatrix, every=None,
                      bcast_impl: Optional[str] = None,
-                     panel_impl: Optional[str] = None,
                      num_monitor: Optional[str] = None,
                      async_snapshots=None, growth_abort: bool = True):
     """Checkpointed mesh LU-nopiv (getrf_nopiv_dist, bitwise).  Returns
@@ -1034,11 +1025,9 @@ def getrf_nopiv_ckpt(a: DistMatrix, every=None,
     ev = resolve_checkpoint(every)
     if ev is None:
         return getrf_nopiv_dist(a, bcast_impl=bcast_impl,
-                                panel_impl=panel_impl,
                                 num_monitor=num_monitor)
     _check_square(a, "getrf_nopiv_ckpt")
     return _run("getrf_nopiv", a, 0, ev, resolve_bcast_impl(bcast_impl),
-                resolve_panel_impl(panel_impl),
                 resolve_num_monitor(num_monitor) == "on",
                 async_snap=resolve_ckpt_async(async_snapshots),
                 growth_abort=growth_abort)
@@ -1057,7 +1046,7 @@ def getrf_pp_ckpt(a: DistMatrix, every=None,
                              num_monitor=num_monitor)
     _check_square(a, "getrf_pp_ckpt")
     return _run("getrf_pp", a, 0, ev, resolve_bcast_impl(bcast_impl),
-                "xla", resolve_num_monitor(num_monitor) == "on",
+                resolve_num_monitor(num_monitor) == "on",
                 async_snap=resolve_ckpt_async(async_snapshots))
 
 
@@ -1082,7 +1071,7 @@ def geqrf_ckpt(a: DistMatrix, every=None, bcast_impl: Optional[str] = None,
         return geqrf_dist(a, bcast_impl=bcast_impl, num_monitor=num_monitor)
     if a.m < a.n:
         raise ValueError(f"geqrf_ckpt requires m >= n, got {a.m}x{a.n}")
-    return _run("geqrf", a, 0, ev, resolve_bcast_impl(bcast_impl), "xla",
+    return _run("geqrf", a, 0, ev, resolve_bcast_impl(bcast_impl),
                 resolve_num_monitor(num_monitor) == "on",
                 async_snap=resolve_ckpt_async(async_snapshots))
 
@@ -1108,6 +1097,6 @@ def he2hb_ckpt(a: DistMatrix, every=None, bcast_impl: Optional[str] = None,
         raise ValueError("he2hb_ckpt needs a square matrix")
     if ev is None or _he2hb_panel_count(a.n, a.nb) == 0:
         return he2hb_dist(a, bcast_impl=bcast_impl, num_monitor=num_monitor)
-    return _run("he2hb", a, 0, ev, resolve_bcast_impl(bcast_impl), "xla",
+    return _run("he2hb", a, 0, ev, resolve_bcast_impl(bcast_impl),
                 resolve_num_monitor(num_monitor) == "on",
                 async_snap=resolve_ckpt_async(async_snapshots))

@@ -132,8 +132,7 @@ def reshard(d: DistMatrix, mesh: Mesh) -> DistMatrix:
     return out
 
 
-def resume(ck: Checkpoint, mesh: Mesh, bcast_impl: Optional[str] = None,
-           panel_impl: Optional[str] = None):
+def resume(ck: Checkpoint, mesh: Mesh, bcast_impl: Optional[str] = None):
     """Continue a checkpointed factorization from its snapshot on
     ``mesh`` and return exactly what the checkpointed driver would have
     ((L|LU, info), (LU, perm, info) for pp, DistQR for geqrf,
@@ -170,9 +169,8 @@ def resume(ck: Checkpoint, mesh: Mesh, bcast_impl: Optional[str] = None,
     rowperm = _rowperm_to_rows(ck, mt2 * ck.nb)
     count("ft.ckpt_resumes", ck.op)
     bi = bcast_impl if bcast_impl is not None else ck.bcast_impl
-    pi = panel_impl if panel_impl is not None else ck.panel_impl
     out = _ckpt._run(
-        ck.op, d, ck.step, ck.every, bi, pi, ck.num_monitor,
+        ck.op, d, ck.step, ck.every, bi, ck.num_monitor,
         rowperm=rowperm, gauges=(ck.gauges or None), ckpt0=ck,
         arrays=(ck.arrays or None),
         # keep the interrupted run's async preference (persisted in the

@@ -38,7 +38,6 @@ from ..core.matrix import (
     tri_project,
 )
 from ..ops.matmul import matmul
-from ..ops.pallas_ops import chol_diag_inv_pallas, panel_engaged
 from ..ops.tile_ops import row_major
 from ..types import Diag, Op, Options, Side, Uplo
 
@@ -157,18 +156,12 @@ def _potrf_scan(a: jax.Array, nb: int = 256, nbuckets: int = 4) -> jax.Array:
             # the MXU matmul rate at (32768, 256) (measured 46 vs 4
             # ms), and inverting only the nb x nb diag block keeps the
             # backward error at the same O(eps * cond(L_kk)) class.
-            # Under Option.PanelImpl=pallas the factor + inverse pair
-            # is ONE fused on-chip kernel instead of the per-column
-            # cholesky + triangular_solve dispatch chain.
-            if panel_engaged(view.dtype, nb * nb * view.dtype.itemsize):
-                ld, linv = chol_diag_inv_pallas(dblk)
-            else:
-                ld = jax.lax.linalg.cholesky(dblk)
-                eye_nb = jnp.eye(nb, dtype=view.dtype)
-                linv = jax.lax.linalg.triangular_solve(
-                    ld[None], eye_nb[None], left_side=True, lower=True,
-                    transpose_a=False,
-                )[0]
+            ld = jax.lax.linalg.cholesky(dblk)
+            eye_nb = jnp.eye(nb, dtype=view.dtype)
+            linv = jax.lax.linalg.triangular_solve(
+                ld[None], eye_nb[None], left_side=True, lower=True,
+                transpose_a=False,
+            )[0]
             linv_h = jnp.conj(linv).T if cplx else linv.T
             sol = matmul(col, linv_h).astype(view.dtype)
             below = (rows >= kk + nb)[:, None]
@@ -205,12 +198,6 @@ def _potrf_and_inv(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
     n = a.shape[0]
     cplx = jnp.issubdtype(a.dtype, jnp.complexfloating)
     if n <= _NB:
-        if panel_engaged(a.dtype, n * n * a.dtype.itemsize):
-            # fused on-chip factor + inverse: one kernel dispatch for the
-            # whole leaf instead of the unrolled cholesky/trsm micro-op
-            # chains (exact column-loop math for every engaged dtype, so
-            # the f32-seeded f64 refinement below is not needed)
-            return chol_diag_inv_pallas(a)
         if a.dtype == jnp.dtype(jnp.float64):
             return _potrf_inv_base_f64(a)
         l = jax.lax.linalg.cholesky(a)

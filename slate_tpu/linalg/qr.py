@@ -33,11 +33,6 @@ import jax.numpy as jnp
 from ..blas3.blas3 import trsm_array
 from ..core.matrix import tri_project
 from ..ops.matmul import matmul
-from ..ops.pallas_ops import (
-    panel_engaged,
-    qr_panel_offset_pallas,
-    qr_panel_pallas,
-)
 from ..types import Diag, MethodGels, Op, Option, Options, Side, SlateError, Uplo, get_option
 
 Array = jax.Array
@@ -197,24 +192,15 @@ def _larft(vr: Array, tau: Array) -> Array:
 
 
 def _panel_qr_t(a: Array) -> Tuple[Array, Array, Array]:
-    """(packed VR, tau, T) of one panel — the ``_panel_qr`` + ``_larft``
-    pair, fused into ONE Pallas dispatch (reflector generation and the
-    compact-WY T accumulation run on the VMEM-resident panel) when
-    ``Option.PanelImpl`` engages; the XLA pair is the reference and is
-    bitwise-identical to the kernel under interpret mode (same op
-    sequence)."""
-    if panel_engaged(a.dtype, a.size * a.dtype.itemsize):
-        return qr_panel_pallas(a)
+    """(packed VR, tau, T) of one panel: the ``_panel_qr`` + ``_larft``
+    pair."""
     vr, tau = _panel_qr(a)
     return vr, tau, _larft(vr, tau)
 
 
 def _panel_qr_offset_t(a: Array, row0) -> Tuple[Array, Array, Array, Array]:
-    """(r, v, tau, T) of one offset-pivot panel — ``_panel_qr_offset`` +
-    ``_larft_v`` as one fused dispatch when ``Option.PanelImpl``
-    engages (``row0`` may be traced; it rides as a scalar operand)."""
-    if panel_engaged(a.dtype, a.size * a.dtype.itemsize):
-        return qr_panel_offset_pallas(a, row0)
+    """(r, v, tau, T) of one offset-pivot panel: the
+    ``_panel_qr_offset`` + ``_larft_v`` pair (``row0`` may be traced)."""
     r, v, tau = _panel_qr_offset(a, row0)
     return r, v, tau, _larft_v(v, tau)
 

@@ -451,12 +451,12 @@ def _potrf_phase_kernels(p, q, mtl, ntl, nt, nb, cplx):
             "info": info_k}
 
 
-def potrf_steps(at, mesh, p, q, nt, la, bi, pi, ui):
+def potrf_steps(at, mesh, p, q, nt, la, bi, ui):
     """Per-step mesh Cholesky: the _potrf_jit phases (module-level
     _chol_* helpers), unbucketed, fenced per phase."""
     import jax.numpy as jnp
 
-    from ..ops.pallas_ops import panel_impl_scope, update_impl_scope
+    from ..ops.pallas_ops import update_impl_scope
     from ..parallel.comm import bcast_impl_scope
 
     rec = active_recorder()
@@ -464,7 +464,7 @@ def potrf_steps(at, mesh, p, q, nt, la, bi, pi, ui):
     mtl, ntl = at.shape[0] // p, at.shape[1] // q
     nb = at.shape[2]
     cplx = jnp.issubdtype(at.dtype, jnp.complexfloating)
-    ctx = lambda: _scopes(bcast_impl_scope(bi), panel_impl_scope(pi))
+    ctx = lambda: bcast_impl_scope(bi)
     uctx = lambda: update_impl_scope(ui)
     ks = _potrf_phase_kernels(p, q, mtl, ntl, nt, nb, cplx)
 
@@ -489,8 +489,8 @@ def potrf_steps(at, mesh, p, q, nt, la, bi, pi, ui):
     coords = _coords(p, q)
     d = min(max(0, int(la)), 1)  # factor-loop pipelining caps at depth 1
     if rec is not None:
-        rec.note_run(op="potrf", nt=int(nt), depth=d, impl=bi, panel=pi,
-                     update=ui, grid=(p, q), phases=PHASES)
+        rec.note_run(op="potrf", nt=int(nt), depth=d, impl=bi, update=ui,
+                     grid=(p, q), phases=PHASES)
     t = at
     if d == 0:
         for k in range(nt):
@@ -556,19 +556,19 @@ def _lu_phase_kernels(p, q, mtl, ntl, nt, nb):
             "info": info_k}
 
 
-def lu_steps(at, mesh, p, q, nt, la, bi, pi, ui):
+def lu_steps(at, mesh, p, q, nt, la, bi, ui):
     """Per-step no-pivot mesh LU: the _lu_jit phases (_nopiv_* helpers),
     unbucketed, fenced per phase."""
     import jax.numpy as jnp
 
-    from ..ops.pallas_ops import panel_impl_scope, update_impl_scope
+    from ..ops.pallas_ops import update_impl_scope
     from ..parallel.comm import bcast_impl_scope
 
     rec = active_recorder()
     spec, rep = _specs()
     mtl, ntl = at.shape[0] // p, at.shape[1] // q
     nb = at.shape[2]
-    ctx = lambda: _scopes(bcast_impl_scope(bi), panel_impl_scope(pi))
+    ctx = lambda: bcast_impl_scope(bi)
     uctx = lambda: update_impl_scope(ui)
     ks = _lu_phase_kernels(p, q, mtl, ntl, nt, nb)
 
@@ -595,7 +595,7 @@ def lu_steps(at, mesh, p, q, nt, la, bi, pi, ui):
     d = min(max(0, int(la)), 1)
     if rec is not None:
         rec.note_run(op="getrf_nopiv", nt=int(nt), depth=d, impl=bi,
-                     panel=pi, update=ui, grid=(p, q), phases=PHASES)
+                     update=ui, grid=(p, q), phases=PHASES)
     t = at
     if d == 0:
         for k in range(nt):
@@ -759,7 +759,7 @@ def _qr_phase_kernels(p, q, m_true):
             "fin": fin_k}
 
 
-def geqrf_steps(at, mesh, p, q, nt, m_true, n_true, bi, pi):
+def geqrf_steps(at, mesh, p, q, nt, m_true, n_true, bi):
     """Per-step distributed CAQR (the _geqrf_jit strict schedule over
     dist_qr's module-level phase helpers), fenced per phase: panel = the
     local offset-pivot QR + compact-WY T, bcast = the three rooted
@@ -771,7 +771,6 @@ def geqrf_steps(at, mesh, p, q, nt, m_true, n_true, bi, pi):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ..ops.pallas_ops import panel_impl_scope
     from ..parallel.comm import bcast_impl_scope
     from ..parallel.mesh import ROW_AXIS
 
@@ -783,8 +782,7 @@ def geqrf_steps(at, mesh, p, q, nt, m_true, n_true, bi, pi):
     ks = _qr_phase_kernels(p, q, m_true)
 
     panel = _Phase("geqrf", "panel",
-                   _sm(ks["panel"], mesh, (spec, rep), (spec, spec, spec)),
-                   trace_ctx=lambda: panel_impl_scope(pi))
+                   _sm(ks["panel"], mesh, (spec, rep), (spec, spec, spec)))
     bcast = _Phase("geqrf", "bcast",
                    _sm(ks["bcast"], mesh, (spec, spec, spec, rep),
                        (spec, spec, spec)),
@@ -800,7 +798,7 @@ def geqrf_steps(at, mesh, p, q, nt, m_true, n_true, bi, pi):
 
     coords = _coords(p, q)
     if rec is not None:
-        rec.note_run(op="geqrf", nt=int(nt), depth=0, impl=bi, panel=pi,
+        rec.note_run(op="geqrf", nt=int(nt), depth=0, impl=bi,
                      grid=(p, q), phases=PHASES)
     dtype = at.dtype
     t = at
@@ -901,14 +899,14 @@ def he2hb_steps(at, mesh, p, q, n_true, nb, nsteps, bi):
 
 def step_traceable(op: str, mesh, p: int, q: int, nt: int, mtl: int,
                    ntl: int, nb: int, cplx: bool = False,
-                   bi: str = "auto", pi: str = "xla", ui: str = "xla"):
+                   bi: str = "auto", ui: str = "xla"):
     """One full flight k-step as a single traceable function over the
     global tile stacks — the slate_lint registry surface for the
     step-dispatch phase programs.  ``k`` is a runtime argument, so the
     rooted broadcasts trace the engine's lax.switch dispatch exactly as
     the per-step jits do.  Returns the composed fn (summa: (at, bt, k);
     potrf/getrf_nopiv: (at, k))."""
-    from ..ops.pallas_ops import panel_impl_scope, update_impl_scope
+    from ..ops.pallas_ops import update_impl_scope
     from ..parallel.comm import bcast_impl_scope
 
     spec, rep = _specs()
@@ -943,7 +941,7 @@ def step_traceable(op: str, mesh, p: int, q: int, nt: int, mtl: int,
                      (spec, prow, rep, rep))
 
         def fn(at, tls, tvs, tts, k):
-            with _scopes(bcast_impl_scope(bi), panel_impl_scope(pi)):
+            with bcast_impl_scope(bi):
                 po = panel(at, k)
                 pl = bcast(po[0], po[1], po[2], k)
                 return update(at, tls, tvs, tts, pl[0], pl[1], pl[2], k)
@@ -989,8 +987,7 @@ def step_traceable(op: str, mesh, p: int, q: int, nt: int, mtl: int,
     info = _sm(ks["info"], mesh, (spec,), spec)
 
     def fn(at, k):
-        with _scopes(bcast_impl_scope(bi), panel_impl_scope(pi),
-                     update_impl_scope(ui)):
+        with _scopes(bcast_impl_scope(bi), update_impl_scope(ui)):
             if op == "potrf":
                 t, po = panel(at, k)
                 pl = bcast(po, k)

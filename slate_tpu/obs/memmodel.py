@@ -116,23 +116,20 @@ def _itemsize(dtype) -> int:
 
 class MemoryModel:
     """Closed-form per-device peak HBM bytes of one mesh kernel at
-    (n, nb, mesh grid, dtype, lookahead depth, BcastImpl, FT, PanelImpl).
+    (n, nb, mesh grid, dtype, lookahead depth, BcastImpl, FT).
 
     ``peak_bytes = arg_bytes + out_bytes + workspace_bytes`` — the same
     decomposition ``compile().memory_analysis()`` reports (arguments +
     outputs + temps), so model-vs-measured comparison is term-by-term.
     ``ft=True`` grows the tile grid by the Huang-Abraham checksum
     augmentation (two weighted checksum tile rows/cols + lcm re-pad —
-    ft/abft._encode_* geometry).  ``panel_impl`` is accepted for API
-    completeness: the fused Pallas panels trade dispatch count, not
-    resident bytes (scratch lives in VMEM, not HBM), so it does not move
-    the model.
+    ft/abft._encode_* geometry).
     """
 
     def __init__(self, op: str, n: int, nb: int, grid: Tuple[int, int],
                  dtype="float32", lookahead: int = 1,
                  bcast_impl: str = "auto", ft: bool = False,
-                 panel_impl: str = "xla", k: Optional[int] = None):
+                 k: Optional[int] = None):
         if op not in MODEL_OPS:
             raise ValueError(f"unknown model op {op!r}; expected {MODEL_OPS}")
         self.op = op
@@ -143,7 +140,6 @@ class MemoryModel:
         self.isz = _itemsize(dtype)
         self.ft = bool(ft)
         self.bcast_impl = bcast_impl
-        self.panel_impl = panel_impl
 
         lcm = math.lcm(self.p, self.q)
         base = max(1, -(-self.n // self.nb))

@@ -12,7 +12,7 @@ estimators can run distributed over the already-factored tiles.
 Three surfaces live here:
 
 - ``Option.NumMonitor`` resolution (``resolve_num_monitor`` /
-  ``use_num_monitor`` / ``SLATE_TPU_NUM``; the PanelImpl pattern:
+  ``use_num_monitor`` / ``SLATE_TPU_NUM``; the BcastImpl pattern:
   explicit > context > env > auto, auto = on iff the obs layer is
   enabled).  ``off`` keeps every threaded kernel jaxpr-IDENTICAL;
   ``on`` adds carry-resident gauges with ZERO extra audited collectives

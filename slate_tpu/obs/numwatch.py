@@ -219,11 +219,6 @@ def _run_mixed(n, nb, mesh, impl) -> Dict[str, float]:
         # finite and far below its first (a stall would flatten this)
         vals["num.ir_history_drop_well"] = (
             hist[0][0] / max(hist[-1][0], 1e-300))
-    # the ABFT online-discrepancy gauge (ft.online_disc) is the same
-    # accuracy-health family; fold it in when an ft run preceded us
-    for gauge in REGISTRY.snapshot().get("gauges", []):
-        if gauge["name"] == "ft.online_disc":
-            vals["num.ft_online_disc"] = float(gauge["value"])
     return vals
 
 

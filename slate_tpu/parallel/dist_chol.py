@@ -44,11 +44,7 @@ from jax.sharding import PartitionSpec as P
 from ..obs import instrument
 from ..obs.numerics import resolve_num_monitor
 from ..ops.pallas_ops import (
-    chol_panel_tiles_pallas,
     chol_trailing_update_pallas,
-    panel_engaged,
-    panel_impl_scope,
-    resolve_panel_impl,
     resolve_update_impl,
     update_engaged,
     update_impl_scope,
@@ -77,8 +73,8 @@ from typing import Optional
 @instrument("potrf_dist")
 def potrf_dist(
     a: DistMatrix, lookahead: Optional[int] = None,
-    bcast_impl: Optional[str] = None, panel_impl: Optional[str] = None,
-    num_monitor: Optional[str] = None, update_impl: Optional[str] = None,
+    bcast_impl: Optional[str] = None, num_monitor: Optional[str] = None,
+    update_impl: Optional[str] = None,
 ) -> Tuple[DistMatrix, jax.Array]:
     """Factor A = L L^H (lower). ``a`` holds the lower triangle (upper tile
     content ignored). Returns (L as DistMatrix, info).
@@ -89,10 +85,7 @@ def potrf_dist(
     (potrf.cc:129-133's lookahead queues).  Results are bitwise-identical
     at any depth.  ``bcast_impl`` (Option.BcastImpl) picks the panel /
     diag-tile broadcast lowering — masked psum or the ppermute engine —
-    also bitwise-identical.  ``panel_impl`` (Option.PanelImpl) picks the
-    panel-phase lowering: ``xla`` (today's cholesky + batched-trsm chain,
-    bitwise) or ``pallas`` (one fused on-chip kernel per panel; matches
-    to the documented explicit-inverse tolerance class).  ``num_monitor``
+    also bitwise-identical.  ``num_monitor``
     (Option.NumMonitor) threads the in-carry numerics gauges: ``on``
     accumulates the Schur-diagonal near-breakdown margin in the loop
     carry (each pivot tile's diagonal sampled right before its own panel
@@ -102,8 +95,8 @@ def potrf_dist(
     step-dispatch path) is jaxpr-identical and records nothing.
     ``update_impl`` (Option.UpdateImpl) picks the trailing-herk lowering:
     ``xla`` (today's masked einsum bulk, jaxpr-identical) or ``pallas``
-    (one fused grid dispatch per k-step, bitwise vs xla under interpret
-    mode; comm bytes invariant by construction)."""
+    (one fused grid dispatch per k-step; comm bytes invariant by
+    construction)."""
     p, q = mesh_shape(a.mesh)
     if a.mt != a.nt:
         raise ValueError("potrf_dist needs a square tile grid")
@@ -118,20 +111,20 @@ def potrf_dist(
         # fused kernels' surface)
         lt, info = _flight.potrf_steps(
             a.tiles, a.mesh, p, q, a.nt, la_depth(lookahead, a.nt),
-            resolve_bcast_impl(bcast_impl), resolve_panel_impl(panel_impl),
+            resolve_bcast_impl(bcast_impl),
             resolve_update_impl(update_impl),
         )
     elif nm:
         lt, info, gz = _potrf_jit(
             a.tiles, a.mesh, p, q, a.nt, la_depth(lookahead, a.nt),
-            resolve_bcast_impl(bcast_impl), resolve_panel_impl(panel_impl),
+            resolve_bcast_impl(bcast_impl),
             resolve_update_impl(update_impl), True, a.n,
         )
         _num.record_chol_gauges("potrf", gz[0], gz[1], gz[2])
     else:
         lt, info = _potrf_jit(
             a.tiles, a.mesh, p, q, a.nt, la_depth(lookahead, a.nt),
-            resolve_bcast_impl(bcast_impl), resolve_panel_impl(panel_impl),
+            resolve_bcast_impl(bcast_impl),
             resolve_update_impl(update_impl), False, 0,
         )
     return DistMatrix(
@@ -140,19 +133,9 @@ def potrf_dist(
 
 
 def _chol_panel_factor_solve(dtile, pcol, cplx):
-    """Diag-tile factor + panel-column tile solves, dispatched by the
-    active Option.PanelImpl scope.  XLA branch: today's ops, bitwise
-    (cholesky, f32 for bf16, then one batched trsm).  Pallas branch: one
-    fused kernel — column-loop factor with the inverse in VMEM scratch,
-    tile solves as MXU matmuls (documented-tolerance parity)."""
+    """Diag-tile factor + panel-column tile solves: cholesky (in f32 for
+    bf16), then one batched trsm."""
     dtype = dtile.dtype
-    if panel_engaged(dtype, dtile.size * dtile.dtype.itemsize):
-        if dtype == jnp.bfloat16:  # no bf16 sqrt/div path worth keeping
-            lkk32, solved32 = chol_panel_tiles_pallas(
-                dtile.astype(jnp.float32), pcol.astype(jnp.float32)
-            )
-            return lkk32.astype(dtype), solved32.astype(dtype)
-        return chol_panel_tiles_pallas(dtile, pcol)
     if dtype == jnp.bfloat16:
         lkk = lax.linalg.cholesky(dtile.astype(jnp.float32)).astype(dtype)
     else:
@@ -169,8 +152,7 @@ def _chol_panel_compute(view, k, p, q, i_log, c, cplx, roff=0, coff=0):
     """Compute half of the right-looking step-k panel phase: diag-tile
     broadcast + factor + panel-column tile solves + write-back.  Reads
     only column slot k // q - coff (refreshed by ``_chol_narrow`` when
-    the update is deferred).  The factor + solve pair dispatches by
-    Option.PanelImpl (_chol_panel_factor_solve).  Returns (view,
+    the update is deferred).  Returns (view,
     pan_own): the owner-masked solved panel column (zeros off the owning
     mesh column), ready for the broadcast half."""
     nb = view.shape[2]
@@ -267,8 +249,8 @@ def _chol_bulk(view, payload, lower, cplx, excl_kc=None):
     return view - jnp.where(mask, upd, 0)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9, 10))
-def _potrf_jit(at, mesh, p, q, nt, la, bi, pi, ui, nm=False, n_true=0):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9))
+def _potrf_jit(at, mesh, p, q, nt, la, bi, ui, nm=False, n_true=0):
     spec = P(ROW_AXIS, COL_AXIS)
 
     def kernel(t_loc):
@@ -393,7 +375,7 @@ def _potrf_jit(at, mesh, p, q, nt, la, bi, pi, ui, nm=False, n_true=0):
     out_specs = (spec, P(ROW_AXIS, COL_AXIS))
     if nm:
         out_specs = out_specs + (P(ROW_AXIS, COL_AXIS),)
-    with bcast_impl_scope(bi), panel_impl_scope(pi), update_impl_scope(ui):
+    with bcast_impl_scope(bi), update_impl_scope(ui):
         out = shard_map_compat(
             kernel,
             mesh=mesh,

@@ -44,12 +44,7 @@ from ..linalg.lu import _getrf_nopiv_rec, _tournament_reduce
 from ..obs import instrument
 from ..obs.numerics import resolve_num_monitor
 from ..ops.pallas_ops import (
-    lu_panel_tiles_pallas,
-    lu_rowsolve_tiles_pallas,
     lu_trailing_update_pallas,
-    panel_engaged,
-    panel_impl_scope,
-    resolve_panel_impl,
     resolve_update_impl,
     update_engaged,
     update_impl_scope,
@@ -81,8 +76,8 @@ from typing import Optional
 @instrument("getrf_nopiv_dist")
 def getrf_nopiv_dist(
     a: DistMatrix, lookahead: Optional[int] = None,
-    bcast_impl: Optional[str] = None, panel_impl: Optional[str] = None,
-    update_impl: Optional[str] = None, num_monitor: Optional[str] = None,
+    bcast_impl: Optional[str] = None, update_impl: Optional[str] = None,
+    num_monitor: Optional[str] = None,
 ) -> Tuple[DistMatrix, jax.Array]:
     """Factor A = L U in place (packed LU tiles). Returns (LU, info).
 
@@ -91,14 +86,11 @@ def getrf_nopiv_dist(
     broadcasts overlap it (getrf_nopiv.cc's lookahead queues); results
     are bitwise-identical at any depth.  ``bcast_impl``
     (Option.BcastImpl) picks the panel-broadcast lowering, also
-    bitwise-identical.  ``panel_impl`` (Option.PanelImpl) picks the
-    panel-phase lowering: ``xla`` (today's recursive diag factor +
-    batched trsm pair, bitwise) or ``pallas`` (fused on-chip panel
-    kernels; documented-tolerance parity).  ``update_impl``
-    (Option.UpdateImpl) picks the trailing-gemm lowering the same way:
-    ``xla`` (today's bulk einsum, jaxpr-identical) or ``pallas``
+    bitwise-identical.  ``update_impl`` (Option.UpdateImpl) picks the
+    trailing-gemm lowering: ``xla`` (today's bulk einsum,
+    jaxpr-identical) or ``pallas``
     (:func:`~..ops.pallas_ops.lu_trailing_update_pallas`, one fused grid
-    dispatch per k-step, bitwise in interpret mode).  ``num_monitor``
+    dispatch per k-step).  ``num_monitor``
     (Option.NumMonitor) threads the in-carry element-growth gauge —
     running max|working array|/max|A|, THE no-pivot breakdown monitor —
     sampled at panel entry of every step (strict-schedule intermediates
@@ -117,20 +109,20 @@ def getrf_nopiv_dist(
         # (per-phase programs carry no gauges)
         lut, info = _flight.lu_steps(
             a.tiles, a.mesh, p, q, a.nt, la_depth(lookahead, a.nt),
-            resolve_bcast_impl(bcast_impl), resolve_panel_impl(panel_impl),
+            resolve_bcast_impl(bcast_impl),
             resolve_update_impl(update_impl),
         )
     elif nm:
         lut, info, gz = _lu_jit(
             a.tiles, a.mesh, p, q, a.nt, la_depth(lookahead, a.nt),
-            resolve_bcast_impl(bcast_impl), resolve_panel_impl(panel_impl),
+            resolve_bcast_impl(bcast_impl),
             resolve_update_impl(update_impl), True, a.m,
         )
         _num.record_lu_growth("getrf_nopiv", gz[0], gz[1])
     else:
         lut, info = _lu_jit(
             a.tiles, a.mesh, p, q, a.nt, la_depth(lookahead, a.nt),
-            resolve_bcast_impl(bcast_impl), resolve_panel_impl(panel_impl),
+            resolve_bcast_impl(bcast_impl),
             resolve_update_impl(update_impl), False, 0,
         )
     return DistMatrix(
@@ -138,21 +130,9 @@ def getrf_nopiv_dist(
     ), info
 
 
-def _lu_cast(x):
-    """bf16 panels factor in f32 (no bf16 reciprocal path worth keeping);
-    every other engaged dtype runs natively."""
-    return x.astype(jnp.float32) if x.dtype == jnp.bfloat16 else x
-
-
 def _lu_panel_factor_solve(dtile, pcol):
-    """Diag-tile no-pivot LU + panel-column tile solves, dispatched by
-    the active Option.PanelImpl scope.  XLA branch: today's ops, bitwise
-    (recursive tile LU + one batched trsm).  Pallas branch: one fused
-    kernel — the packed L\\U column loop with U^-1 in VMEM scratch, tile
-    solves as MXU matmuls (documented-tolerance parity)."""
-    if panel_engaged(dtile.dtype, dtile.size * dtile.dtype.itemsize):
-        luk, solved = lu_panel_tiles_pallas(_lu_cast(dtile), _lu_cast(pcol))
-        return luk.astype(dtile.dtype), solved.astype(pcol.dtype)
+    """Diag-tile no-pivot LU + panel-column tile solves: the recursive
+    tile LU and one batched trsm."""
     luk = _getrf_nopiv_rec(dtile)  # packed L\U, unit L diag implicit
     solved = lax.linalg.triangular_solve(
         jnp.broadcast_to(jnp.triu(luk), pcol.shape), pcol,
@@ -162,12 +142,7 @@ def _lu_panel_factor_solve(dtile, pcol):
 
 
 def _lu_panel_rowsolve(luk, prow, eye):
-    """Panel-row solve L_kk^{-1} A[k, j], dispatched like the column
-    half (fused unit-L^-1 kernel under pallas)."""
-    if panel_engaged(luk.dtype, luk.size * luk.dtype.itemsize):
-        return lu_rowsolve_tiles_pallas(_lu_cast(luk), _lu_cast(prow)).astype(
-            prow.dtype
-        )
+    """Panel-row solve L_kk^{-1} A[k, j]: one batched unit-lower trsm."""
     return lax.linalg.triangular_solve(
         jnp.broadcast_to(jnp.tril(luk, -1) + eye, prow.shape), prow,
         left_side=True, lower=True, transpose_a=False,
@@ -198,8 +173,7 @@ def _nopiv_panel_compute(t_loc, k, p, q, i_log, j_log, r, c, roff=0,
         newcol = pcol
     else:
         dtile = bcast_diag_tile(t_loc, k, p, q, nb, roff, coff)
-        # panel column: L[i,k] = A[i,k] U_kk^{-1}  (i > k); factor + solve
-        # dispatch by Option.PanelImpl (_lu_panel_factor_solve)
+        # panel column: L[i,k] = A[i,k] U_kk^{-1}  (i > k)
         pcol = lax.dynamic_slice_in_dim(t_loc, kc, 1, axis=1)[:, 0]
         luk, lsolved = _lu_panel_factor_solve(dtile, pcol)
         on_d = (i_log == k)[:, None, None]
@@ -360,8 +334,8 @@ def _lu_growth_out(amax0, g, gfinal):
     return jnp.stack([allr(amax0), allr(g)])[None, None]
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9, 10))
-def _lu_jit(at, mesh, p, q, nt, la, bi, pi, ui, nm=False, m_true=0):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9))
+def _lu_jit(at, mesh, p, q, nt, la, bi, ui, nm=False, m_true=0):
     spec = P(ROW_AXIS, COL_AXIS)
 
     def kernel(t_loc):
@@ -435,7 +409,7 @@ def _lu_jit(at, mesh, p, q, nt, la, bi, pi, ui, nm=False, m_true=0):
     out_specs = (spec, P(ROW_AXIS, COL_AXIS))
     if nm:
         out_specs = out_specs + (P(ROW_AXIS, COL_AXIS),)
-    with bcast_impl_scope(bi), panel_impl_scope(pi), update_impl_scope(ui):
+    with bcast_impl_scope(bi), update_impl_scope(ui):
         out = shard_map_compat(
             kernel,
             mesh=mesh,
@@ -458,8 +432,7 @@ def _lu_jit(at, mesh, p, q, nt, la, bi, pi, ui, nm=False, m_true=0):
 @instrument("getrf_tntpiv_dist")
 def getrf_tntpiv_dist(
     a: DistMatrix, lookahead: Optional[int] = None,
-    bcast_impl: Optional[str] = None, panel_impl: Optional[str] = None,
-    num_monitor: Optional[str] = None,
+    bcast_impl: Optional[str] = None, num_monitor: Optional[str] = None,
 ) -> Tuple[DistMatrix, jax.Array, jax.Array]:
     """Factor P A = L U with tournament pivoting across the mesh.
 
@@ -473,12 +446,7 @@ def getrf_tntpiv_dist(
     column) overlap it — the CALU form of the reference's lookahead.  The
     deferred update must land before the cross-shard row swaps (they move
     full rows), so the overlap window is the tournament, not the whole
-    panel.  Results are bitwise-identical at any depth.  ``panel_impl``
-    (Option.PanelImpl) picks the POST-pivot panel lowering — the diag
-    factor + tile solves that run after the tournament has swapped the
-    winners in (``pallas`` routes them through the fused
-    ``lu_panel_tiles_pallas`` pair; the pivot search itself stays XLA:
-    argmax/tournament collectives have no MXU body).  ``num_monitor``
+    panel.  Results are bitwise-identical at any depth.  ``num_monitor``
     (Option.NumMonitor): ``on`` carries the element-growth gauge through
     the k-loop (the tournament's pivot quality monitor — growth far
     above the partial-pivot bound flags a lost tournament); ``off`` is
@@ -494,14 +462,14 @@ def getrf_tntpiv_dist(
     if nm:
         lut, perm, info, gz = _tntpiv_jit(
             a.tiles, a.mesh, p, q, a.nt, a.m, la_depth(lookahead, a.nt),
-            resolve_bcast_impl(bcast_impl), resolve_panel_impl(panel_impl),
+            resolve_bcast_impl(bcast_impl),
             True,
         )
         _num.record_lu_growth("getrf_tntpiv", gz[0], gz[1])
     else:
         lut, perm, info = _tntpiv_jit(
             a.tiles, a.mesh, p, q, a.nt, a.m, la_depth(lookahead, a.nt),
-            resolve_bcast_impl(bcast_impl), resolve_panel_impl(panel_impl),
+            resolve_bcast_impl(bcast_impl),
             False,
         )
     return (
@@ -511,8 +479,8 @@ def getrf_tntpiv_dist(
     )
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9))
-def _tntpiv_jit(at, mesh, p, q, nt, m_true, la, bi, pi, nm=False):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8))
+def _tntpiv_jit(at, mesh, p, q, nt, m_true, la, bi, nm=False):
     spec = P(ROW_AXIS, COL_AXIS)
 
     def kernel(t_loc):
@@ -681,12 +649,10 @@ def _tntpiv_jit(at, mesh, p, q, nt, m_true, la, bi, pi, nm=False):
     out_specs = (spec, P(ROW_AXIS), P(ROW_AXIS, COL_AXIS))
     if nm:
         out_specs = out_specs + (P(ROW_AXIS, COL_AXIS),)
-    # the POST-pivot panel (diag factor + tile solves after the swaps)
-    # dispatches by PanelImpl like the nopiv kernel; the pivot search
-    # stays XLA by construction (no dispatch site).  The trailing gemm
-    # stays pinned xla: Option.UpdateImpl scopes summa/potrf/LU-nopiv
-    # only, and the pin keeps this jit's cache UpdateImpl-independent
-    with bcast_impl_scope(bi), panel_impl_scope(pi), update_impl_scope("xla"):
+    # the trailing gemm stays pinned xla: Option.UpdateImpl scopes
+    # summa/potrf/LU-nopiv only, and the pin keeps this jit's cache
+    # UpdateImpl-independent
+    with bcast_impl_scope(bi), update_impl_scope("xla"):
         out = shard_map_compat(
             kernel,
             mesh=mesh,
@@ -713,8 +679,7 @@ def _tntpiv_jit(at, mesh, p, q, nt, m_true, la, bi, pi, nm=False):
 @instrument("getrf_pp_dist")
 def getrf_pp_dist(
     a: DistMatrix, lookahead: Optional[int] = None,
-    bcast_impl: Optional[str] = None, panel_impl: Optional[str] = None,
-    num_monitor: Optional[str] = None,
+    bcast_impl: Optional[str] = None, num_monitor: Optional[str] = None,
 ) -> Tuple[DistMatrix, jax.Array, jax.Array]:
     """Factor P A = L U with classic partial (per-column argmax) pivoting.
 
@@ -735,10 +700,7 @@ def getrf_pp_dist(
     contract as getrf_tntpiv_dist.  ``lookahead`` >= 1 overlaps the
     pivoted panel factor's collectives with the previous step's deferred
     trailing gemm (bitwise-identical reorder; see getrf_tntpiv_dist).
-    ``panel_impl`` (Option.PanelImpl) picks the post-pivot panel-ROW
-    solve lowering (``pallas`` = ``lu_rowsolve_tiles_pallas``); the
-    panel-column factor is fused with the per-column pivot search and
-    stays XLA.  ``num_monitor`` (Option.NumMonitor): ``on`` carries the
+    ``num_monitor`` (Option.NumMonitor): ``on`` carries the
     element-growth gauge (max 2^{n-1} under partial pivoting — the
     Wilkinson bound — so a tripped gauge is a certified pathological
     input); ``off`` is jaxpr-identical.
@@ -753,14 +715,14 @@ def getrf_pp_dist(
     if nm:
         lut, perm, info, gz = _pp_jit(
             a.tiles, a.mesh, p, q, a.nt, a.m, la_depth(lookahead, a.nt),
-            resolve_bcast_impl(bcast_impl), resolve_panel_impl(panel_impl),
+            resolve_bcast_impl(bcast_impl),
             True,
         )
         _num.record_lu_growth("getrf_pp", gz[0], gz[1])
     else:
         lut, perm, info = _pp_jit(
             a.tiles, a.mesh, p, q, a.nt, a.m, la_depth(lookahead, a.nt),
-            resolve_bcast_impl(bcast_impl), resolve_panel_impl(panel_impl),
+            resolve_bcast_impl(bcast_impl),
             False,
         )
     return (
@@ -1021,8 +983,8 @@ def _pp_panel_and_swaps(t_loc, rowperm, k, p, q, r, c, nt, m_true,
         )
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9))
-def _pp_jit(at, mesh, p, q, nt, m_true, la, bi, pi, nm=False):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8))
+def _pp_jit(at, mesh, p, q, nt, m_true, la, bi, nm=False):
     spec = P(ROW_AXIS, COL_AXIS)
 
     def kernel(t_loc):
@@ -1121,10 +1083,10 @@ def _pp_jit(at, mesh, p, q, nt, m_true, la, bi, pi, nm=False):
     out_specs = (spec, P(ROW_AXIS), P(ROW_AXIS, COL_AXIS))
     if nm:
         out_specs = out_specs + (P(ROW_AXIS, COL_AXIS),)
-    # post-pivot row solve dispatches by PanelImpl; update pinned xla —
-    # see _tntpiv_jit.  The program's ops sit under the ``getrf`` stage
-    # scope, each step's under its phase (panel, swap, bcast, bulk)
-    with bcast_impl_scope(bi), panel_impl_scope(pi), update_impl_scope("xla"), \
+    # update pinned xla — see _tntpiv_jit.  The program's ops sit under
+    # the ``getrf`` stage scope, each step's under its phase (panel,
+    # swap, bcast, bulk)
+    with bcast_impl_scope(bi), update_impl_scope("xla"), \
             jax.named_scope("getrf"):
         out = shard_map_compat(
             kernel,
@@ -1267,7 +1229,7 @@ def _gb_pp_jit(at, mesh, p, q, nt, m_true, wd_l, wd_u, wd_usw, bi):
     # band kernel keeps the XLA forms end to end: its windowed solves and
     # trailing einsum are inline (no dispatch sites), and the pins keep
     # the trace independent of any ambient impl chain
-    with bcast_impl_scope(bi), panel_impl_scope("xla"), update_impl_scope("xla"):
+    with bcast_impl_scope(bi), update_impl_scope("xla"):
         lut, perm, info = shard_map_compat(
             kernel,
             mesh=mesh,

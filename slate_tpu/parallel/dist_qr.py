@@ -39,12 +39,6 @@ from jax.sharding import PartitionSpec as P
 from ..linalg.qr import _larft, _larft_v, _panel_qr, _panel_qr_offset, _v_of
 from ..obs import instrument
 from ..obs.numerics import resolve_num_monitor
-from ..ops.pallas_ops import (
-    panel_engaged,
-    panel_impl_scope,
-    qr_panel_offset_pallas,
-    resolve_panel_impl,
-)
 from ..types import Op
 from .dist import DistMatrix
 from .mesh import COL_AXIS, ROW_AXIS, mesh_shape
@@ -93,17 +87,12 @@ def _merge_ids(p: int) -> List[List[int]]:
 
 
 @instrument("geqrf_dist")
-def geqrf_dist(a: DistMatrix, bcast_impl=None, panel_impl=None,
-               num_monitor=None) -> DistQR:
+def geqrf_dist(a: DistMatrix, bcast_impl=None, num_monitor=None) -> DistQR:
     """Factor A = Q R across the mesh (m >= n).  ``bcast_impl``
     (Option.BcastImpl) picks the panel-broadcast lowering — the rooted
     ppermute engine or the legacy masked psum — bitwise-identical
     (PR 5's engine, threaded here per the ROADMAP "finish the collective
-    story" item).  ``panel_impl`` (Option.PanelImpl) picks the offset
-    panel-QR lowering: ``xla`` (today's ``_panel_qr_offset`` +
-    ``_larft_v`` pair) or ``pallas`` (the fused
-    ``qr_panel_offset_pallas`` dispatch); the tree merge stays XLA (tiny
-    replicated (2nb, nb) QRs, no MXU body).
+    story" item).
 
     ``num_monitor`` (Option.NumMonitor, ISSUE 15): ``on`` carries the
     per-panel reflector/τ orthogonality-loss proxy (``_qr_orth_loss``)
@@ -119,25 +108,24 @@ def geqrf_dist(a: DistMatrix, bcast_impl=None, panel_impl=None,
     if a.m < a.n:
         raise ValueError(f"geqrf_dist requires m >= n, got {a.m}x{a.n}")
     bi = resolve_bcast_impl(bcast_impl)
-    pi = resolve_panel_impl(panel_impl)
     nm = resolve_num_monitor(num_monitor) == "on"
     if _flight.step_dispatch_active():
         # flight-recorder step dispatch: same arithmetic, fenced per
         # phase (the per-phase programs carry no gauges — monitoring is
         # the fused kernels' surface, the potrf/LU contract)
         fact, tloc, tvs, tts = _flight.geqrf_steps(
-            a.tiles, a.mesh, p, q, a.nt, a.m, a.n, bi, pi)
+            a.tiles, a.mesh, p, q, a.nt, a.m, a.n, bi)
         fd = DistMatrix(
             tiles=fact, m=a.m, n=a.n, nb=a.nb, mesh=a.mesh, diag_pad=True
         )
         return DistQR(fd, tloc, tvs, tts)
     if nm:
         fact, tloc, treev, treet, g = _geqrf_jit(
-            a.tiles, a.mesh, p, q, a.nt, a.m, a.n, bi, pi, True)
+            a.tiles, a.mesh, p, q, a.nt, a.m, a.n, bi, True)
         _num.record_qr_orth("geqrf", jnp.max(g))
     else:
         fact, tloc, treev, treet = _geqrf_jit(
-            a.tiles, a.mesh, p, q, a.nt, a.m, a.n, bi, pi, False)
+            a.tiles, a.mesh, p, q, a.nt, a.m, a.n, bi, False)
     fd = DistMatrix(
         tiles=fact, m=a.m, n=a.n, nb=a.nb, mesh=a.mesh, diag_pad=True
     )
@@ -233,14 +221,8 @@ def _qr_panel_factor(k, t_loc, p, q, m_true):
     flat = pcol.reshape(mfl, nb)
     valid = (flat_gids >= k * nb) & (flat_gids < m_true)
     masked = jnp.where((valid & mine_c)[:, None], flat, 0)
-    # offset-panel dispatch by Option.PanelImpl: the xla pair is today's
-    # ops (bitwise); the pallas branch runs the SAME pair fused in one
-    # dispatch (bitwise in interpret mode — the kernel body IS the pair)
-    if panel_engaged(masked.dtype, masked.size * masked.dtype.itemsize):
-        r_a, v, _tau, tl = qr_panel_offset_pallas(masked, row0)
-    else:
-        r_a, v, tau = _panel_qr_offset(masked, row0)
-        tl = _larft_v(v, tau)
+    r_a, v, tau = _panel_qr_offset(masked, row0)
+    tl = _larft_v(v, tau)
     return (jnp.where(mine_c, r_a, 0), jnp.where(mine_c, v, 0),
             jnp.where(mine_c, tl, 0))
 
@@ -380,8 +362,8 @@ def _qr_pad_identity(t_loc, p, q, n_true, dtype):
     return jnp.where(dmask, jnp.ones((), dtype), t_loc)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9))
-def _geqrf_jit(at, mesh, p, q, nt, m_true, n_true, bi, pi, nm):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8))
+def _geqrf_jit(at, mesh, p, q, nt, m_true, n_true, bi, nm):
     spec = P(ROW_AXIS, COL_AXIS)
     nmerge = max(1, p)
 
@@ -430,7 +412,7 @@ def _geqrf_jit(at, mesh, p, q, nt, m_true, n_true, bi, pi, nm):
                  P(ROW_AXIS, COL_AXIS))
     if nm:
         out_specs = out_specs + (P(ROW_AXIS, COL_AXIS),)
-    with bcast_impl_scope(bi), panel_impl_scope(pi):
+    with bcast_impl_scope(bi):
         return shard_map_compat(
             kernel,
             mesh=mesh,

@@ -35,9 +35,8 @@ Routing (``Option.MixedPrecision``, resolve chain explicit >
 IR -> GMRES-IR -> full-f64 fallback for real f64 inputs.  Convergence is
 the reference's gate (refine.py): ||r|| <= ||x|| * ||A|| * eps * sqrt(n).
 Every tier threads ``opts`` end-to-end, so the f32 factor gets ring
-broadcasts (Option.BcastImpl), lookahead pipelining, fused Pallas panels
-(Option.PanelImpl) and ABFT (Option.FaultTolerance) exactly like a direct
-factor call.
+broadcasts (Option.BcastImpl), lookahead pipelining and ABFT
+(Option.FaultTolerance) exactly like a direct factor call.
 """
 
 from __future__ import annotations
@@ -363,8 +362,8 @@ def _ir_gesv_jit(at, bt, lut, perm, info, mesh, p, q, m, nrhs, nb,
 
 def _factor_f32(kind: str, a: jax.Array, mesh: Mesh, nb: int, opts):
     """The f32 mesh factor with ``opts`` threaded end-to-end: the factor
-    drivers consume Option.Lookahead, Option.BcastImpl, Option.PanelImpl
-    and Option.FaultTolerance exactly as a direct f32 call would (the
+    drivers consume Option.Lookahead, Option.BcastImpl and
+    Option.FaultTolerance exactly as a direct f32 call would (the
     whole point of the rebuild — the old facade factored bare)."""
     from .drivers import getrf_mesh, potrf_mesh
 

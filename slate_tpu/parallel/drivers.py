@@ -53,15 +53,6 @@ def _bi(opts: Optional[Options]):
     return get_option(opts, Option.BcastImpl)
 
 
-def _pi(opts: Optional[Options]):
-    """Raw Option.PanelImpl value from a driver ``opts`` mapping — the
-    panel-factorization lowering the factor kernels consume (fused
-    Pallas panel kernels vs the XLA reference chains).  May be None:
-    ``ops.pallas_ops.resolve_panel_impl`` inside each kernel is the
-    single authority for the context/env/auto default chain."""
-    return get_option(opts, Option.PanelImpl)
-
-
 def _ui(opts: Optional[Options]):
     """Raw Option.UpdateImpl value from a driver ``opts`` mapping — the
     trailing-update lowering the summa/potrf/LU-nopiv k-loops consume
@@ -159,11 +150,11 @@ def potrf_mesh(
 
         return potrf_ckpt(
             from_dense(a, mesh, nb, diag_pad_one=True), every=every,
-            bcast_impl=_bi(opts), panel_impl=_pi(opts), num_monitor=_nm(opts),
+            bcast_impl=_bi(opts), num_monitor=_nm(opts),
         )
     return potrf_dist(
         from_dense(a, mesh, nb, diag_pad_one=True), lookahead=_la(opts),
-        bcast_impl=_bi(opts), panel_impl=_pi(opts), update_impl=_ui(opts),
+        bcast_impl=_bi(opts), update_impl=_ui(opts),
         num_monitor=_nm(opts),
     )
 
@@ -193,8 +184,7 @@ def posv_mesh(
     mixed-precision ladder by default (Option.MixedPrecision, default
     auto: f32 mesh factor + fused f64 refinement, GMRES-IR escalation,
     full-f64 fallback — dist_refine.py; the f32 factor consumes every
-    opt the direct path would: Lookahead, BcastImpl, PanelImpl,
-    FaultTolerance).  ``off`` (or any non-f64 dtype) runs the direct
+    opt the direct path would: Lookahead, BcastImpl, FaultTolerance).  ``off`` (or any non-f64 dtype) runs the direct
     potrf + two-trsm path, trace-identical to the pre-mixed driver."""
     from .dist_refine import mixed_mesh_route
 
@@ -224,11 +214,11 @@ def getrf_nopiv_mesh(
 
         return getrf_nopiv_ckpt(
             from_dense(a, mesh, nb, diag_pad_one=True), every=every,
-            bcast_impl=_bi(opts), panel_impl=_pi(opts), num_monitor=_nm(opts),
+            bcast_impl=_bi(opts), num_monitor=_nm(opts),
         )
     return getrf_nopiv_dist(
         from_dense(a, mesh, nb, diag_pad_one=True), lookahead=_la(opts),
-        bcast_impl=_bi(opts), panel_impl=_pi(opts), update_impl=_ui(opts),
+        bcast_impl=_bi(opts), update_impl=_ui(opts),
         num_monitor=_nm(opts),
     )
 
@@ -273,7 +263,7 @@ def geqrf_mesh(
         return geqrf_ckpt(from_dense(a, mesh, nb), every=every,
                           bcast_impl=_bi(opts), num_monitor=_nm(opts))
     return geqrf_dist(from_dense(a, mesh, nb), bcast_impl=_bi(opts),
-                      panel_impl=_pi(opts), num_monitor=_nm(opts))
+                      num_monitor=_nm(opts))
 
 
 @instrument("gels_mesh")
@@ -440,7 +430,7 @@ def getrf_tntpiv_mesh(
     Returns (LU, perm over the padded row space, info)."""
     return getrf_tntpiv_dist(
         from_dense(a, mesh, nb, diag_pad_one=True), lookahead=_la(opts),
-        bcast_impl=_bi(opts), panel_impl=_pi(opts), num_monitor=_nm(opts),
+        bcast_impl=_bi(opts), num_monitor=_nm(opts),
     )
 
 
@@ -639,7 +629,7 @@ def getrf_mesh(
         )
     return getrf_pp_dist(
         from_dense(a, mesh, nb, diag_pad_one=True), lookahead=_la(opts),
-        bcast_impl=_bi(opts), panel_impl=_pi(opts), num_monitor=_nm(opts),
+        bcast_impl=_bi(opts), num_monitor=_nm(opts),
     )
 
 

@@ -12,10 +12,9 @@ asserts the acceptance surface end to end:
 - the GMRES-IR escalation tier converges on its own tolerance;
 - the ``ir.*`` counters land in a schema-valid RunReport.
 
-The smoke reads ``SLATE_TPU_BCAST_IMPL`` / ``SLATE_TPU_PANEL_IMPL`` like
-every mesh kernel, so CI re-runs it under the ring broadcast and Pallas
-panel lowerings to prove the opts actually reach the f32 factor and the
-refinement loop's residual SUMMA.
+The smoke reads ``SLATE_TPU_BCAST_IMPL`` like every mesh kernel, so CI
+re-runs it under the ring broadcast to prove the option actually reaches
+the f32 factor and the refinement loop's residual SUMMA.
 
 Usage::
 
@@ -119,8 +118,7 @@ def run_smoke(out_dir: str, n: int = 96, nb: int = 16) -> int:
     report.write_report(
         rep_path, name="mixed_smoke",
         config={"n": n, "nb": nb, "grid": "2x4",
-                "bcast_impl": os.environ.get("SLATE_TPU_BCAST_IMPL", "auto"),
-                "panel_impl": os.environ.get("SLATE_TPU_PANEL_IMPL", "auto")},
+                "bcast_impl": os.environ.get("SLATE_TPU_BCAST_IMPL", "auto")},
         values=vals,
     )
     with open(rep_path) as fh:

@@ -527,13 +527,12 @@ class Router:
 
     def _resil_opts(self):
         """Raw schedule/monitor options the resilient mesh path forwards
-        (the drivers' _la/_bi/_pi/_nm idiom — armed options must thread
+        (the drivers' _la/_bi/_nm idiom — armed options must thread
         end-to-end, not silently drop to defaults)."""
         from ..types import Option, get_option
 
         return (get_option(self.opts, Option.Lookahead),
                 get_option(self.opts, Option.BcastImpl),
-                get_option(self.opts, Option.PanelImpl),
                 get_option(self.opts, Option.NumMonitor))
 
     def _factor_solve_mesh(self, op: str, a, b, pol, tr=None):
@@ -542,7 +541,7 @@ class Router:
         from ..parallel.dist import from_dense
 
         every = self._ckpt_every()
-        la, bi, pi, nm = self._resil_opts()
+        la, bi, nm = self._resil_opts()
         if pol != FtPolicy.Off:
             if every is not None:
                 raise SlateError(
@@ -555,19 +554,19 @@ class Router:
                 if op == "posv":
                     l, info, _rep = abft.potrf_ft(
                         a, self.mesh, self.nb, policy=pol, lookahead=la,
-                        bcast_impl=bi, panel_impl=pi)
+                        bcast_impl=bi)
                 else:
                     # the only ABFT LU is no-pivot — _guard validates the
                     # solution it produces
                     l, info, _rep = abft.getrf_nopiv_ft(
                         a, self.mesh, self.nb, policy=pol, lookahead=la,
-                        bcast_impl=bi, panel_impl=pi)
+                        bcast_impl=bi)
             return self._trsm_solve(op, l, b, tr=tr), info
         d = from_dense(a, self.mesh, self.nb, diag_pad_one=True)
         if op == "posv":
             with rtrace.phase(tr, "factor", method="potrf_ckpt"):
                 l, info = potrf_ckpt(d, every=every, bcast_impl=bi,
-                                     panel_impl=pi, num_monitor=nm)
+                                     num_monitor=nm)
             return self._trsm_solve(op, l, b, tr=tr), info
         # gesv on the checkpointed path: with NumMonitor armed, try the
         # cheap no-pivot factor first — the FRIENDLY accuracy class the
@@ -593,8 +592,7 @@ class Router:
             try:
                 with rtrace.phase(tr, "factor", method="nopiv_ckpt"):
                     lu, info = getrf_nopiv_ckpt(
-                        d, every=every, bcast_impl=bi, panel_impl=pi,
-                        num_monitor=nm)
+                        d, every=every, bcast_impl=bi, num_monitor=nm)
                 serve_count("class_friendly")
                 return self._trsm_solve(op, lu, b, tr=tr), info
             except GrowthAbort:
@@ -612,7 +610,7 @@ class Router:
         from ..ft.ckpt import getrf_pp_ckpt
         from ..parallel.dist import from_dense
 
-        _la, bi, _pi, nm = self._resil_opts()
+        _la, bi, nm = self._resil_opts()
         if d is None:
             d = from_dense(a, self.mesh, self.nb, diag_pad_one=True)
         with rtrace.phase(tr, "factor", method="pp_ckpt"):
@@ -624,10 +622,9 @@ class Router:
     def _resume_solve(self, op: str, b, checkpoint, tr=None):
         from ..ft import elastic
 
-        _la, bi, pi, _nm = self._resil_opts()
+        _la, bi, _nm = self._resil_opts()
         with rtrace.phase(tr, "factor", method="elastic_resume"):
-            out = elastic.resume(checkpoint, self.mesh, bcast_impl=bi,
-                                 panel_impl=pi)
+            out = elastic.resume(checkpoint, self.mesh, bcast_impl=bi)
         if len(out) == 3:  # getrf_pp: (LU, perm, info)
             lu, perm, info = out
             return self._trsm_solve(op, lu, b, perm=perm, tr=tr), info
@@ -640,7 +637,7 @@ class Router:
         from ..parallel.dist_trsm import trsm_dist
         from ..types import Diag, Op, Uplo
 
-        la, bi, _pi, _nm = self._resil_opts()
+        la, bi, _nm = self._resil_opts()
         with rtrace.phase(tr, "solve"):
             bd = from_dense(b, self.mesh, self.nb)
             if perm is not None:
@@ -695,15 +692,14 @@ class Router:
             rtrace.finish(tr, "reject_admission")
             raise
         try:
-            _la, bi, pi, nm = self._resil_opts()
+            _la, bi, nm = self._resil_opts()
             monitored = _num.resolve_num_monitor(nm) == "on"
             if monitored:
                 _num.clear_last("geqrf")  # police THIS factor's gauge
             bcol = b if b.ndim == 2 else b[:, None]
             with rtrace.phase(tr, "factor", method="geqrf_dist"):
                 f1 = geqrf_dist(from_dense(a, self.mesh, self.nb),
-                                bcast_impl=bi, panel_impl=pi,
-                                num_monitor=nm)
+                                bcast_impl=bi, num_monitor=nm)
             if monitored and _num.orth_exceeded("geqrf"):
                 serve_count("retries")
                 rtrace.note(tr, "orth_retry")
@@ -715,8 +711,7 @@ class Router:
                         f1, from_dense(eye, self.mesh, self.nb),
                         Op.NoTrans, bcast_impl=bi))[:, :n]
                     f2 = geqrf_dist(from_dense(q1, self.mesh, self.nb),
-                                    bcast_impl=bi, panel_impl=pi,
-                                    num_monitor=nm)
+                                    bcast_impl=bi, num_monitor=nm)
                     qb = to_dense(unmqr_dist(
                         f2, from_dense(bcol, self.mesh, self.nb),
                         Op.ConjTrans, bcast_impl=bi))[:n]

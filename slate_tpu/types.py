@@ -240,34 +240,20 @@ class Option(enum.Enum):
     # order: explicit option > comm.use_bcast_impl context >
     # SLATE_TPU_BCAST_IMPL environment > auto.
     BcastImpl = "bcast_impl"
-    # Panel-factorization lowering for the fused Pallas panel kernels
-    # (ops/pallas_ops.py): "xla" (the reference semantics — today's
-    # cholesky/triangular_solve/Householder dispatch chains, bitwise),
-    # "pallas" (one fused on-chip kernel per panel phase: MAGMA-style
-    # blocked panels; f64/complex panels keep xla; on CPU the kernels run
-    # under the Pallas interpreter; on a TPU every panel kernel RAISES
-    # SlateError — Mosaic cannot lower their in-kernel dynamic_slice,
-    # PR 21), or "auto" (the default: xla on every backend).
-    # Resolution order: explicit option > pallas_ops.use_panel_impl
-    # context > SLATE_TPU_PANEL_IMPL environment > auto (the
-    # Option.BcastImpl pattern).  The pallas forms match the XLA
-    # references to the documented O(eps cond) explicit-inverse class
-    # (QR panels are bitwise); parity is gated by
-    # tests/test_pallas_panels.py under interpret mode.
-    PanelImpl = "panel_impl"
     # Trailing-update lowering for the mesh k-loops' bulk phase
     # (ops/pallas_ops.py, ISSUE 20): "xla" (the reference semantics —
     # today's einsum bulk chains, jaxpr-IDENTICAL by construction),
     # "pallas" (one fused grid dispatch over the local trailing tile
     # stack per k-step — summa_update_pallas / chol_trailing_update_pallas
     # / lu_trailing_update_pallas, with the broadcast panels riding VMEM
-    # blocks; bitwise vs the xla bulk under interpret mode), or "auto"
+    # blocks; bitwise vs the xla bulk in float64 under interpret mode,
+    # within a length-nb dot's rounding bound in float32), or "auto"
     # (the default: pallas on a real TPU backend for MXU dtypes, xla
     # elsewhere).  Fusion changes compute scheduling, never comm — the
     # broadcast schedule and comm-audit wire bytes are invariant across
     # lowerings (asserted).  Resolution order: explicit option >
     # pallas_ops.use_update_impl context > SLATE_TPU_UPDATE_IMPL
-    # environment > auto (the Option.PanelImpl pattern).  Scope: the
+    # environment > auto (the Option.BcastImpl pattern).  Scope: the
     # summa / potrf / LU-nopiv bulk phases; the pivoted/band LU kernels
     # pin xla (their trailing sweeps interleave with pivot application).
     UpdateImpl = "update_impl"
@@ -285,7 +271,7 @@ class Option(enum.Enum):
     MixedPrecision = "mixed_precision"
     # Numerical-health monitoring for the mesh factorization k-loops and
     # the mixed-precision refinement loop (obs/numerics.py): "off" (the
-    # plain kernels, jaxpr-IDENTICAL — the PanelImpl/MixedPrecision
+    # plain kernels, jaxpr-IDENTICAL — the UpdateImpl/MixedPrecision
     # pattern), "on" (the loop carry accumulates running element-growth /
     # diagonal-margin gauges and the refinement while_loop keeps a
     # fixed-size (||r||, ||x||) history buffer — zero extra collectives:

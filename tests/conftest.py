@@ -14,11 +14,13 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax
 
 jax.config.update("jax_enable_x64", True)
-# persistent compile cache: the suite re-compiles hundreds of CPU programs
-# per run; the disk cache cuts warm reruns
-from slate_tpu.utils.compile_cache import enable_compile_cache
-
-enable_compile_cache(min_compile_secs=2.0)
+# no persistent compile cache, even where JAX_COMPILATION_CACHE_DIR is set:
+# an XLA:CPU executable loaded back from the disk cache can give two of its
+# collective-permutes one rendezvous id, and a mesh program whose permutes
+# sit in conditional branches (getrf_pp_dist's k-loop on the 2x2 mesh) then
+# aborts the process in the rendezvous.  Programs compiled in-process
+# are unaffected.
+jax.config.update("jax_enable_compilation_cache", False)
 
 import numpy as np
 import pytest

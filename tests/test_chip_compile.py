@@ -4,10 +4,7 @@ No chip is attached: ``jax.experimental.topologies`` describes a v5e and
 the TPU compiler (Mosaic for the kernels) runs here, so a kernel that
 interpret mode accepts but the chip's compiler refuses fails in tier-1
 instead of on the chip.  Real widths: nb = 256, an 8 x 8 local trailing
-grid, a 63-tile panel.  The panel kernels do not lower (value-level
-``dynamic_slice``) and raise on a TPU under an explicit
-``Option.PanelImpl=pallas``.  ``_interpret()`` is steered inside each
-test; the topology is described in a module-scoped fixture, never at
+grid.  ``_interpret()`` is steered inside each test; the topology is described in a module-scoped fixture, never at
 import time (only one process may load libtpu, and every xdist worker
 imports this file).  A failure to describe it fails the tests: the
 installed libtpu can always describe a v5e.
@@ -22,11 +19,9 @@ from jax.sharding import SingleDeviceSharding
 
 from slate_tpu.ops import pallas_ops as po
 from slate_tpu.ops.matmul import matmul_pallas
-from slate_tpu.types import SlateError
 
 NB = 256
 TRAIL = 8  # local trailing tile grid
-PANEL = 63  # panel tiles below the diagonal tile
 
 
 @pytest.fixture(scope="module")
@@ -70,11 +65,6 @@ DEFAULT_PATH_KERNELS = {
     "summa_update": (po.summa_update_pallas, lambda s: _trailing_args(s, mask=False)),
     "chol_trailing_update": (po.chol_trailing_update_pallas, _trailing_args),
     "lu_trailing_update": (po.lu_trailing_update_pallas, _trailing_args),
-    "ft_summa_update": (
-        po.ft_summa_update_pallas,
-        lambda s: _trailing_args(s, mask=False)
-        + (s((TRAIL,)), s((TRAIL,)), s((2, TRAIL, NB, NB))),
-    ),
     "transpose": (po.transpose_pallas, lambda s: (s((TRAIL, NB, NB)),)),
     "geadd": (
         lambda a, b: po.geadd_pallas(2.0, a, 0.5, b),
@@ -97,46 +87,11 @@ def test_kernel_compiles_for_v5e(name, one_chip, on_tpu):
 
 
 def test_auto_resolves_as_on_the_chip(on_tpu):
-    """auto: trailing updates take the kernels above (within the VMEM
-    cap); panels stay on XLA until a chip measurement says otherwise."""
+    """auto: trailing updates take the kernels above within the VMEM
+    cap, and fall back to XLA past it."""
     assert po.update_active_impl() == "pallas"
     assert po.update_engaged(jnp.float32, 2 * TRAIL * NB * NB * 4)
-    assert po.panel_active_impl() == "xla"
-    assert not po.panel_engaged(jnp.float32, NB * NB * 4)
-
-
-def _panel_args(shape):
-    return shape((NB, NB)), shape((PANEL, NB, NB))
-
-
-PANEL_KERNELS = {
-    "chol_diag_inv": lambda d, t: po.chol_diag_inv_pallas(d),
-    "chol_panel_tiles": po.chol_panel_tiles_pallas,
-    "lu_panel_tiles": po.lu_panel_tiles_pallas,
-    "lu_rowsolve_tiles": po.lu_rowsolve_tiles_pallas,
-    "qr_panel": lambda d, t: po.qr_panel_pallas(t.reshape(PANEL * NB, NB)),
-    "qr_panel_offset": lambda d, t: po.qr_panel_offset_pallas(t.reshape(PANEL * NB, NB), 0),
-}
-
-
-@pytest.mark.parametrize("name", sorted(PANEL_KERNELS))
-def test_panel_kernel_raises_on_tpu(name, one_chip, on_tpu):
-    """A panel kernel Mosaic cannot lower raises SlateError on a TPU
-    backend — never a silent drop to XLA or to the interpreter."""
-    shape = lambda dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
-    with pytest.raises(SlateError, match="does not lower"):
-        jax.jit(PANEL_KERNELS[name]).lower(*_panel_args(shape))
-
-
-def test_explicit_pallas_panel_raises_through_driver(on_tpu):
-    """Option.PanelImpl=pallas on the public QR factor raises on a TPU
-    backend instead of running another lowering; auto traces XLA."""
-    from slate_tpu import api
-
-    a = jax.ShapeDtypeStruct((512, 256), jnp.float32)
-    with po.use_panel_impl("pallas"), pytest.raises(SlateError, match="does not lower"):
-        jax.eval_shape(api.qr_factor, a)
-    assert "pallas_call" not in str(jax.make_jaxpr(api.qr_factor)(a))
+    assert not po.update_engaged(jnp.float32, po._UPDATE_VMEM_CAP + 1)
 
 
 @pytest.mark.parametrize("driver,fused", [("posv_mesh", True), ("gesv_mesh", False)])
@@ -145,8 +100,7 @@ def test_mesh_solve_compiles_for_2x2(driver, fused, topo, on_tpu):
     x64 on (as the test suite and every f64 user runs): the pmin'd info
     scalars must stay 32-bit.  At this local grid the Cholesky trailing
     update takes the fused kernel inside shard_map; the partial-pivot LU
-    has no fused update and its panels stay XLA under auto, so its
-    program holds no Mosaic kernel."""
+    has no fused update, so its program holds no Mosaic kernel."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from slate_tpu import parallel
@@ -182,7 +136,7 @@ def test_pp_panel_slab_row_major_for_v5e(topo, on_tpu):
     rows, ib = nt // 2 * NB, _pp_sub_width(NB)
     t = jax.ShapeDtypeStruct((nt, nt, NB, NB), jnp.float32,
                              sharding=NamedSharding(mesh, P(ROW_AXIS, COL_AXIS)))
-    hlo = _pp_jit.lower(t, mesh, 2, 2, nt, nt * NB, 1, "auto", "auto").compile().as_text()
+    hlo = _pp_jit.lower(t, mesh, 2, 2, nt, nt * NB, 1, "auto").compile().as_text()
     slab = f"f32[{ib},{rows}]{{1,0"
     loops = re.findall(r"= \((.*?)\) while\(.*?body=%?([\w.\-]+)", hlo)
     col_loops = [body for carry, body in loops if slab in carry]

@@ -344,3 +344,21 @@ def test_potrf_scan_non_spd_info():
     info_scan = int(_pivot_info(_potrf_scan(a, nb=64, nbuckets=4)))
     info_lower = int(_pivot_info(_potrf_lower(a)))
     assert info_scan == info_lower == p + 1
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_potrf_and_inv_leaf(dtype):
+    """The joint (L, L^-1) of one leaf block (the scanned factor's panel
+    pair): L L^T = A and L L^-1 = I to the dtype's backward-error class
+    against the float64 reference."""
+    from slate_tpu.linalg.chol import _potrf_and_inv
+
+    nb = 8
+    a = generate("spd", nb, dtype=np.float64, seed=3).astype(dtype)
+    l, linv = (np.asarray(x, np.float64) for x in _potrf_and_inv(jnp.asarray(a)))
+    an = np.asarray(a, np.float64)
+    tol = 100 * nb * float(np.finfo(dtype).eps)
+    assert np.abs(np.triu(l, 1)).max() == 0 and np.abs(np.triu(linv, 1)).max() == 0
+    assert np.abs(l @ l.T - an).max() < tol * nb * np.abs(an).max()
+    scale = nb * np.abs(l).max() * np.abs(linv).max()
+    assert np.abs(l @ linv - np.eye(nb)).max() < tol * scale

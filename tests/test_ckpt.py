@@ -139,6 +139,30 @@ def test_checkpoint_disk_roundtrip(tmp_path):
     _assert_tree_bitwise(ref, elastic.resume(ck2, mesh), "disk resume")
 
 
+def test_checkpoint_with_panel_impl_key_resumes(tmp_path):
+    """A snapshot written when the metadata still carried a
+    ``panel_impl`` field loads (the key is ignored) and resumes bitwise."""
+    import json
+
+    mesh = mesh24()
+    d, ref, ckpted = _run_case("potrf", mesh)
+    with inject.fault_scope(
+        inject.FaultPlan([inject.KillFault("potrf", 4)])
+    ), pytest.raises(ckpt.Preempted) as ei:
+        ckpted(d, every=EVERY)
+    path = ei.value.checkpoint.save(str(tmp_path / "new.npz"))
+    with np.load(path) as z:
+        arrays = {k: z[k] for k in z.files}
+    meta = json.loads(bytes(arrays["meta"]).decode())
+    meta["panel_impl"] = "auto"
+    arrays["meta"] = np.frombuffer(json.dumps(meta).encode(), np.uint8)
+    old = str(tmp_path / "old.npz")
+    with open(old, "wb") as fh:
+        np.savez(fh, **arrays)
+    ck = ckpt.Checkpoint.load(old)
+    _assert_tree_bitwise(ref, elastic.resume(ck, mesh), "old-snapshot resume")
+
+
 def test_ckpt_off_is_driver_jaxpr_identical():
     """Option.Checkpoint off/absent routes potrf_mesh through the exact
     pre-checkpoint path — same jaxpr, not merely same numbers."""

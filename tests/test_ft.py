@@ -141,6 +141,29 @@ def test_detect_clean(rng, dtype):
     assert ft_counter_values()["detected"] == before  # nothing flagged
 
 
+@pytest.mark.parametrize("op", ["potrf", "getrf_nopiv"])
+def test_ft_factor_clean_backward_error(rng, op):
+    """A clean checksum-carrying factor reports ``clean`` and meets the
+    float64 backward-error class of an NB-blocked factorization."""
+    mesh = mesh24()
+    if op == "potrf":
+        a = _spd(rng, N)
+        res, info, rep = abft.potrf_ft(a, mesh, NB)
+    else:
+        a = _ddom(rng, N)
+        res, info, rep = abft.getrf_nopiv_ft(a, mesh, NB)
+    assert int(info) == 0
+    assert rep.action == "clean"
+    out = np.asarray(to_dense(res), np.float64)
+    an = np.asarray(a, np.float64)
+    if op == "potrf":
+        rec = np.tril(out) @ np.tril(out).T
+    else:
+        rec = (np.tril(out, -1) + np.eye(N)) @ np.triu(out)
+    tol = 100 * NB * float(np.finfo(np.float64).eps) * N * np.abs(an).max()
+    assert np.abs(rec - an).max() < tol
+
+
 # ---------------------------------------------------------------------------
 # (c) injected single-tile faults per phase: detect + locate + repair
 # ---------------------------------------------------------------------------

@@ -164,18 +164,24 @@ def test_auto_routes_through_f32_factor_and_meets_gate(kind, rng):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("cond,max_iters", [(1e2, 4), (1e8, 30)])
+@pytest.mark.parametrize("cond,max_iters", [
+    pytest.param(1e2, 4, id="100.0-4"),
+    pytest.param(1e8, 30, id="100000000.0-30"),
+    pytest.param(None, 4, id="spd"),  # the SPD solve: posv_mixed_mesh
+])
 def test_mixed_accuracy_at_gate(cond, max_iters, rng):
     mesh = mesh24()
-    a = _cond(rng, cond)
+    a = _cond(rng, cond) if cond is not None else _spd(rng)
     b = _rhs(rng, 3)  # multi-RHS
-    x, iters, info = gesv_mixed_mesh(a, b, mesh, NB)
+    mixed, plain = ((gesv_mixed_mesh, _gesv_mesh_plain) if cond is not None
+                    else (posv_mixed_mesh, _posv_mesh_plain))
+    x, iters, info = mixed(a, b, mesh, NB)
     assert int(info) == 0
     assert 0 <= int(iters) <= max_iters
     assert _gate(a, x, b)
     # mixed-vs-f64: the direct f64 solve also satisfies the same gate —
     # the mixed path's accuracy contract is the f64 path's
-    xf, info_f = _gesv_mesh_plain(a, b, mesh, NB)
+    xf, info_f = plain(a, b, mesh, NB)
     assert _gate(a, xf, b)
 
 
@@ -281,20 +287,6 @@ def test_mixed_opts_threading_bitwise_invariant(rng):
             outs.append(np.asarray(x))
     for o in outs[1:]:
         np.testing.assert_array_equal(outs[0], o)
-
-
-def test_mixed_pallas_panels_meet_gate(rng):
-    # Option.PanelImpl=pallas reroutes the f32 factor's panel phases to
-    # the fused kernels (interpret mode on CPU) — different bits
-    # (documented explicit-inverse class), same accuracy contract
-    mesh = mesh24()
-    a = _spd(rng)
-    b = _rhs(rng)
-    x, iters, info = posv_mixed_mesh(
-        a, b, mesh, NB, opts={Option.PanelImpl: "pallas"}
-    )
-    assert int(info) == 0 and int(iters) >= 0
-    assert _gate(a, x, b)
 
 
 # ---------------------------------------------------------------------------

@@ -49,6 +49,12 @@ def _spd(rng, n, dtype=np.float64):
     return a @ jnp.conj(a).T + n * jnp.eye(n, dtype=dtype)
 
 
+def _tol(dtype, nb, scale):
+    """Backward-error class of an nb-blocked factorization: 100 nb eps
+    times the operand scale."""
+    return 100 * nb * float(np.finfo(dtype).eps) * scale
+
+
 def test_roundtrip(rng):
     mesh = mesh24()
     a = _rand(rng, 100, 68)
@@ -127,15 +133,29 @@ def test_trsm_dist_stationary_a(rng, uplo, op):
     assert select_trsm_method(Side.Left, n // 8, nrhs // 8) == MethodTrsm.TrsmA
 
 
-@pytest.mark.parametrize("n", [64, 100])
-def test_potrf_dist(rng, n):
+@pytest.mark.parametrize("dtype,n,nb", [
+    pytest.param(np.float64, 64, 16, id="64"),
+    pytest.param(np.float64, 100, 16, id="100"),
+    pytest.param(np.float64, 64, 8, id="float64-aligned"),
+    pytest.param(np.float64, 60, 8, id="float64-ragged-tail"),
+    pytest.param(np.float32, 64, 8, id="float32-aligned"),
+    pytest.param(np.float32, 60, 8, id="float32-ragged-tail"),
+    pytest.param(np.complex128, 64, 8, id="complex128-aligned"),
+])
+def test_potrf_dist(rng, dtype, n, nb):
+    """L L^H = A to the dtype's backward-error class against the float64
+    (complex128) reference, aligned and with a ragged last tile."""
     mesh = mesh24()
-    a = _spd(rng, n)
-    l, info = potrf_mesh(a, mesh, nb=16)
+    a = _spd(rng, n, dtype)
+    l, info = potrf_mesh(a, mesh, nb=nb)
     assert int(info) == 0
-    ld = np.tril(np.asarray(to_dense(l)))
-    resid = np.linalg.norm(ld @ ld.T - np.asarray(a)) / np.linalg.norm(np.asarray(a))
-    assert resid < 1e-13
+    wide = np.complex128 if np.issubdtype(dtype, np.complexfloating) else np.float64
+    ld = np.tril(np.asarray(to_dense(l), wide))
+    an = np.asarray(a, wide)
+    err = ld @ ld.conj().T - an
+    if dtype != np.float32:
+        assert np.linalg.norm(err) / np.linalg.norm(an) < 1e-13
+    assert np.abs(err).max() < _tol(dtype, nb, np.abs(an).max() * n)
 
 
 def test_potrf_dist_complex(rng):
@@ -168,6 +188,51 @@ def test_posv_mesh(rng):
     assert int(info) == 0
     err = np.linalg.norm(np.asarray(x) - np.asarray(x_true)) / np.linalg.norm(np.asarray(x_true))
     assert err < 1e-10
+
+
+@pytest.mark.parametrize("n", [64, 60], ids=["aligned", "ragged-tail"])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_getrf_nopiv_dist_factor(rng, dtype, n):
+    """L U = A for a diagonally dominant A (no pivot needed), to the
+    dtype's backward-error class against the float64 reference."""
+    from slate_tpu.parallel import getrf_nopiv_mesh
+
+    mesh, nb = mesh24(), 8
+    a = _rand(rng, n, n, dtype) + n * jnp.eye(n, dtype=dtype)
+    lu, info = getrf_nopiv_mesh(a, mesh, nb=nb)
+    assert int(info) == 0
+    lun = np.asarray(to_dense(lu), np.float64)[:n, :n]
+    an = np.asarray(a, np.float64)
+    rec = (np.tril(lun, -1) + np.eye(n)) @ np.triu(lun)
+    assert np.abs(rec - an).max() < _tol(dtype, nb, np.abs(an).max() * n)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("form", ["factor_solve", "rowsolve"])
+def test_lu_panel_forms(rng, form, dtype):
+    """The mesh LU's panel solves on one nb = 8 diagonal tile and 8
+    tiles beside it, against the float64 reference: the column half
+    packs L\\U of the tile (L U = A_kk) and solves A_i U^-1; the row half
+    solves L^-1 A_j with the unit lower factor."""
+    from slate_tpu.parallel.dist_lu import _lu_panel_factor_solve, _lu_panel_rowsolve
+
+    nb, tiles = 8, 8
+    d = (rng.standard_normal((nb, nb)) + nb * np.eye(nb)).astype(dtype)
+    pan = rng.standard_normal((tiles, nb, nb)).astype(dtype)
+    luk, solved = _lu_panel_factor_solve(jnp.asarray(d), jnp.asarray(pan))
+    lu = np.asarray(luk, np.float64)
+    lo, up = np.tril(lu, -1) + np.eye(nb), np.triu(lu)
+    dn, pn = np.asarray(d, np.float64), np.asarray(pan, np.float64)
+    tol = 100 * nb * float(np.finfo(dtype).eps)
+    assert np.abs(lo @ up - dn).max() < tol * nb * np.abs(dn).max()
+    if form == "factor_solve":
+        got, rebuilt = np.asarray(solved, np.float64), lambda x: x @ up
+    else:
+        eye = jnp.eye(nb, dtype=dtype)
+        got = np.asarray(_lu_panel_rowsolve(luk, jnp.asarray(pan), eye), np.float64)
+        rebuilt = lambda x: lo @ x
+    scale = nb * np.abs(pn).max() * max(np.abs(lo).max(), np.abs(up).max())
+    assert np.abs(rebuilt(got) - pn).max() < tol * scale
 
 
 def test_gesv_nopiv_mesh(rng):
@@ -1091,17 +1156,17 @@ def test_band_mesh_kernels_band_cost(rng):
 
     # lowering pinned to psum + the xla panel/update forms: the
     # flop-class gate is impl-independent (ppermute adds bytes
-    # bookkeeping, not flops; the fused panel/update kernels change
+    # bookkeeping, not flops; the fused update kernels change
     # dispatch count, not flop class) but the jits now take the
-    # bcast-impl / panel-impl / update-impl static args
+    # bcast-impl / update-impl static args
     dense = _potrf_jit.lower(
-        tiles, mesh, 2, 4, nt, 1, "psum", "xla", "xla"
+        tiles, mesh, 2, 4, nt, 1, "psum", "xla"
     ).compile()
     band = _pbtrf_band_jit.lower(tiles, mesh, 2, 4, nt, wd, 1, "psum").compile()
     assert flops(band) < flops(dense) / 4, (flops(band), flops(dense))
 
     dense_lu = _pp_jit.lower(
-        tiles, mesh, 2, 4, nt, n, 1, "psum", "xla"
+        tiles, mesh, 2, 4, nt, n, 1, "psum"
     ).compile()
     wd_u = ((nb - 1) + 2 * kd) // nb + 1
     wd_usw = ((nb - 1) + 3 * kd) // nb + 1

@@ -192,7 +192,7 @@ def _pp(la):
         from slate_tpu.parallel.dist_lu import _pp_jit
 
         mesh, d = _mesh_operand()
-        return _pp_jit.lower(d.tiles, mesh, 2, 2, d.nt, d.m, la, "psum", "xla", False)
+        return _pp_jit.lower(d.tiles, mesh, 2, 2, d.nt, d.m, la, "psum", False)
     return lower
 
 

@@ -130,3 +130,36 @@ def test_geqrf_scan():
             unmqr_scan_array(f, unmqr_scan_array(f, jnp.asarray(b), Op.ConjTrans))
         )
         assert np.abs(rt - b).max() < 1e-12
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_panel_qr_pairs(dtype):
+    """The Householder panel + compact-WY T pairs, on one nb = 8 panel
+    over a diagonal tile and 8 tiles below it: Q = I - V T V^H is
+    orthogonal and rebuilds the panel (``_panel_qr_t``), and likewise for
+    the offset-pivot form with one tile of zeroed history above
+    (``_panel_qr_offset_t``), against the float64 reference."""
+    from slate_tpu.linalg.qr import _panel_qr_offset_t, _panel_qr_t, _v_of
+
+    nb = 8
+    m = 9 * nb
+    rng = np.random.default_rng(5)
+    tol = 100 * nb * float(np.finfo(dtype).eps)
+
+    def check(q, rebuilt, a):
+        assert np.abs(q.T @ q - np.eye(q.shape[0])).max() < tol
+        assert np.abs(rebuilt - a).max() < tol * q.shape[0] * np.abs(a).max()
+
+    a = rng.standard_normal((m, nb)).astype(dtype)
+    vr, _tau, t = _panel_qr_t(jnp.asarray(a))
+    v = np.asarray(_v_of(vr), np.float64)
+    q = np.eye(m) - v @ np.asarray(t, np.float64) @ v.T
+    r = np.vstack([np.triu(np.asarray(vr, np.float64)[:nb]), np.zeros((m - nb, nb))])
+    check(q, q @ r, np.asarray(a, np.float64))
+
+    ao = np.vstack([np.zeros((nb, nb)), rng.standard_normal((m, nb))]).astype(dtype)
+    r, v, _tau, t = (np.asarray(x, np.float64)
+                     for x in _panel_qr_offset_t(jnp.asarray(ao), nb))
+    q = np.eye(m + nb) - v @ t @ v.T
+    assert np.abs(np.tril(r[nb:], -1)).max() == 0 and np.abs(r[:nb]).max() == 0
+    check(q, q @ r, np.asarray(ao, np.float64))

@@ -8,8 +8,6 @@
 - The trailing-update kernels alone, with a random per-tile keep mask,
   against the same select in XLA: each grid step must apply its own
   tile's mask.
-- ``Option.PanelImpl``: ``auto`` is ``xla``; an explicit ``pallas`` must
-  raise ``SlateError`` on a TPU (the panel kernels do not lower).
 
 Each driver runs at such a shape on a mesh over every visible device
 (1x1 on one chip, 2x2 on four) under the fused lowering and under
@@ -132,19 +130,6 @@ def main(argv=None) -> int:
                           "max_rel_diff_vs_xla": diff, "diff_gate": 1e-5, "ok": good}),
               flush=True)
 
-    if devs[0].platform == "tpu":  # off-TPU the panel kernels run interpreted
-        from slate_tpu.types import SlateError
-
-        for name, fn, a in (("potrf_mesh", chol, spd), ("getrf_nopiv_mesh", lu, diag_dom)):
-            try:
-                fn(jnp.asarray(a), {Option.PanelImpl: "pallas"})
-                raised = None
-            except SlateError as e:
-                raised = str(e)
-            good = raised is not None
-            ok &= good
-            print(json.dumps({"option": Option.PanelImpl.value, "impl": "pallas",
-                              "driver": name, "raised": raised, "ok": good}), flush=True)
     d = devs[0]
     print(json.dumps({"ok": bool(ok), "device": {"platform": d.platform,
                                                   "kind": d.device_kind,

@@ -56,8 +56,6 @@ PHASE_FLOPS = {
     "hemm_summa": 2 * N * N * NRHS,
     "stedc_dist": None,
     "heev_chain": 4 * N**3 / 3,
-    # potrf + LU-nopiv through the fused panel path
-    "panel_pallas": N**3 / 3 + 2 * N**3 / 3,
     "flight_timeline": None,
 }
 
