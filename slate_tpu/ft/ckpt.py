@@ -6,7 +6,7 @@ everything.  This module re-expresses the three long-running factor
 loops — potrf, LU-nopiv, and partial-pivot LU — as a CHAIN OF SEGMENT
 DISPATCHES over the same module-level step helpers the flight recorder
 already exercises per step (``_chol_panel_compute``/``_nopiv_panel``/
-``_pp_panel_and_swaps``): each segment jit runs steps [k0, k1) of the
+``_pp_strict_steps``): each segment jit runs steps [k0, k1) of the
 strict (depth-0, unbucketed) schedule on the full tile view, and the
 loop carry — factored panels + trailing block in one cyclic tile stack,
 the replicated pivot permutation (pp), and the Option.NumMonitor gauge
@@ -104,8 +104,7 @@ from ..parallel.dist_lu import (
     _nopiv_bulk,
     _nopiv_narrow,
     _nopiv_panel,
-    _nopiv_step,
-    _pp_panel_and_swaps,
+    _pp_strict_steps,
     _wabs_max,
     getrf_nopiv_dist,
     getrf_pp_dist,
@@ -530,44 +529,20 @@ def _wabs_init_jit(at, mesh, p, q, m_true):
 
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _pp_seg_jit(at, rowperm, g, mesh, p, q, nt, m_true, k0, k1, bi, nm):
+    """Steps [k0, k1) of getrf_pp_dist's strict schedule
+    (``_pp_strict_steps``)."""
     spec = P(ROW_AXIS, COL_AXIS)
 
     def kernel(t_loc, rowperm, g_in):
-        mtl, ntl, nb, _ = t_loc.shape
-        r, c, i_log, j_log = local_indices(p, q, mtl, ntl)
-        zero = jnp.zeros((), jnp.int32)
-        rdt = num_gauge_dtype(t_loc.dtype)
-
-        def probe(t_loc, gg):
-            return jnp.maximum(
-                gg, _wabs_max(t_loc, i_log, j_log, nb, m_true, rdt))
-
-        def step(k, carry):
-            if nm:
-                t_loc, rowperm, gg = carry
-                gg = probe(t_loc, gg)
-            else:
-                t_loc, rowperm = carry
-            t_loc, rowperm = _pp_panel_and_swaps(
-                t_loc, rowperm, k, p, q, r, c, nt, m_true,
-                zero, mtl, zero, ntl,
-            )
-            t_loc = _nopiv_step(
-                t_loc, k, p, q, i_log, j_log, r, c, panel_done=True
-            )
-            return (t_loc, rowperm, gg) if nm else (t_loc, rowperm)
-
-        init = ((t_loc, rowperm, g_in.astype(rdt)) if nm
-                else (t_loc, rowperm))
-        with audit_scope(k1 - k0):
-            out = lax.fori_loop(k0, k1, step, init)
+        r, c, i_log, j_log = local_indices(p, q, *t_loc.shape[:2])
+        g = g_in.astype(num_gauge_dtype(t_loc.dtype)) if nm else None
+        t_loc, rowperm, g = _pp_strict_steps(
+            t_loc, rowperm, g, k0, k1, p, q, r, c, i_log, j_log, nt, m_true)
         if nm:
-            t_loc, rowperm, gg = out
-            gg = lax.pmax(lax.pmax(gg, ROW_AXIS), COL_AXIS)
+            g = lax.pmax(lax.pmax(g, ROW_AXIS), COL_AXIS)
         else:
-            t_loc, rowperm = out
-            gg = jnp.zeros((), jnp.float32)
-        return t_loc, rowperm[None], gg[None, None]
+            g = jnp.zeros((), jnp.float32)
+        return t_loc, rowperm[None], g[None, None]
 
     with bcast_impl_scope(bi):
         lt, perm, g_out = shard_map_compat(

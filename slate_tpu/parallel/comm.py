@@ -509,6 +509,13 @@ def bcast_diag_tile(
     dtile = lax.dynamic_slice(
         t_loc, (k // p - roff, k // q - coff, 0, 0), (1, 1, nb, nb)
     )[0, 0]
+    return bcast_owner_tile(dtile, k, p, q)
+
+
+def bcast_owner_tile(dtile: jax.Array, k, p: int, q: int) -> jax.Array:
+    """The broadcast half of :func:`bcast_diag_tile`: every device
+    passes its candidate ``dtile`` and receives the one held by the
+    device at mesh (k % p, k % q)."""
     if _IMPL_ACTIVE[-1] == "psum":
         r = lax.axis_index(ROW_AXIS)
         c = lax.axis_index(COL_AXIS)

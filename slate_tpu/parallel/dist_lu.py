@@ -61,6 +61,7 @@ from .comm import (
     bcast_from_col,
     bcast_from_row,
     bcast_impl_scope,
+    bcast_owner_tile,
     bucket_plan,
     la_depth,
     local_indices,
@@ -315,9 +316,12 @@ def _wabs_max(view, i_v, j_v, nb, m_true, rdt):
     """Masked abs-max of the working array over the true extent — the
     element-growth probe (running max of max|A^(k)|, the quantity the
     Wilkinson growth bound speaks about).  Purely local: the gauge rides
-    the loop carry and is pmax-reduced ONCE at kernel exit."""
+    the loop carry and is pmax-reduced ONCE at kernel exit.  ``view`` is
+    a tile stack or, 2-D, the local matrix of ``_tiles_to_rows``."""
     gr = i_v[:, None, None, None] * nb + jnp.arange(nb)[None, None, :, None]
     gc = j_v[None, :, None, None] * nb + jnp.arange(nb)[None, None, None, :]
+    if view.ndim == 2:
+        gr, gc = gr.reshape(-1, 1), gc.reshape(1, -1)
     m = (gr < m_true) & (gc < m_true)
     return jnp.max(jnp.where(m, jnp.abs(view), 0)).astype(rdt)
 
@@ -694,7 +698,10 @@ def getrf_pp_dist(
     The accumulated nb transpositions then move
     full rows across shards with the same gather/scatter collective the
     tournament kernel uses (internal_swap.cc's role), and the step finishes
-    with the shared row-solve + trailing-gemm tail (_nopiv_step).
+    with the row solve, the panel broadcasts and the trailing product.
+    The k-loop carries the local tile stack as one row-major matrix
+    (``_tiles_to_rows``), so the product, the row swap and the panel's
+    column block share its layout.
 
     Returns (LU DistMatrix, perm over the padded row space, info), same
     contract as getrf_tntpiv_dist.  ``lookahead`` >= 1 overlaps the
@@ -740,11 +747,23 @@ def _pp_sub_width(nb: int) -> int:
 
 
 def _pp_panel_factor(t_loc, k, p, q, r, c, nt, m_true, s_r, wlr, ib=None):
+    """:func:`_pp_factor_panel` of the tile stack's local column slot
+    k // q, window rows [s_r, s_r + wlr)."""
+    nb = t_loc.shape[2]
+    zero = jnp.zeros((), jnp.int32)
+    pcolw = lax.dynamic_slice(
+        t_loc, (s_r, jnp.asarray(k // q, jnp.int32), zero, zero), (wlr, 1, nb, nb)
+    )[:, 0]
+    return _pp_factor_panel(pcolw, k, p, q, r, c, nt, m_true, s_r, ib)
+
+
+def _pp_factor_panel(pcolw, k, p, q, r, c, nt, m_true, s_r, ib=None):
     """Partial-pivot panel factor (the internal_getrf.cc half of the
-    shared machinery) on a broadcast COPY of panel column k.  Reads only
-    local column slot k // q (window rows [s_r, s_r + wlr)), so under
-    lookahead it can run after the narrow column refresh and overlap the
-    deferred bulk update.
+    shared machinery) on a broadcast COPY of panel column k.  ``pcolw``
+    (wlr, nb, nb) is this device's local column slot k // q, window rows
+    [s_r, s_r + wlr) (only the owning mesh column's is used), so under
+    lookahead the factor can run after the narrow column refresh and
+    overlap the deferred bulk update.
 
     Blocked within the panel as LAPACK's dgetrf blocks a matrix
     (right-looking): the nb columns split into sub-blocks of ``ib``
@@ -767,21 +786,16 @@ def _pp_panel_factor(t_loc, k, p, q, r, c, nt, m_true, s_r, wlr, ib=None):
 
     Returns (flat, piv_pos): the factored panel (flattened window rows,
     (wlr * nb, nb)) and the global pivot position chosen per column."""
-    mtl, ntl, nb, _ = t_loc.shape
-    dtype = t_loc.dtype
+    wlr, nb, _ = pcolw.shape
+    dtype = pcolw.dtype
     mglob = nt * nb
     base = k * nb
-    kc32 = jnp.asarray(k // q, jnp.int32)
-    zero = jnp.zeros((), jnp.int32)
     rows = wlr * nb
     i_win = r + (s_r + jnp.arange(wlr)) * p
     win_gids = (i_win[:, None] * nb + jnp.arange(nb)[None, :]).reshape(-1)
     ib = _pp_sub_width(nb) if ib is None else ib
 
     # ---- panel factor with per-column pivoting (getrf panel) ----
-    pcolw = lax.dynamic_slice(
-        t_loc, (s_r, kc32, zero, zero), (wlr, 1, nb, nb)
-    )[:, 0]
     pan = bcast_from_col(jnp.where(c == k % q, pcolw, 0), k % q)
     flat = pan.reshape(rows, nb)
 
@@ -891,22 +905,17 @@ def _pp_panel_factor(t_loc, k, p, q, r, c, nt, m_true, s_r, wlr, ib=None):
     return flat, jnp.concatenate(pivs)
 
 
-def _pp_apply_swaps(t_loc, rowperm, flat, piv_pos, k, p, q, r, c, nt,
-                    s_r, wlr, s_cw, wlsw):
-    """Apply the partial-pivot panel's nb transpositions to the stored
-    rows (the internal_swap.cc half) and write the factored panel back
-    into the owning column.  Reads full rows across the swap column
-    window, so any deferred trailing update must be fully applied first.
-    Returns (t_loc, rowperm)."""
-    mtl, ntl, nb, _ = t_loc.shape
-    dtype = t_loc.dtype
+def _pp_swap_plan(rowperm, piv_pos, k, p, r, nt, nb, mtl):
+    """Where the partial-pivot panel's nb transpositions move stored rows
+    (the internal_swap.cc half), in no particular carry layout.  The
+    transpositions are simulated on the global row positions; the rows
+    that move are the nb panel targets and the pivot positions below the
+    panel.  Returns (rowperm, src, dst): ``src`` (local tile slot, row in
+    the tile, owned here) names the 2nb rows each destination receives,
+    ``dst`` (local tile slot, row in the tile) where they land, slot
+    ``mtl`` where this device owns no destination (dropped)."""
     mglob = nt * nb
     base = k * nb
-    kc32 = jnp.asarray(k // q, jnp.int32)
-    zero = jnp.zeros((), jnp.int32)
-
-    # ---- apply the nb transpositions to the stored rows (restricted to
-    # the swap column window; the panel column is overwritten below) ----
     ident = jnp.arange(mglob)
 
     def sim(j, sc):
@@ -925,18 +934,39 @@ def _pp_apply_swaps(t_loc, rowperm, flat, piv_pos, k, p, q, r, c, nt,
     src = jnp.minimum(occ, mglob - 1)
     src_t, src_r = src // nb, src % nb
     own_src = (src_t % p == r) & slot_ok
-    tcols = lax.dynamic_slice(
-        t_loc, (zero, s_cw, zero, zero), (mtl, wlsw, nb, nb)
-    )
-    vals = tcols[jnp.minimum(src_t // p, mtl - 1), :, src_r, :]
-    vals = jnp.where(own_src[:, None, None], vals, 0)
-
-    rows_data = psum_a(vals, ROW_AXIS)
     dst = jnp.minimum(pos, mglob - 1)
     dst_t, dst_r = dst // nb, dst % nb
     own_dst = (dst_t % p == r) & slot_ok
     dst_loc = jnp.where(own_dst, dst_t // p, mtl)  # mtl -> dropped
-    tcols = tcols.at[dst_loc, :, dst_r, :].set(
+    return (rowperm, (jnp.minimum(src_t // p, mtl - 1), src_r, own_src),
+            (dst_loc, dst_r))
+
+
+def _pp_apply_swaps(t_loc, rowperm, flat, piv_pos, k, p, q, r, c, nt,
+                    s_r, wlr, s_cw, wlsw):
+    """Apply the partial-pivot panel's nb transpositions to the stored
+    rows of the tile stack (:func:`_pp_swap_plan`), restricted to the
+    swap column window, and write the factored panel back into the
+    owning column.  Reads full rows across the swap column window, so
+    any deferred trailing update must be fully applied first.  Returns
+    (t_loc, rowperm)."""
+    mtl, ntl, nb, _ = t_loc.shape
+    dtype = t_loc.dtype
+    kc32 = jnp.asarray(k // q, jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+
+    # ---- apply the nb transpositions to the stored rows (restricted to
+    # the swap column window; the panel column is overwritten below) ----
+    rowperm, (src_i, src_r, own_src), (dst_i, dst_r) = _pp_swap_plan(
+        rowperm, piv_pos, k, p, r, nt, nb, mtl)
+    tcols = lax.dynamic_slice(
+        t_loc, (zero, s_cw, zero, zero), (mtl, wlsw, nb, nb)
+    )
+    vals = tcols[src_i, :, src_r, :]
+    vals = jnp.where(own_src[:, None, None], vals, 0)
+
+    rows_data = psum_a(vals, ROW_AXIS)
+    tcols = tcols.at[dst_i, :, dst_r, :].set(
         rows_data.astype(dtype), mode="drop"
     )
     t_loc = lax.dynamic_update_slice(t_loc, tcols, (zero, s_cw, zero, zero))
@@ -956,22 +986,19 @@ def _pp_apply_swaps(t_loc, rowperm, flat, piv_pos, k, p, q, r, c, nt,
 
 def _pp_panel_and_swaps(t_loc, rowperm, k, p, q, r, c, nt, m_true,
                         s_r, wlr, s_cw, wlsw):
-    """Shared partial-pivot panel factor + cross-shard row-swap machinery
-    (the internal_getrf.cc + internal_swap.cc pair), used by the dense
-    (getrf_pp_dist) and band (gbtrf_band_dist) kernels so the pivot
-    tie-break / sentinel / swap-write logic lives in ONE place — split
-    into ``_pp_panel_factor`` (reads only column k; overlappable under
-    lookahead; blocked in sub-blocks of ``_pp_sub_width(nb)`` columns,
+    """Partial-pivot panel factor + cross-shard row swaps on the tile
+    stack (the internal_getrf.cc + internal_swap.cc pair), as the band
+    kernel (gbtrf_band_dist) runs them: ``_pp_panel_factor`` (reads only
+    column k; blocked in sub-blocks of ``_pp_sub_width(nb)`` columns,
     each factored as a transposed slab) and ``_pp_apply_swaps``
-    (full-row motion) so the dense kernel can land a deferred trailing
-    update between them.
+    (full-row motion).  The dense kernel runs the same factor and swap
+    plan on its local matrix (``_pp_step_rows``).
 
     ``s_r``/``wlr`` restrict the panel's candidate rows to the local slot
-    window [s_r, s_r + wlr) — the band kernel's O(kl)-row panel; the
-    dense kernel passes the full height (0, mtl).  ``s_cw``/``wlsw``
-    restrict the swap application to that local column window (a band
-    row's nonzeros — L history in columns >= g - kl, U fill up to
-    g + kl + ku — live inside it); the dense kernel passes (0, ntl).
+    window [s_r, s_r + wlr) — the band kernel's O(kl)-row panel.
+    ``s_cw``/``wlsw`` restrict the swap application to that local column
+    window (a band row's nonzeros — L history in columns >= g - kl, U
+    fill up to g + kl + ku — live inside it).
 
     Returns (t_loc, rowperm): all nb transpositions applied and the
     factored panel written back into the owning column's window rows."""
@@ -983,6 +1010,158 @@ def _pp_panel_and_swaps(t_loc, rowperm, k, p, q, r, c, nt, m_true,
         )
 
 
+# The dense partial-pivot kernel carries its local tile stack as ONE
+# row-major matrix: local row i*nb + a, column j*nb + c is tile [i, j]'s
+# element (a, c).  A TPU tiles the two most-minor dimensions of an array,
+# so the trailing product (rows, nb) x (nb, cols), the row swap's whole
+# rows and the panel's column block share one layout only in this form;
+# a tile-stack carry would be converted whole to the product's layout,
+# to the swap's and back every k-step.  A value a phase reads from
+# the matrix is materialized (``optimization_barrier``) before the next
+# op overwrites the matrix in place, else the compiler copies the whole
+# matrix to keep the read valid.
+
+
+def _tiles_to_rows(t_loc):
+    """The local tile stack (mtl, ntl, nb, nb) as the local matrix."""
+    mtl, ntl, nb, _ = t_loc.shape
+    return row_major(jnp.transpose(t_loc, (0, 2, 1, 3)).reshape(mtl * nb, ntl * nb))
+
+
+def _rows_to_tiles(a, nb):
+    """The local matrix back as the tile stack."""
+    m, n = a.shape
+    return jnp.transpose(a.reshape(m // nb, nb, n // nb, nb), (0, 2, 1, 3))
+
+
+def _col_block(a, kc, nb):
+    """Local column slot ``kc`` of the matrix, materialized."""
+    return lax.optimization_barrier(
+        lax.dynamic_slice(a, (jnp.zeros_like(kc), kc * nb), (a.shape[0], nb)))
+
+
+def _pp_update_rows(a, payload, excl_kc=None):
+    """The trailing update ``A -= L U`` over the whole local matrix, L the
+    panel column payload as (mtl * nb, nb) and U the panel row payload
+    as (nb, ntl * nb) (both zero outside the trailing rows and
+    columns), as one product.  ``excl_kc`` leaves
+    column slot excl_kc as it is (the narrow refresh updated it)."""
+    pan, urow = payload
+    nb = pan.shape[-1]
+    l = pan.reshape(-1, nb)
+    u = jnp.transpose(urow, (1, 0, 2)).reshape(nb, -1)
+    upd = jnp.dot(l, u, precision=PRECISE).astype(a.dtype)
+    if excl_kc is not None:
+        keep = jnp.arange(a.shape[1]) // nb != excl_kc
+        upd = jnp.where(keep[None, :], upd, 0)
+    return a - upd
+
+
+def _pp_narrow_rows(a, payload, kc):
+    """The deferred update of column slot ``kc`` alone (the panel the
+    step factors next).  Returns (a, the refreshed column block)."""
+    pan, urow = payload
+    nb = pan.shape[-1]
+    col = lax.dynamic_slice(a, (jnp.zeros_like(kc), kc * nb), (a.shape[0], nb))
+    col = lax.optimization_barrier(
+        col - jnp.dot(pan.reshape(-1, nb), urow[kc], precision=PRECISE).astype(a.dtype))
+    return lax.dynamic_update_slice(a, col, (jnp.zeros_like(kc), kc * nb)), col
+
+
+def _pp_swap_rows(a, rowperm, flat, piv_pos, k, p, q, r, c, nt):
+    """``_pp_apply_swaps`` on the local matrix: gather the 2nb whole
+    rows that move, exchange them over mesh axis 'p', scatter them in
+    place, then write the factored panel into the owning column."""
+    nb = flat.shape[1]
+    m = a.shape[0]
+    kc = jnp.asarray(k // q, jnp.int32)
+    rowperm, (src_i, src_r, own_src), (dst_i, dst_r) = _pp_swap_plan(
+        rowperm, piv_pos, k, p, r, nt, nb, m // nb)
+    vals = jnp.where(own_src[:, None], a[src_i * nb + src_r], 0)
+    rows_data = psum_a(vals, ROW_AXIS)
+    a = a.at[dst_i * nb + dst_r].set(rows_data.astype(a.dtype), mode="drop")
+    col = _col_block(a, kc, nb)
+    return lax.dynamic_update_slice(
+        a, jnp.where(c == k % q, flat, col), (jnp.zeros_like(kc), kc * nb)
+    ), rowperm
+
+
+def _pp_row_solve(a, flat, k, p, q, i_log, j_log, r, c):
+    """Row solve U[k, j] = L_kk^{-1} A[k, j] on row slot k // p of the
+    local matrix, and the step's two panel broadcasts (the
+    ``_nopiv_panel(panel_done=True)`` phase).  ``flat`` is the factored
+    panel, so the diagonal tile and the column payload come from it.
+    Returns (a, (pan, urow)), the payloads in the tile stack's shapes."""
+    nb = flat.shape[1]
+    ntl = j_log.shape[0]
+    kr = jnp.asarray(k // p, jnp.int32)
+    pan = flat.reshape(-1, nb, nb)
+    luk = bcast_owner_tile(lax.dynamic_index_in_dim(pan, kr, keepdims=False),
+                           k, p, q)
+    prow = lax.optimization_barrier(
+        lax.dynamic_slice(a, (kr * nb, jnp.zeros_like(kr)), (nb, a.shape[1])))
+    usolved = lax.linalg.triangular_solve(
+        jnp.tril(luk, -1) + jnp.eye(nb, dtype=luk.dtype), prow,
+        left_side=True, lower=True, transpose_a=False, unit_diagonal=True,
+    )
+    right = j_log > k
+    newrow = jnp.where(jnp.repeat(right, nb)[None, :], usolved, prow)
+    mine_r = r == k % p
+    a = lax.dynamic_update_slice(
+        a, jnp.where(mine_r, newrow, prow), (kr * nb, jnp.zeros_like(kr)))
+    below = (i_log > k)[:, None, None]
+    rtiles = jnp.transpose(newrow.reshape(nb, ntl, nb), (1, 0, 2))
+    own = (jnp.where(below & (c == k % q), pan, 0),
+           jnp.where(right[:, None, None] & mine_r, rtiles, 0))
+    with phase_scope("bcast", k):
+        return a, _nopiv_panel_bcast(own, k, p, q)
+
+
+def _pp_step_rows(a, rowperm, k, p, q, r, c, i_log, j_log, nt, m_true):
+    """One strict-schedule step of the partial-pivot LU on the local
+    matrix: panel factor, row swaps, row solve and panel broadcasts, then
+    the whole trailing update."""
+    nb = a.shape[0] // i_log.shape[0]
+    a = row_major(a)
+    with phase_scope("panel", k):
+        col = _col_block(a, jnp.asarray(k // q, jnp.int32), nb)
+        flat, piv_pos = _pp_factor_panel(
+            col.reshape(-1, nb, nb), k, p, q, r, c, nt, m_true, jnp.int32(0))
+    with phase_scope("swap", k):
+        a, rowperm = _pp_swap_rows(a, rowperm, flat, piv_pos, k, p, q, r, c, nt)
+    with phase_scope("panel", k):
+        a, payload = _pp_row_solve(a, flat, k, p, q, i_log, j_log, r, c)
+    with phase_scope("bulk", k):
+        return row_major(_pp_update_rows(a, payload)), rowperm
+
+
+def _pp_growth(g, a, i_log, j_log, m_true):
+    """The NumMonitor growth gauge ``g`` with the local matrix ``a``
+    sampled (None, the gauge off, stays None)."""
+    if g is None:
+        return None
+    nb = a.shape[0] // i_log.shape[0]
+    return jnp.maximum(g, _wabs_max(a, i_log, j_log, nb, m_true, g.dtype))
+
+
+def _pp_strict_steps(t_loc, rowperm, g, k0, k1, p, q, r, c, i_log, j_log, nt,
+                     m_true):
+    """Steps [k0, k1) of the strict schedule (``_pp_step_rows``) on the
+    tile stack ``t_loc``, carried as the local matrix in between, the
+    growth gauge ``g`` sampled at each step's entry: ``getrf_pp_dist`` at
+    lookahead 0 and the checkpointed segments (``ft.ckpt``) run it.
+    Returns (t_loc, rowperm, g)."""
+    def step(k, carry):
+        a, rowperm, g = carry
+        g = _pp_growth(g, a, i_log, j_log, m_true)
+        a, rowperm = _pp_step_rows(a, rowperm, k, p, q, r, c, i_log, j_log, nt, m_true)
+        return a, rowperm, g
+
+    with audit_scope(k1 - k0):
+        a, rowperm, g = lax.fori_loop(k0, k1, step, (_tiles_to_rows(t_loc), rowperm, g))
+    return _rows_to_tiles(a, t_loc.shape[2]), rowperm, g
+
+
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8))
 def _pp_jit(at, mesh, p, q, nt, m_true, la, bi, nm=False):
     spec = P(ROW_AXIS, COL_AXIS)
@@ -991,88 +1170,48 @@ def _pp_jit(at, mesh, p, q, nt, m_true, la, bi, nm=False):
         mtl, ntl, nb, _ = t_loc.shape
         dtype = t_loc.dtype
         r, c, i_log, j_log = local_indices(p, q, mtl, ntl)
-        mglob = nt * nb
-        zero = jnp.zeros((), jnp.int32)
         rdt = num_gauge_dtype(dtype)
-        if nm:
-            amax0 = _wabs_max(t_loc, i_log, j_log, nb, m_true, rdt)
-            g0 = amax0
-
-        def probe(t_loc, g):
-            return jnp.maximum(
-                g, _wabs_max(t_loc, i_log, j_log, nb, m_true, rdt))
-
-        rowperm0 = jnp.arange(mglob)
+        amax0 = _wabs_max(t_loc, i_log, j_log, nb, m_true, rdt) if nm else None
+        rowperm = jnp.arange(nt * nb)
         if la <= 0:
-            def step(k, carry):
-                if nm:
-                    t_loc, rowperm, g = carry
-                    g = probe(t_loc, g)
-                else:
-                    t_loc, rowperm = carry
-                t_loc, rowperm = _pp_panel_and_swaps(
-                    t_loc, rowperm, k, p, q, r, c, nt, m_true,
-                    zero, mtl, zero, ntl,
-                )
-                # ---- shared tail: row solve + trailing update ----
-                t_loc = _nopiv_step(
-                    t_loc, k, p, q, i_log, j_log, r, c, panel_done=True
-                )
-                return (t_loc, rowperm, g) if nm else (t_loc, rowperm)
-
-            init = (t_loc, rowperm0, g0) if nm else (t_loc, rowperm0)
-            with audit_scope(nt):
-                out = lax.fori_loop(0, nt, step, init)
-            if nm:
-                t_loc, rowperm, g = out
-            else:
-                t_loc, rowperm = out
+            t_loc, rowperm, g = _pp_strict_steps(
+                t_loc, rowperm, amax0, 0, nt, p, q, r, c, i_log, j_log, nt, m_true)
         else:
             # Lookahead (getrf.cc's panel/update overlap): refresh the
             # panel column, factor it with pivoting (its collectives are
-            # independent of the deferred bulk einsum), land the rest of
+            # independent of the deferred bulk product), land the rest of
             # the deferred update, then swap full rows, row-solve, and
-            # defer this step's own trailing gemm.
+            # defer this step's own trailing product.
             def step(k, carry):
-                if nm:
-                    t_loc, rowperm, pl, g = carry
-                    g = probe(t_loc, g)
-                else:
-                    t_loc, rowperm, pl = carry
+                a, rowperm, pl, g = carry
+                g = _pp_growth(g, a, i_log, j_log, m_true)
+                a = row_major(a)
+                kc = jnp.asarray(k // q, jnp.int32)
                 with phase_scope("bulk", k):
-                    t_loc = _nopiv_narrow(t_loc, pl, k, p, q, with_row=False)
+                    a, col = _pp_narrow_rows(a, pl, kc)
                 with phase_scope("panel", k):
-                    flat, piv_pos = _pp_panel_factor(
-                        t_loc, k, p, q, r, c, nt, m_true, zero, mtl
-                    )
+                    flat, piv_pos = _pp_factor_panel(
+                        col.reshape(mtl, nb, nb), k, p, q, r, c, nt, m_true,
+                        jnp.int32(0))
                 with phase_scope("bulk", k):
-                    t_loc = _nopiv_bulk(t_loc, pl, excl_kc=k // q)
+                    a = _pp_update_rows(a, pl, excl_kc=kc)
                 with phase_scope("swap", k):
-                    t_loc, rowperm = _pp_apply_swaps(
-                        t_loc, rowperm, flat, piv_pos, k, p, q, r, c, nt,
-                        zero, mtl, zero, ntl,
-                    )
+                    a, rowperm = _pp_swap_rows(
+                        a, rowperm, flat, piv_pos, k, p, q, r, c, nt)
                 with phase_scope("panel", k):
-                    t_loc, pl_new = _nopiv_panel(
-                        t_loc, k, p, q, i_log, j_log, r, c, panel_done=True
-                    )
-                return ((t_loc, rowperm, pl_new, g) if nm
-                        else (t_loc, rowperm, pl_new))
+                    a, pl = _pp_row_solve(a, flat, k, p, q, i_log, j_log, r, c)
+                return row_major(a), rowperm, pl, g
 
             zero_pl = (
                 jnp.zeros((mtl, nb, nb), dtype),
                 jnp.zeros((ntl, nb, nb), dtype),
             )
-            init = ((t_loc, rowperm0, zero_pl, g0) if nm
-                    else (t_loc, rowperm0, zero_pl))
             with audit_scope(nt):
-                out = lax.fori_loop(0, nt, step, init)
-            if nm:
-                t_loc, rowperm, pl, g = out
-            else:
-                t_loc, rowperm, pl = out
+                a, rowperm, pl, g = lax.fori_loop(
+                    0, nt, step, (_tiles_to_rows(t_loc), rowperm, zero_pl, amax0))
             with phase_scope("bulk", nt - 1):
-                t_loc = _nopiv_bulk(t_loc, pl)  # drain the last deferred gemm
+                a = _pp_update_rows(a, pl)  # drain the last deferred product
+            t_loc = _rows_to_tiles(a, nb)
         info = _lu_info_dist(t_loc, i_log, j_log, nt, nb)
         if nm:
             gz = _lu_growth_out(
@@ -1083,11 +1222,9 @@ def _pp_jit(at, mesh, p, q, nt, m_true, la, bi, nm=False):
     out_specs = (spec, P(ROW_AXIS), P(ROW_AXIS, COL_AXIS))
     if nm:
         out_specs = out_specs + (P(ROW_AXIS, COL_AXIS),)
-    # update pinned xla — see _tntpiv_jit.  The program's ops sit under
-    # the ``getrf`` stage scope, each step's under its phase (panel,
-    # swap, bcast, bulk)
-    with bcast_impl_scope(bi), update_impl_scope("xla"), \
-            jax.named_scope("getrf"):
+    # The program's ops sit under the ``getrf`` stage scope, each step's
+    # under its phase (panel, swap, bcast, bulk)
+    with bcast_impl_scope(bi), jax.named_scope("getrf"):
         out = shard_map_compat(
             kernel,
             mesh=mesh,

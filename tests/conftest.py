@@ -64,3 +64,49 @@ def loop_view_copies(hlo: str, min_dim: int) -> dict:
                    if (m := re.match(r"\S+ = (.*?) copy(?:-start)?\(", l))
                    and shape in m.group(1)]
     return out
+
+
+def loop_copies_at_least(hlo: str, elems: int) -> dict:
+    """{body: copies} for every while loop of compiled HLO text whose
+    carry holds an array of at least ``elems`` elements; the copies are
+    the ``copy`` / ``copy-start`` instructions with a result of at least
+    ``elems`` elements, in the loop body and in every computation it
+    calls (nested loops, branches, fusions).  Elements are counted, not
+    shapes matched, so a copy of the same data in another shape counts."""
+    import math
+    import re
+
+    def size(text):
+        return max([math.prod(int(d) for d in dims.split(",") if d)
+                    for dims in re.findall(r"[a-z]\d+\[([\d,]*)\]", text)] + [0])
+
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line.strip())
+    calls = re.compile(r"(?:calls|to_apply|body|condition|branch_computations"
+                       r"|true_computation|false_computation)=\{?([%\w.\-, ]+)")
+    out = {}
+    for body in set(re.findall(r"body=%?([\w.\-]+)", hlo)):
+        param = next(l for l in comps[body] if "parameter(0)" in l)
+        if size(param.split(" parameter(0)")[0]) < elems:
+            continue
+        seen, todo, found = set(), [body], []
+        while todo:
+            name = todo.pop()
+            if name in seen or name not in comps:
+                continue
+            seen.add(name)
+            for l in comps[name]:
+                m = re.match(r"\S+ = (.*?) copy(?:-start)?\(", l)
+                if m and size(m.group(1)) >= elems:
+                    found.append(re.split(r", (?:metadata|backend_config)=", l)[0])
+                todo.extend(n.strip().lstrip("%") for c in calls.findall(l)
+                            for n in c.split(","))
+        out[body] = found
+    return out

@@ -151,6 +151,31 @@ def test_pp_panel_slab_row_major_for_v5e(topo, on_tpu):
         assert set(copied(text)) <= {f"f32[{ib},1]"}, body  # one slab column
 
 
+@pytest.mark.parametrize("la", [0, 1])
+def test_pp_loop_copies_no_local_matrix_for_v5e(la, topo, on_tpu):
+    """The mesh LU's k-loop on a described 2x2 v5e, as ``gesv_mesh``
+    runs it (lookahead 1) and at lookahead 0: its body copies nothing as
+    large as the local matrix.  Carried as a tile stack, each step
+    converted the whole stack to the trailing product's layout, to the
+    row swap's and back (three copies of the local matrix per step)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from conftest import loop_copies_at_least
+    from slate_tpu import parallel
+    from slate_tpu.parallel.dist_lu import _pp_jit
+    from slate_tpu.parallel.mesh import COL_AXIS, ROW_AXIS
+
+    mesh = parallel.make_mesh(2, 2, devices=topo.devices)
+    nt = 16
+    t = jax.ShapeDtypeStruct((nt, nt, NB, NB), jnp.float32,
+                             sharding=NamedSharding(mesh, P(ROW_AXIS, COL_AXIS)))
+    hlo = _pp_jit.lower(t, mesh, 2, 2, nt, nt * NB, la, "auto").compile().as_text()
+    local = (nt // 2 * NB) ** 2
+    copies = loop_copies_at_least(hlo, local)
+    assert len(copies) == 1, copies  # the k-loop, the one loop carrying the matrix
+    assert not any(copies.values()), copies
+
+
 def test_potrf_scan_carry_in_place_for_v5e(one_chip, on_tpu):
     """The scanned Cholesky's loop carry stays in place on a TPU: no
     whole-view copy in any bucket's loop body.  Left to layout

@@ -6,16 +6,20 @@ per sub-block.  Every caller of the panel is checked against NumPy's
 partial pivoting on a 2x2 mesh with n not a multiple of nb; the panel
 alone is checked, at several sub-block widths, against its own unblocked
 form (``ib == nb``); and the column loop is checked to hold nothing as
-large as the panel.
+large as the panel.  The dense kernel's step on its local matrix is
+checked against the same step on the tile stack, as the kernel carried
+it before.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from slate_tpu.ft import ckpt
+from slate_tpu.ops.pallas_ops import update_impl_scope
 from slate_tpu.parallel import from_dense, make_mesh, to_dense
 from slate_tpu.parallel import dist_lu
 from slate_tpu.parallel.comm import local_indices, shard_map_compat
@@ -84,6 +88,57 @@ def test_blocked_panel_checkpointed_is_bitwise_the_plain_factor():
     np.testing.assert_array_equal(np.asarray(to_dense(got[0])),
                                   np.asarray(to_dense(ref[0])))
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
+
+
+def _tile_stack_factor(d):
+    """``getrf_pp_dist`` as its step ran on the tile stack (strict
+    schedule): the shared panel and row swaps, then the no-pivot row
+    solve and trailing einsum.  Returns (LU tiles, perm, info)."""
+    def kernel(t):
+        mtl, ntl, nb, _ = t.shape
+        r, c, i_log, j_log = local_indices(2, 2, mtl, ntl)
+        zero = jnp.zeros((), jnp.int32)
+
+        def step(k, carry):
+            t, rowperm = dist_lu._pp_panel_and_swaps(
+                *carry, k, 2, 2, r, c, d.nt, d.m, zero, mtl, zero, ntl)
+            t = dist_lu._nopiv_step(t, k, 2, 2, i_log, j_log, r, c, panel_done=True)
+            return t, rowperm
+
+        t, rowperm = lax.fori_loop(0, d.nt, step, (t, jnp.arange(d.nt * nb)))
+        info = dist_lu._lu_info_dist(t, i_log, j_log, d.nt, nb)
+        return t, rowperm[None], info[None, None]
+
+    spec = P(ROW_AXIS, COL_AXIS)
+    with update_impl_scope("xla"):
+        lut, perm, info = jax.jit(shard_map_compat(
+            kernel, mesh=d.mesh, in_specs=(spec,),
+            out_specs=(spec, P(ROW_AXIS), spec), check_vma=False))(d.tiles)
+    return lut, perm[0], jnp.max(info)
+
+
+@pytest.mark.parametrize("la", [0, 1])
+def test_local_matrix_step_matches_the_tile_stack_step(la):
+    """The dense kernel carries its local tile stack as one row-major
+    matrix: the same pivots and info as the tile-stack step, and packed
+    LU factors within the rounding bound of a length-n dot,
+    gamma_{n+1} (|PA| + |L| |U|), divided by |u_jj| below the diagonal."""
+    a = _operand("dense")
+    d = from_dense(jnp.asarray(a), _mesh(), NB, diag_pad_one=True)
+    lu, perm, info = dist_lu.getrf_pp_dist(d, lookahead=la)
+    ref_t, ref_perm, ref_info = _tile_stack_factor(d)
+    assert int(info) == int(ref_info) == 0
+    np.testing.assert_array_equal(np.asarray(perm), np.asarray(ref_perm))
+    got = np.asarray(to_dense(lu))[:N, :N]
+    ref = np.asarray(to_dense(d.__class__(
+        tiles=ref_t, m=d.m, n=d.n, nb=NB, mesh=d.mesh, diag_pad=True)))[:N, :N]
+    l, u = np.tril(ref, -1) + np.eye(N), np.triu(ref)
+    eps = np.finfo(ref.dtype).eps
+    gamma = (N + 1) * eps / (1 - (N + 1) * eps)
+    bound = gamma * (np.abs(a[np.asarray(perm)[:N]]) + np.abs(l) @ np.abs(u))
+    bound = np.where(np.tri(N, k=-1, dtype=bool), bound / np.abs(np.diag(u))[None, :], bound)
+    err = np.abs(got - ref)
+    assert (err <= bound).all(), float((err - bound).max())
 
 
 def _panel(tiles, nt, m, k, ib):
