@@ -131,11 +131,15 @@ def _use_pallas(a: jax.Array, b: jax.Array) -> bool:
 
 
 # Ozaki dispatch thresholds (measured win region; see matmul() comment).
-# Round-4 remeasure with the pair-epilogue: Ozaki beats XLA's f32-pair
-# emulation at EVERY shape with min dim >= 1024 and >= 2048^3 work
-# (2048^3: 180 vs 169 GF/s; (8192,1024,8192): 1145 vs 664; 4096^3:
-# 1106 vs 866; (8192,4096,8192): 2674 vs 1610; 8192^3: ~4700 vs ~1400),
-# so the gate now encodes that boundary.
+# An older remeasure with the pair-epilogue put Ozaki ahead of XLA's
+# f32-pair emulation at every shape with min dim >= 1024 and >= 2048^3
+# work (2048^3: 180 vs 169 GF/s; (8192,1024,8192): 1145 vs 664; 4096^3:
+# 1106 vs 866; (8192,4096,8192): 2674 vs 1610), so the gate encodes that
+# boundary.  At 8192^3 on a v5e, operands (u - 1/2) exp(2g) whose rows
+# span many binades, one call each (PERF.md section 6): Ozaki with the 11
+# slices the operands ask for 0.294 s (3737 GF/s), XLA's float64 dot
+# 0.467 s (2355 GF/s), the two products 8.8e-18 apart in the gemm
+# tester's normwise form.
 _OZAKI_MIN_ELEMS = 2048**3
 _OZAKI_MIN_DIM = 1024
 
@@ -183,7 +187,8 @@ def matmul(
     region (huge square products, see the gate below).  Pass
     ``precision=Precision.Emulated`` to force emulation everywhere.
     Fast-tier f64 uses the 6-slice split (~2^-33 measured accuracy) when
-    Ozaki engages."""
+    Ozaki engages; every other tier lets the operands' exponent spread
+    choose the slice count (``ozaki.slice_count``)."""
     if precision is None:
         precision = Precision.Highest if precise else Precision.Fast
     dt = jnp.result_type(a.dtype, b.dtype)
@@ -203,7 +208,8 @@ def matmul(
     ):
         from .ozaki import matmul_c128, matmul_f64
 
-        n_slices = 6 if precision == Precision.Fast else 9
+        # Fast: the fixed 6-slice split; otherwise the operands choose
+        n_slices = 6 if precision == Precision.Fast else None
         if dt == jnp.float64:
             return matmul_f64(a.astype(dt), b.astype(dt), n_slices=n_slices)
         if dt == jnp.complex128:

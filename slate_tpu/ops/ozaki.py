@@ -25,11 +25,20 @@ concatenated contraction axis ([qa_0..qa_s] against [qb_s..qb_0]), so the
 whole product costs S(S+1)/2 unit-GEMM flops — 45 for S=9, i.e. ~6 TF/s of
 f64-equivalent throughput at the v5e int8 peak vs 1.3 TF/s emulated.
 
-Accuracy: the dropped t+u >= S tail is ~ S k 2^(-6S) relative to the row
-scale — below the sqrt(k)*eps backward error of a true f64 GEMM for S=9.
-Elements with |x| outside the f32 exponent range (|x| > ~1e38 or rows whose
-max is < ~1e-38) are not supported (the hi/lo split degenerates); scale
-your data, as you would for any f32-adjacent pipeline.
+Accuracy: the dropped t+u >= S tail is bounded relative to the row and
+column scales, not to the entries, so operands whose rows span many
+binades need more slices than N(0,1) data.  A caller that names no count
+gets the smallest S in [8, 12] that the operands' own exponents and norms
+show to be enough (``slice_count``, derived there); callers that pass
+``n_slices`` keep their count.  Elements with |x| outside the f32 exponent
+range (|x| > ~1e38 or rows whose max is < ~1e-38) are not supported (the
+hi/lo split degenerates); scale your data, as you would for any
+f32-adjacent pipeline.
+
+Scopes: the dispatch product runs under the stage ``ozaki`` with the
+phases ``split`` (hi/lo, digit planes, the slice rule), ``products`` (the
+int8 contractions) and ``accumulate`` (the pair cascade and the f64
+epilogue), op metadata only.
 
 References (design provenance, no code taken): the reference SLATE has no
 f64-emulation tier — its f64 path is cuBLAS DGEMM dispatched from
@@ -41,6 +50,7 @@ above.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -55,6 +65,14 @@ _D = _W + 1     # grid step in bits; hi+lo digit sums are <= 2^_D = 64
 # (s+1) * k * 2^(2*_D) < 2^31 with s+1 <= 16  =>  k < 2^(31-12-4) = 2^15.
 _K_CHUNK = 8192
 _DEFAULT_SLICES = 9  # 6*9 = 54 bits > f64's 53-bit significand
+# The count ``slice_count`` chooses lies in [_MIN_SLICES, _MAX_SLICES]; the
+# split makes the top count's planes once and the products run as many as
+# the operands need.
+_MIN_SLICES, _MAX_SLICES = 8, 12
+# The gemm tester's limit is 3 eps (sqrt(k) + 2) ||A|| ||B||; the rule's
+# bound on the dropped digits may take this share of it.
+_TESTER_EPS = 3 * 2.0**-52
+_RULE_SHARE = 0.5
 
 
 def _exp2i(e: Array) -> Array:
@@ -98,9 +116,15 @@ def _slice_digits(hi: Array, lo: Array, e: Array, n_slices: int) -> Array:
             # libm approximation and its off-by-one-ulp results cascade
             # through the residual recurrence
             shift = jnp.float32(2.0 ** (_D * (t + 1)))
-            # floor is exact; first digit reaches +-64 (|r| < 1), later
-            # ones +-32 — the 2^(2*_D) overflow bound assumes the 64
-            q = jnp.floor(r * shift + 0.5)
+            # the nearest integer, halves up, from the exact fraction
+            # y - floor(y): floor(y + 0.5) rounds y + 0.5 in f32 first,
+            # which takes y = 1/2 - 2^-25 to 1, and the residual below then
+            # needs a bit more than f32 holds
+            y = r * shift
+            f = jnp.floor(y)
+            q = f + (y - f >= 0.5).astype(jnp.float32)
+            # |y - q| <= 1/2, so the residual is exact; the first digit
+            # reaches +-64 (|r| < 1), later ones +-32
             r = r - q / shift
             digs.append(q.astype(jnp.int8))
         return jnp.stack(digs)
@@ -127,35 +151,151 @@ def split_rows(x: Array, n_slices: int = _DEFAULT_SLICES, e: Array | None = None
     linalg/chol._potrf_ll_ozaki).  A bound looser than the row max costs
     top digit planes (log2(bound/rowmax) bits); add a slice to compensate.
     """
-    hi, lo = _split_f32(x)
-    if e is None:
-        e = _row_exp(jnp.max(jnp.abs(hi), axis=1, keepdims=True))
-    q = _slice_digits(hi, lo, e, n_slices)
+    q, e, _ = _split_rows_hi(x, n_slices, e)
     return q, e
 
 
-@functools.partial(jax.jit, static_argnames=("n_slices",))
-def matmul_f64(a: Array, b: Array, n_slices: int = _DEFAULT_SLICES) -> Array:
+def _split_rows_hi(x: Array, n_slices: int, e: Array | None = None):
+    """``split_rows`` that also returns the f32 high part of ``x``."""
+    hi, lo = _split_f32(x)
+    if e is None:
+        e = _row_exp(jnp.max(jnp.abs(hi), axis=1, keepdims=True))
+    return _slice_digits(hi, lo, e, n_slices), e, hi
+
+
+# Open ``slice_record`` lists, innermost last.
+_SLICE_RECORDS: list[list] = []
+
+
+@contextlib.contextmanager
+def slice_record():
+    """Yield a list that receives the slice count of every product whose
+    count ``slice_count`` chose while the block is open (int32 scalars of
+    the caller's trace: tracers under ``jit``).  A jitted caller returns
+    them beside its result, so a count is read from the program that ran
+    it and never computed twice."""
+    rec: list = []
+    _SLICE_RECORDS.append(rec)
+    try:
+        yield rec
+    finally:
+        _SLICE_RECORDS.pop()
+
+
+def matmul_f64(a: Array, b: Array, n_slices: int | None = None) -> Array:
     """f64-accurate ``a @ b`` computed as Ozaki-split int8 GEMMs.
 
-    a: (m, k) f64, b: (k, n) f64.  n_slices=9 gives full f64 accuracy;
-    n_slices=6 is a ~1.7x faster variant at ~f32-pair (2^-36) accuracy.
+    a: (m, k) f64, b: (k, n) f64.  With ``n_slices`` None the operands
+    choose the count (``slice_count``) and an open ``slice_record`` gets
+    it; ``n_slices=9`` is the fixed full-accuracy count for N(0,1)-like
+    data, ``n_slices=6`` a ~1.7x faster variant at ~f32-pair (2^-36)
+    accuracy.
     """
     if a.dtype != jnp.float64 or b.dtype != jnp.float64:
         raise TypeError(f"matmul_f64 requires f64 operands, got {a.dtype}, {b.dtype}")
-    qa, ea = split_rows(a, n_slices)
-    qb, eb = split_rows(b.T, n_slices)
-    return matmul_planes(qa, ea, qb, eb)
+    c, s = _matmul_f64(a, b, n_slices=n_slices)
+    if n_slices is None and _SLICE_RECORDS:
+        _SLICE_RECORDS[-1].append(s)
+    return c
 
 
-def matmul_planes(qa: Array, ea: Array, qb: Array, eb: Array) -> Array:
+@functools.partial(jax.jit, static_argnames=("n_slices",))
+def _matmul_f64(a: Array, b: Array, n_slices: int | None) -> tuple[Array, Array]:
+    """(a @ b, the slice count the products ran)."""
+    from ..parallel.comm import phase_scope
+
+    with jax.named_scope("ozaki"):
+        with phase_scope("split"):
+            top = _MAX_SLICES if n_slices is None else n_slices
+            qa, ea, hi_a = _split_rows_hi(a, top)
+            qb, eb, hi_bt = _split_rows_hi(b.T, top)
+            s = (jnp.int32(n_slices) if n_slices is not None
+                 else slice_count(hi_a, ea, hi_bt, eb))
+        c = matmul_planes(qa, ea, qb, eb, None if n_slices is not None else s)
+    return c, s
+
+
+def _slice_bound_eps(n_slices: int) -> float:
+    """eps_S of ``slice_count``: the dropped digits of one term of the
+    k-sum, relative to its row and column scales."""
+    return (n_slices + 2) * 2.0 ** (-_D * n_slices)
+
+
+def slice_count(hi_a: Array, ea: Array, hi_bt: Array, eb: Array) -> Array:
+    """The smallest slice count in [_MIN_SLICES, _MAX_SLICES] whose bound
+    on the dropped digits meets ``_RULE_SHARE`` of the gemm tester's limit
+    3 u (sqrt(k) + 2) ||A||_inf ||B||_inf (u = 2^-52), or the top count
+    where none does; an int32 scalar computed on the device from the
+    split's row exponents ``ea`` (m, 1) of A and ``eb`` (n, 1) of B^T and
+    the f32 high parts ``hi_a`` (m, k) and ``hi_bt`` (n, k).
+
+    The bound.  Row i of A is scaled by 2^-ea_i and column j of B by
+    2^-eb_j, so every scaled entry lies below 1.  A scaled entry is the
+    digit sum  sum_t d_t 2^(-6(t+1)) + r  with |d_t| <= 64, so each digit
+    term is at most 2^(-6t), and the split's remainder after S digits
+    (half a last-digit unit for each of hi and lo) is at most 2^(-6S).
+    The product keeps the digit pairs t + u < S.  Of one term a_il b_lj of
+    the k-sum it drops, relative to 2^(ea_i + eb_j):
+
+      - the pairs t + u = s, S <= s <= 2S - 2: at most 2S - 1 - s of them,
+        each at most 2^(-6s); together at most (S - 1) 2^(-6S) / (1 - 2^-6);
+      - the two remainders times the other operand: at most
+        2^(-6S) + 2^(-6S) (1 + 2^(-6S));
+
+    together at most  eps_S = (S + 2) 2^(-6S).  Added without cancellation
+    over the k terms that is  k eps_S 2^(ea_i + eb_j).  The digits are
+    rounded to nearest, so what is dropped has mean zero, and the
+    probabilistic model of rounding error (Higham and Mary, SIAM J. Sci.
+    Comput. 41, 2019) takes  sqrt(k) eps_S 2^(ea_i + eb_j)  instead: the
+    same sqrt(k) growth the tester's normalisation assumes.  Summed over j
+    and maximised over i,
+
+      ||C - C_S||_inf <= sqrt(k) eps_S max_i 2^ea_i sum_j 2^eb_j.
+
+    Each dropped term is at most its bound and in practice far below it
+    (a digit spreads over its range rather than sitting at 64, and most
+    entries lie binades below their row's maximum): on seeded operands
+    (u - 1/2) exp(phi g), n 512, phi 0 to 3, the bound sits 20-100x above
+    the truncation error measured against numpy, so the share 1/2 keeps
+    the product's error at least ten times under the tester's limit
+    (tests/test_ozaki.py checks it).  The rule gives S = 8 for phi 0 and
+    11 for phi 2, where the row maxima stand about 2^10 above the typical
+    entry.
+
+    The norms are summed in f32 after scaling by the largest exponent, so
+    nothing overflows (every scaled entry is below 1); an all-zero operand
+    gives the bottom count."""
+    k = hi_a.shape[1]
+    ea_max, eb_max = jnp.max(ea), jnp.max(eb)
+    sa, sb = _exp2i(-ea_max), _exp2i(-eb_max)
+    norm_a = jnp.max(jnp.sum(jnp.abs(hi_a) * sa, axis=1))   # ||A||_inf 2^-ea_max
+    norm_b = jnp.max(jnp.sum(jnp.abs(hi_bt) * sb, axis=0))  # ||B||_inf 2^-eb_max
+    col_scales = jnp.sum(_exp2i(jnp.maximum(eb - eb_max, -126)))  # sum_j 2^eb_j 2^-eb_max
+    root = float(k) ** 0.5
+    limit = _RULE_SHARE * _TESTER_EPS * (root + 2.0) / root
+    denom = norm_a * norm_b
+    ratio = jnp.where(denom > 0, col_scales / jnp.where(denom > 0, denom, 1.0), 0.0) / limit
+    misses = [ratio * jnp.float32(_slice_bound_eps(c)) > 1.0
+              for c in range(_MIN_SLICES, _MAX_SLICES)]
+    return jnp.int32(_MIN_SLICES) + sum(m.astype(jnp.int32) for m in misses)
+
+
+def matmul_planes(qa: Array, ea: Array, qb: Array, eb: Array,
+                  n_slices: Array | None = None) -> Array:
     """f64 product A @ B^T from pre-split digit planes (split_rows of A
     (m, k) and of B^T (n, k)).  This is the reuse entry point: operands
     whose planes are cached (factorization panels, stationary matrices)
     skip the O(S m k) digit split and the f64 hi/lo subtract on every
-    reuse — the panel-update schedule in linalg/chol rides this."""
-    n_slices, m, k = qa.shape
-    assert qb.shape[0] == n_slices and qb.shape[2] == k, (qa.shape, qb.shape)
+    reuse — the panel-update schedule in linalg/chol rides this.
+
+    ``n_slices`` None runs every diagonal the planes have.  A traced count
+    (``slice_count``'s, at least ``_MIN_SLICES``) runs the diagonals below
+    it: those from ``_MIN_SLICES`` up each sit under a ``lax.cond``, so
+    one program serves every count and runs S(S+1)/2 unit products."""
+    from ..parallel.comm import phase_scope
+
+    top, m, k = qa.shape
+    assert qb.shape[0] == top and qb.shape[2] == k, (qa.shape, qb.shape)
     n = qb.shape[1]
 
     nchunks = -(-k // _K_CHUNK)
@@ -177,9 +317,6 @@ def matmul_planes(qa: Array, ea: Array, qb: Array, eb: Array) -> Array:
             acc = acc + ci if nchunks > 1 else ci
         return acc
 
-    # 2^ea, 2^eb each fit f32; apply as two exact f64 multiplies
-    sa = _exp2i(ea).astype(jnp.float64)          # (m, 1)
-    sb = _exp2i(eb).astype(jnp.float64).T        # (1, n)
     # Weighted-term accumulation in native f32 PAIRS (double-single with a
     # TwoSum cascade), not in emulated f64: each int32 term splits exactly
     # into two f32 components (|term| < 2^28.2, so hi carries the top 24
@@ -188,24 +325,39 @@ def matmul_planes(qa: Array, ea: Array, qb: Array, eb: Array) -> Array:
     # row/column scales touch emulated-f64 arithmetic (3 ops/element vs
     # ~2 n_slices before; measured +6-8% end-to-end at the 8192-class
     # shapes, residual 9.2e-15 at n=1024 vs the 1.1e-11 gate).
-    hi = jnp.zeros((m, n), jnp.float32)
-    lo = jnp.zeros((m, n), jnp.float32)
-    for s in range(n_slices):
-        # digit t carries weight 2^(-D(t+1)): the s = t+u diagonal carries
-        # 2^(-D(s+2))
-        w = jnp.float32(2.0 ** (-_D * (s + 2)))
-        t = diag_term(s)
-        th = t.astype(jnp.float32)
-        tl = (t - th.astype(jnp.int32)).astype(jnp.float32)
-        for x in (th * w, tl * w):
-            # TwoSum(hi, x) with the error folded into lo
-            ssum = hi + x
-            bb = ssum - hi
-            err = (hi - (ssum - bb)) + (x - bb)
-            hi = ssum
-            lo = lo + err
-    out = hi.astype(jnp.float64) + lo.astype(jnp.float64)
-    return out * sa * sb
+    def add_diag(pair, s):
+        with phase_scope("products"):
+            t = diag_term(s)
+        with phase_scope("accumulate"):
+            hi, lo = pair
+            # digit t carries weight 2^(-D(t+1)): the s = t+u diagonal
+            # carries 2^(-D(s+2))
+            w = jnp.float32(2.0 ** (-_D * (s + 2)))
+            th = t.astype(jnp.float32)
+            tl = (t - th.astype(jnp.int32)).astype(jnp.float32)
+            for x in (th * w, tl * w):
+                # TwoSum(hi, x) with the error folded into lo
+                ssum = hi + x
+                bb = ssum - hi
+                err = (hi - (ssum - bb)) + (x - bb)
+                hi = ssum
+                lo = lo + err
+        return hi, lo
+
+    pair = (jnp.zeros((m, n), jnp.float32), jnp.zeros((m, n), jnp.float32))
+    for s in range(top):
+        if n_slices is None or s < _MIN_SLICES:
+            pair = add_diag(pair, s)
+        else:
+            pair = lax.cond(s < n_slices, functools.partial(add_diag, s=s),
+                            lambda p: p, pair)
+    with phase_scope("accumulate"):
+        hi, lo = pair
+        # 2^ea, 2^eb each fit f32; apply as two exact f64 multiplies
+        sa = _exp2i(ea).astype(jnp.float64)          # (m, 1)
+        sb = _exp2i(eb).astype(jnp.float64).T        # (1, n)
+        out = hi.astype(jnp.float64) + lo.astype(jnp.float64)
+        return out * sa * sb
 
 
 # ---------------------------------------------------------------------------
@@ -291,8 +443,7 @@ def exp2_scale_f64(e: Array) -> Array:
     return _exp2i(e).astype(jnp.float64)
 
 
-@functools.partial(jax.jit, static_argnames=("n_slices",))
-def matmul_c128(a: Array, b: Array, n_slices: int = _DEFAULT_SLICES) -> Array:
+def matmul_c128(a: Array, b: Array, n_slices: int | None = None) -> Array:
     """complex128 ``a @ b`` as three real Ozaki products (Karatsuba).
 
     (ar + i*ai)(br + i*bi) = (m1 - m2) + i*(m3 - m1 - m2) with
@@ -300,12 +451,22 @@ def matmul_c128(a: Array, b: Array, n_slices: int = _DEFAULT_SLICES) -> Array:
     of 4.  The m3 - m1 - m2 cancellation costs at most a couple of ulps
     relative to |a||b|, the same backward-error class as a plain complex
     GEMM (reference complex path: vendor ZGEMM, internal_gemm.cc:634).
+    ``n_slices`` as in ``matmul_f64``; with None each real product
+    chooses its own count and an open ``slice_record`` gets all three.
     """
     if a.dtype != jnp.complex128 or b.dtype != jnp.complex128:
         raise TypeError(f"matmul_c128 requires c128 operands, got {a.dtype}, {b.dtype}")
+    c, counts = _matmul_c128(a, b, n_slices=n_slices)
+    if n_slices is None and _SLICE_RECORDS:
+        _SLICE_RECORDS[-1].extend(counts)
+    return c
+
+
+@functools.partial(jax.jit, static_argnames=("n_slices",))
+def _matmul_c128(a: Array, b: Array, n_slices: int | None):
     ar, ai = jnp.real(a), jnp.imag(a)
     br, bi = jnp.real(b), jnp.imag(b)
-    m1 = matmul_f64(ar, br, n_slices=n_slices)
-    m2 = matmul_f64(ai, bi, n_slices=n_slices)
-    m3 = matmul_f64(ar + ai, br + bi, n_slices=n_slices)
-    return jax.lax.complex(m1 - m2, m3 - m1 - m2)
+    m1, s1 = _matmul_f64(ar, br, n_slices=n_slices)
+    m2, s2 = _matmul_f64(ai, bi, n_slices=n_slices)
+    m3, s3 = _matmul_f64(ar + ai, br + bi, n_slices=n_slices)
+    return jax.lax.complex(m1 - m2, m3 - m1 - m2), (s1, s2, s3)
